@@ -25,8 +25,7 @@ TRACING_CALLERS = frozenset({
     "jax.lax.scan", "jax.lax.while_loop", "jax.lax.fori_loop",
     "jax.lax.cond", "jax.lax.switch", "jax.lax.map",
     "jax.lax.associative_scan", "jax.lax.custom_root",
-    "jax.shard_map", "jax.experimental.shard_map.shard_map",
-    "bigdl_tpu.utils.jax_compat.shard_map",
+    "jax.shard_map",
     # pallas kernel bodies trace like any other staged function: the
     # rules (span-in-jit, host-sync, np-vs-jnp) apply to them verbatim
     "jax.experimental.pallas.pallas_call",

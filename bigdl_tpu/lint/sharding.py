@@ -43,8 +43,7 @@ MESH_CTORS = frozenset({
 })
 
 SHARD_MAP_FNS = frozenset({
-    "jax.shard_map", "jax.experimental.shard_map.shard_map",
-    "bigdl_tpu.utils.jax_compat.shard_map", "shard_map",
+    "jax.shard_map", "shard_map",
 })
 
 # canonical name -> positional index of the axis-name argument

@@ -10,11 +10,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 import bigdl_tpu.nn as nn
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.parallel.sequence import MultiHeadAttention
-from bigdl_tpu.utils.jax_compat import shard_map
 
 
 class TransformerEncoderLayer(Module):

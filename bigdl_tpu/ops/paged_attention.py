@@ -147,7 +147,9 @@ def _call_kernel(q, pool, page_table, start, *, sm_scale, head_block,
     b, h, c, d = q.shape
     n, _, ps, _ = pool["k"].shape
     npg = page_table.shape[1]
-    hb = fit_block(h, head_block)
+    # the int8 scale planes are (num_pages, H, page_size): the head block
+    # sits on the sublane axis of their BlockSpec
+    hb = fit_block(h, head_block, align=8)
     quant = "k_scale" in pool
     kernel = functools.partial(
         _decode_kernel_quant if quant else _decode_kernel,
@@ -231,12 +233,11 @@ def paged_pool_attention(q, pool, page_table, q_pos, sm_scale=None,
                              head_block=head_block, interpret=interpret)
     if mesh is None:
         return call(q, pool, page_table, start)
-    from bigdl_tpu.utils.jax_compat import shard_map
     m, axis = mesh
     kv = P(None, axis, None, None)
     pool_spec = {k: (kv if pool[k].ndim == 4 else P(None, axis, None))
                  for k in pool}
-    return shard_map(call, mesh=m,
-                     in_specs=(kv, pool_spec, P(None, None), P(None)),
-                     out_specs=kv, check_vma=False)(
+    return jax.shard_map(call, mesh=m,
+                         in_specs=(kv, pool_spec, P(None, None), P(None)),
+                         out_specs=kv, check_vma=False)(
         q, pool, page_table, start)

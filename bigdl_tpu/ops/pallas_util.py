@@ -5,8 +5,8 @@ Every kernel follows the same deployment pattern: compiled Mosaic on TPU,
 the pallas interpreter everywhere else — so parity tests on the CPU
 backend exercise the identical kernel code the chip runs. The helpers
 here are the pattern's common parts: backend detection, the TPU compiler
-params shim (the class was renamed across jax releases), and the
-block-size fitter that keeps grids aligned to the 128-wide MXU/VPU tiles.
+params, and the block-size fitter that keeps grids aligned to the
+128-wide MXU/VPU tiles.
 """
 
 from __future__ import annotations
@@ -28,25 +28,24 @@ def compiler_params(interpret, dimension_semantics):
     """TPU compiler params for ``pl.pallas_call`` (None in interpret
     mode). ``dimension_semantics`` marks each grid dim "parallel" or
     "arbitrary" (sequential — required for dims that carry scratch
-    accumulators). Handles the ``TPUCompilerParams`` ->
-    ``CompilerParams`` rename across jax releases."""
+    accumulators)."""
     if interpret:
         return None
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=tuple(dimension_semantics))
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics))
 
 
-def fit_block(s, want):
-    """Largest block <= ``want`` that divides ``s`` (prefers multiples of
-    128 for the MXU/VPU tiles); any 128-multiple sequence length works."""
+def fit_block(s, want, align=128):
+    """Largest block <= ``want`` that divides ``s`` and is a multiple of
+    ``align``, else all of ``s`` — the only two block sizes the TPU
+    lowering accepts on a tiled dimension (``align`` 128 on the lane axis,
+    8 on the sublane axis). Interpret mode checks none of this, so a
+    merely-dividing block (6 of 12 heads) passes every CPU test and is
+    refused on the chip."""
     if s <= want:
         return s
-    for b in range(min(want, s), 127, -128):
-        if b % 128 == 0 and s % b == 0:
-            return b
-    for b in range(min(want, s), 0, -1):  # CPU/interpret: any divisor
+    for b in range(want - want % align, 0, -align):
         if s % b == 0:
             return b
     return s

@@ -112,7 +112,7 @@ def fused_sample_logits(logits, key, temperature=1.0, top_k=None,
         jnp.asarray(temperature, logits.dtype).reshape(-1, 1)
         if jnp.ndim(temperature) else
         jnp.full((1, 1), temperature, logits.dtype), (s, 1))
-    bs = fit_block(s, block_s)
+    bs = fit_block(s, block_s, align=8)
     kernel = functools.partial(_sample_kernel, top_k=top_k, top_p=top_p,
                                vocab=v)
     out = pl.pallas_call(

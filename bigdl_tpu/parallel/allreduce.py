@@ -33,11 +33,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from bigdl_tpu.utils.jax_compat import shard_map
 
 
 def ring_allreduce_bytes(n_elems, ndev, dtype=jnp.bfloat16):

@@ -323,9 +323,13 @@ class DistriOptimizer(Optimizer):
                             driver_state["epoch"], records,
                             time.time() - t_epoch)
                 driver_state["epoch"] += 1
-                # keep epoch-based LR schedules live in the sharded state
-                opt_shard = {**opt_shard, "epoch": jnp.asarray(
-                    driver_state["epoch"], jnp.int32)}
+                # keep epoch-based LR schedules live in the sharded state —
+                # on the leaf's own mesh sharding: a plain array here
+                # changes the step's input type and recompiles the whole
+                # train step at the first epoch boundary
+                opt_shard = {**opt_shard, "epoch": jax.device_put(
+                    np.int32(driver_state["epoch"]),
+                    opt_shard["epoch"].sharding)}
             except TrainingPreempted:
                 # deliberate exit with a final checkpoint already written
                 # (_check_preempt) — retrying would defeat the preemption

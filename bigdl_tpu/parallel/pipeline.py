@@ -20,10 +20,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from bigdl_tpu.utils.jax_compat import shard_map
 
 
 def pipeline_apply(stage_fn, stage_params, xs, axis, n_stages):

@@ -22,10 +22,8 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-
-from bigdl_tpu.utils.jax_compat import shard_map
 
 
 def flash_profitable(t, causal=False):

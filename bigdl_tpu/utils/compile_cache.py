@@ -1,50 +1,57 @@
-"""Persistent XLA compilation cache setup, shared by the test suite and the
-driver dry-run child.
+"""Persistent XLA compilation cache: one function, one directory.
 
-Both are compile-dominated on the single-core CPU backend with stable shapes,
-so a warm cache cuts repeat wall time ~2x (tests) and keeps the multichip
-dry run far inside its watchdog. The cache directory is keyed by a CPU
-feature fingerprint: XLA:CPU AOT entries written on a different
-microarchitecture load with SIGILL-risk warnings (observed 2026-07-30), and
-neither consumer can afford a crash on a stale shared cache.
+Every entry point that compiles (``Engine.init``, the test suite, the
+chip smoke) calls :func:`enable_persistent_cache`. A directory that moves
+between runs never hits, so it is either the one the environment names
+(``JAX_COMPILATION_CACHE_DIR``, which JAX reads by itself — nothing is set
+in code then) or one fixed path inside the checkout.
+
+No per-CPU sub-directory: jaxlib's cache key already hashes the CPU
+backend's machine features (the serialized CPU topology lists ``+avx2``,
+``+avx512f``, ...), so an XLA:CPU entry written on another
+microarchitecture misses instead of loading.
 """
 
 from __future__ import annotations
 
-import hashlib
+import logging
 import os
 
+logger = logging.getLogger("bigdl_tpu")
 
-def _cpu_fingerprint() -> str:
-    try:
-        with open("/proc/cpuinfo") as fh:
-            flags = next((ln for ln in fh if ln.startswith("flags")), "")
-        return hashlib.md5(flags.encode()).hexdigest()[:8]
-    except OSError:
-        return "generic"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+DEFAULT_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 
 
-def enable_persistent_cache(tag: str = "test") -> None:
-    """Point jax at ``~/.cache/bigdl_tpu_xla_{tag}_cache_{cpufp}``.
+def enable_persistent_cache() -> None:
+    """Turn on JAX's persistent compilation cache.
 
-    Must run after ``import jax`` but before any backend use. Never raises:
-    an unwritable cache dir just means cold compiles.
+    ``JAX_COMPILATION_CACHE_DIR`` set: that directory, and no directory
+    is set in code; failing to create it raises, because the environment
+    asked for it. Unset: ``<checkout>/.jax_cache``; a checkout that
+    cannot be written (an installed package) runs with cold compiles and
+    says so. Must run after ``import jax`` and before the first compile.
     """
     import jax
 
-    try:
-        # BIGDL_TPU_TEST_CACHE keeps its original exact-path contract (a
-        # pre-warmed cache dir is pointed at directly) — note an explicit
-        # override therefore OPTS OUT of the cross-machine fingerprint
-        # keying and owns any stale-microarchitecture entries
-        cache = os.environ.get("BIGDL_TPU_TEST_CACHE")
-        if not cache:
-            cache = os.path.join(
-                os.path.expanduser("~"), ".cache",
-                f"bigdl_tpu_xla_{tag}_cache_{_cpu_fingerprint()}")
-        os.makedirs(cache, exist_ok=True)
+    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if cache:
+        if "://" not in cache:
+            os.makedirs(cache, exist_ok=True)
+    else:
+        cache = DEFAULT_CACHE_DIR
+        try:
+            os.makedirs(cache, exist_ok=True)
+        except OSError as e:
+            logger.warning("no persistent compile cache: cannot create "
+                           "%s (%s)", cache, e)
+            return
         jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass
+    # every program is kept, however quick its compile: around any positive
+    # threshold, programs land in the directory on one run and not on the
+    # next, so a second run would still add entries; and the test suite
+    # recompiles the same small programs from fresh jit objects hundreds of
+    # times (tier-1 cold: 624 s at 0.5 s, 572 s at 0; 29 MB afterwards)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)

@@ -3,15 +3,20 @@
 Reference: the lazy `.so`-from-jar loading of BigDL-core with
 ``MKL.isMKLLoaded`` guards at every call site (SURVEY.md section 2.1).
 Same contract here: ``native_lib()`` returns the ctypes wrapper or None, and
-every caller has a numpy fallback — the framework works without the native
-build, just slower on the host preprocessing path.
+every caller has a numpy fallback — the framework works on a host without a
+C++ toolchain, just slower on the host preprocessing path. The binary is
+never committed: it is built where it runs, with flags that do not depend on
+the build machine's CPU (csrc/Makefile), and rebuilt whenever the source it
+was built from differs from the one on disk.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
+import shutil
 import subprocess
 
 import numpy as np
@@ -21,6 +26,7 @@ logger = logging.getLogger("bigdl_tpu.native")
 _CSRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "csrc")
 _SO = os.path.join(_CSRC, "libbigdl_tpu_native.so")
+_STAMP = _SO + ".sha256"     # digest of the source the binary was built from
 
 _lib = None
 _tried = False
@@ -293,37 +299,50 @@ class _NativeLib:
         return out
 
 
+def _source_digest():
+    """Content key of what the binary is built from. A copy of the tree
+    scrambles mtimes, so staleness is decided by content, not by time."""
+    h = hashlib.sha256()
+    for name in ("bigdl_tpu_native.cpp", "Makefile"):
+        with open(os.path.join(_CSRC, name), "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
 def _build():
-    try:
-        subprocess.run(["make", "-C", _CSRC], check=True,
-                       capture_output=True, timeout=120)
-        return True
-    except Exception as e:  # missing toolchain etc — fall back to numpy
-        logger.warning("native build failed (%s); using numpy fallbacks", e)
+    """Build the library with ``make``. A host without a C++ toolchain is
+    the documented optional case (False: callers use their numpy
+    fallbacks); a toolchain that runs and fails is an error."""
+    if shutil.which("make") is None or shutil.which("g++") is None:
+        logger.warning("no C++ toolchain (make, g++): native host kernels "
+                       "unavailable, using numpy fallbacks")
         return False
+    r = subprocess.run(["make", "-B", "-C", _CSRC], capture_output=True,
+                       text=True, timeout=300)
+    if r.returncode:
+        raise RuntimeError(f"building {_SO} failed:\n{r.stderr[-2000:]}")
+    return True
 
 
 def native_lib():
-    """The ctypes wrapper, building on first use; None if unavailable."""
+    """The ctypes wrapper, built on first use from csrc/ on this machine;
+    None when there is no source or no toolchain to build it with."""
     global _lib, _tried
     if _lib is not None or _tried:
         return _lib
     _tried = True
-    src = os.path.join(_CSRC, "bigdl_tpu_native.cpp")
-    stale = (os.path.exists(_SO) and os.path.exists(src)
-             and os.path.getmtime(src) > os.path.getmtime(_SO))
-    if not os.path.exists(_SO) or stale:
-        if not (os.path.exists(src) and _build()) \
-                and not os.path.exists(_SO):
-            return None
+    if not os.path.exists(os.path.join(_CSRC, "bigdl_tpu_native.cpp")):
+        return None
+    digest = _source_digest()
     try:
-        _lib = _NativeLib(ctypes.CDLL(_SO))
-    except OSError as e:
-        logger.warning("could not load %s: %s", _SO, e)
-    except AttributeError as e:
-        # stale .so predating a symbol and no working toolchain to
-        # rebuild — numpy fallbacks beat crashing every dataset iter
-        logger.warning("%s is stale (missing symbol: %s); using numpy "
-                       "fallbacks", _SO, e)
-        _lib = None
+        with open(_STAMP) as f:
+            current = os.path.exists(_SO) and f.read().strip() == digest
+    except FileNotFoundError:
+        current = False
+    if not current:
+        if not _build():
+            return None
+        with open(_STAMP, "w") as f:
+            f.write(digest)
+    _lib = _NativeLib(ctypes.CDLL(_SO))
     return _lib

@@ -1,0 +1,651 @@
+"""chip_smoke.py: prove on demand that the system starts on the chip.
+
+``python chip_smoke.py`` is one process (a chip belongs to one process at
+a time) that drives the main paths through the entry points a user calls,
+at the full width of models the repo lists, on whatever TPU JAX reports:
+
+- **serve**: GPT-2 124M (768/12/12, vocab 50257, 1024 positions, random
+  weights from a seed) behind ``ServingEngine`` with default arguments,
+  mixed-length prompts, greedy and sampled requests; then the same
+  requests through the paged engine with both Pallas kernels on, and once
+  more with int8 K/V. Checked on logits, not tokens (see ``MARGIN_TOL``).
+- **kernels**: each Pallas kernel with ``interpret=False`` against its
+  XLA twin at that model's shapes.
+- **train**: ResNet-50 NHWC, bf16 compute, batch 256, a few steps through
+  ``Optimizer(...).optimize()`` on one repeated seeded batch.
+- **four chips** (only when JAX reports four or more): the train leg then
+  runs over all devices, and the serve leg at ``tp=4`` and the multichip
+  dry run are added, each asserting that what should be sharded really
+  spans every chip.
+
+There is no CPU mode, no size flag and no environment switch: without a
+TPU it exits non-zero and prints no result. The legs are plain functions
+taking sizes so ``tests/test_chip_smoke.py`` can run them tiny on the CPU.
+
+The last line of stdout is the verdict, one JSON object with exactly two
+keys: ``{"ok": true, "device": {"platform", "kind", "count"}}``, the device
+as JAX reports it. The line before it, ``chip_smoke: report: {...}``, is
+the record: ``{"versions", "cache": {"dir", "entries_before",
+"entries_added", "mbytes"}, "legs": {name: {"ok", "setup_s" (first pass:
+compiles included), "steady_s" (second pass: no compile allowed), ...}}}``.
+Any failed leg makes ``ok`` false and the exit code 1; the error is
+printed under the leg's name, never dropped.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import functools
+import json
+import os
+import sys
+import tempfile
+import time
+import traceback
+from unittest import mock
+
+import numpy as np
+
+# Why logits and not tokens: with random weights the largest logit changes
+# on rounding, so token identity with a reference is not a property the
+# system has on this chip. What it must have: every greedy token the
+# engine emits is, under the model's uncached full forward with true-f32
+# matmuls (``jax.default_matmul_precision("highest")``), within MARGIN_TOL
+# of that row's maximum.
+#
+# The engine's float32 matmuls run at the TPU's default precision (one
+# bf16 pass). For GPT-2 124M with N(0, 0.02) weights the logits have a
+# standard deviation near 0.55 and a typical top-1/top-2 gap near 0.1.
+# Every serve leg reports as ``precision_gap`` the largest difference
+# between the default- and the highest-precision uncached forward over the
+# rows it checks: 0.0186 on the v5e (my chip run, PR 21). An argmax taken
+# under default precision can trail the true maximum by at most twice
+# that, which is the tolerance. A defect of the cache path (a wrong row, a
+# position off by one, a stale page) moves logits by their own standard
+# deviation, more than ten times the tolerance.
+MARGIN_TOL = 0.04
+# int8 K/V adds a symmetric per-token quantization step of amax/127 (0.4 %
+# of the largest element) on every cached key and value, the same order as
+# the bf16 rounding above and independent of it (measured deficit 0.0055
+# against 0.0 without it, my chip run, PR 21).
+MARGIN_TOL_INT8_KV = 0.06
+
+# each wave's prompts share one prefill bucket (16, 128, 1024), so which
+# of them the scheduler happens to admit together cannot change what compiles
+GPT2_PROMPT_WAVES = ((12,), (70, 100), (600, 700, 960))
+GPT2_NEW_TOKENS = 32
+SAMPLING = {"top_k": 40, "top_p": 0.9, "temperature": 0.8}
+
+
+# ------------------------------------------------------------------ serve --
+def make_reference(model):
+    """The model's uncached full forward as the logit reference: rows
+    ``positions`` of one padded sequence, once with true-f32 matmuls and
+    once at the backend's default precision (for ``precision_gap``)."""
+    import jax
+
+    def rows(params, ids, positions):
+        h, _ = model.gpt.apply(params["gpt"], (), ids)
+        return model._lm_logits(params, h[0, positions])
+
+    def both(params, ids, positions):
+        with jax.default_matmul_precision("highest"):
+            exact = rows(params, ids, positions)
+        return exact, rows(params, ids, positions)
+
+    fn = jax.jit(both)
+    pmax = model.gpt.max_position
+
+    def reference(params, seq, positions):
+        ids = np.zeros((1, pmax), np.int32)
+        ids[0, :len(seq)] = seq
+        exact, default = fn(params, ids, np.asarray(positions, np.int32))
+        return np.asarray(exact), np.asarray(default)
+
+    return reference
+
+
+@contextlib.contextmanager
+def _compile_log():
+    """``(time, function)`` of every XLA compilation (or load from the
+    persistent cache) while the block runs. A steady window must hold
+    none: a compile there is a shape, dtype or sharding that changed
+    between two calls that should have been the same call."""
+    import jax.monitoring
+    log = []
+
+    def listen(event, duration, fun_name=None, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            log.append((time.perf_counter(), fun_name))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        yield log
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+
+
+def serve_leg(model_kw, params, prompt_waves, n_new, tol, engine_kw=None,
+              flags=(), seed=0, wait_s=600.0):
+    """Serve seeded prompts through ``ServingEngine`` twice (first pass
+    compiles, second must not) and check every emitted token on logits.
+
+    ``prompt_waves``: tuples of prompt lengths, each wave submitted
+    together and awaited before the next, so short waves compile their own
+    prefill buckets. The last wave also carries one sampled request.
+    Returns the leg's record; raises on any violated check."""
+    from bigdl_tpu.models.gpt import gpt2_small
+    from bigdl_tpu.serving import ServingEngine
+
+    # the two kernels are default-off behaviours selected by environment
+    # flags, read at model construction and at trace time: hold them on for
+    # the whole leg, and put the environment back afterwards
+    with mock.patch.dict(os.environ, {name: "1" for name in flags}):
+        model = gpt2_small(**model_kw)
+        reference = make_reference(model)
+        rng = np.random.default_rng(seed)
+        waves = [[rng.integers(0, model.vocab_size, n).astype(np.int32)
+                  for n in wave] for wave in prompt_waves]
+        sampled_prompt = rng.integers(0, model.vocab_size,
+                                      prompt_waves[0][0]).astype(np.int32)
+        engine = ServingEngine(model, params, top_k=SAMPLING["top_k"],
+                               top_p=SAMPLING["top_p"], **(engine_kw or {}))
+        try:
+            def one_pass():
+                greedy, sampled = [], None
+                for i, wave in enumerate(waves):
+                    handles = [engine.submit(p, n_new) for p in wave]
+                    if i == len(waves) - 1:
+                        sampled = engine.submit(
+                            sampled_prompt, n_new,
+                            temperature=SAMPLING["temperature"])
+                    greedy += [engine.result(h, timeout=wait_s)
+                               for h in handles]
+                return greedy, engine.result(sampled, timeout=wait_s)
+
+            t0 = time.perf_counter()
+            greedy, sampled = one_pass()
+            setup_s = time.perf_counter() - t0
+            traces = {k: engine.stats[k]
+                      for k in ("prefill_traces", "step_traces")}
+            t0 = time.perf_counter()
+            with _compile_log() as compiled:
+                greedy2, _ = one_pass()
+            steady_s = time.perf_counter() - t0
+            metrics = engine.metrics()
+            placement = _placement(engine, model)
+        finally:
+            engine.shutdown()
+
+    n_req = sum(len(w) for w in waves) + 1
+    prompts = [p for wave in waves for p in wave]
+    for p, out in zip(prompts + [sampled_prompt], greedy + [sampled]):
+        _require(len(out) == len(p) + n_new and (out[:len(p)] == p).all(),
+                 f"request of {len(p)} tokens came back with {len(out)}")
+    _require(metrics["admitted"] == metrics["retired"] == 2 * n_req,
+             f"admitted {metrics['admitted']} retired {metrics['retired']} "
+             f"of {2 * n_req} submitted")
+    _require(metrics["failures"] == 0, f"{metrics['failures']} failures")
+    # documented budget (docs/serving.md): the step executable compiles
+    # once, twice when a paged engine also builds its copy-on-write step;
+    # prefill compiles once per bucket (dense) or once (chunked)
+    _require(traces["step_traces"] <= 2, f"step_traces {traces}")
+    _require(traces["prefill_traces"] <= len(prompt_waves) + 1,
+             f"prefill_traces {traces}")
+    _require(not compiled, f"the steady pass compiled "
+                           f"{[name for _, name in compiled]}")
+    # a donated buffer read after donation, or state leaking from one
+    # request to the next, shows as a second pass that differs
+    same = sum(bool((a == b).all()) for a, b in zip(greedy, greedy2))
+
+    deficit, gap = 0.0, 0.0
+    for p, out in zip(prompts, greedy):
+        pos = np.arange(len(p) - 1, len(out) - 1)
+        exact, default = reference(params, out, pos)
+        _require(np.isfinite(exact).all(), "reference logits not finite")
+        chosen = exact[np.arange(n_new), out[len(p):]]
+        deficit = max(deficit, float((exact.max(-1) - chosen).max()))
+        gap = max(gap, float(np.abs(exact - default).max()))
+    _require(deficit <= tol,
+             f"a greedy token trails the reference maximum by {deficit:.4f} "
+             f"> {tol} (precision gap {gap:.4f})")
+    # the sampled request: every token must come from the reference's
+    # top-k set, up to the same rounding at its boundary
+    p = sampled_prompt
+    exact, _ = reference(params, sampled, np.arange(len(p) - 1,
+                                                    len(sampled) - 1))
+    kth = np.sort(exact, axis=-1)[:, -SAMPLING["top_k"]]
+    outside = float((kth - exact[np.arange(n_new), sampled[len(p):]]).max())
+    _require(outside <= tol,
+             f"a sampled token lies {outside:.4f} below the top-"
+             f"{SAMPLING['top_k']} boundary")
+    return {"ok": True, "requests": 2 * n_req, "setup_s": round(setup_s, 2),
+            "steady_s": round(steady_s, 2), **traces,
+            "margin_deficit": round(deficit, 5),
+            "precision_gap": round(gap, 5), "tolerance": tol,
+            "second_pass_identical": f"{same}/{len(greedy)}",
+            "tp_degree": metrics["tp_degree"], **placement}
+
+
+def _placement(engine, model):
+    """Under a tp mesh: parameters and the K/V tables must sit on every
+    device of the mesh, and the leaves whose spec names a mesh axis but
+    which ``ModelLayout.fit`` replicated because the axis does not divide
+    them are listed (reported, not fixed: GPT-2's vocabulary of 50257 is
+    odd, so the embedding and the tied LM head are such leaves)."""
+    import jax
+    layout = engine.layout
+    if layout is None:
+        return {}
+    n = layout.num_devices
+    slots = engine.slots
+    _spans(slots.params, n, "parameters")
+    _spans(slots._pools if engine.paged else slots._cache, n, "K/V tables")
+    specs = jax.tree_util.tree_leaves(
+        layout.param_specs(model, slots.params),
+        is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec))
+    leaves = jax.tree_util.tree_leaves_with_path(slots.params)
+    return {"mesh_devices": n, "replicated_despite_spec": [
+        jax.tree_util.keystr(path) for (path, leaf), spec
+        in zip(leaves, specs)
+        if any(spec) and leaf.sharding.is_fully_replicated]}
+
+
+def _spans(tree, n, what):
+    import jax
+    for leaf in jax.tree_util.tree_leaves(tree):
+        _require(len(leaf.sharding.device_set) == n,
+                 f"{what}: a {leaf.shape} leaf sits on "
+                 f"{len(leaf.sharding.device_set)} of {n} devices")
+
+
+def _require(cond, message):
+    if not cond:
+        raise AssertionError(message)
+
+
+# ---------------------------------------------------------------- kernels --
+def _rel_err(got, want):
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-9))
+
+
+def kernels_leg(heads=12, head_dim=64, seq=1024, long_shape=(1, 8, 8192, 64),
+                tail=512, slots=8, page_size=16, chunk=64, vocab=50257):
+    """Each Pallas kernel, compiled (``interpret=False`` passed, so it can
+    never silently interpret), against its XLA twin under true-f32
+    matmuls. Tolerances are relative to the twin's largest element:
+    ``2e-2`` where a bf16 pass or bf16 operands are in the path (2^-8
+    rounding on O(1) values, summed over a softmax that averages it down),
+    exact equality for sampled tokens."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.models.gpt import sample_logits
+    from bigdl_tpu.ops.flash_attention import flash_attention
+    from bigdl_tpu.ops.paged_attention import paged_pool_attention
+    from bigdl_tpu.ops.sampling import fused_sample_logits
+    from bigdl_tpu.parallel.sequence import (
+        full_attention, paged_attention, paged_gather, paged_gather_dequant,
+        paged_write, paged_write_quant)
+
+    tol = 2e-2
+    out = {}
+    t_start = time.perf_counter()
+
+    def exact(fn):
+        def run(*a):
+            with jax.default_matmul_precision("highest"):
+                return fn(*a)
+        return jax.jit(run)
+
+    def check_flash(name, flash_loss, twin_loss, q, k, v):
+        """Output and dq/dk/dv of the kernel against its twin; both
+        callables return ``(scalar loss, output)``."""
+        grad = functools.partial(jax.value_and_grad, argnums=(0, 1, 2),
+                                 has_aux=True)
+        (_, o), g = jax.jit(grad(flash_loss))(q, k, v)
+        (_, o_ref), g_ref = exact(grad(twin_loss))(q, k, v)
+        errs = [_rel_err(a, b) for a, b in zip((o, *g), (o_ref, *g_ref))]
+        out[name] = round(max(errs), 5)
+        _require(max(errs) <= tol, f"{name}: fwd/dq/dk/dv rel err {errs}")
+
+    def normal(seed, shape, dtype):
+        return [jax.random.normal(kk, shape, jnp.float32).astype(dtype)
+                for kk in jax.random.split(jax.random.key(seed), 4)]
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32)
+
+    # flash attention, forward and backward, whole sequence against
+    # full_attention
+    for dtype in (jnp.float32, jnp.bfloat16):
+        q, k, v, w = normal(1, (1, heads, seq, head_dim), dtype)
+        w = w.astype(jnp.float32)
+
+        def flash_loss(q, k, v):
+            o = flash(q, k, v)
+            return jnp.sum(o * w), o
+
+        def twin_loss(q, k, v):
+            o = full_attention(*(t.astype(jnp.float32) for t in (q, k, v)),
+                               causal=True)
+            return jnp.sum(o * w), o
+
+        check_flash(f"flash_s{seq}_{jnp.dtype(dtype).name}", flash_loss,
+                    twin_loss, q, k, v)
+
+    # flash attention at the long shape: the last ``tail`` query rows
+    # against the whole context (the (S, S) twin would not fit beside it)
+    s, d = long_shape[2:]
+    q, k, v, w = normal(2, long_shape, jnp.bfloat16)
+    wt = w[:, :, -tail:].astype(jnp.float32)
+
+    def flash_tail(q, k, v):
+        o = flash(q, k, v)[:, :, -tail:]
+        return jnp.sum(o * wt), o
+
+    def twin_tail(q, k, v):
+        q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q[:, :, -tail:], k) * d ** -0.5
+        rows = s - tail + jnp.arange(tail)[:, None]
+        scores = jnp.where(jnp.arange(s)[None, :] <= rows, scores, -jnp.inf)
+        o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(scores, -1), v)
+        return jnp.sum(o * wt), o
+
+    check_flash(f"flash_s{s}_bfloat16_tail{tail}", flash_tail, twin_tail,
+                q, k, v)
+
+    # paged attention: pools as the allocator leaves them (page runs in
+    # position order, sentinel tails, one empty slot), decode and chunk
+    lengths = [5, 17, seq // 3, 1, seq - chunk, seq, 0, chunk][:slots]
+    per_row = seq // page_size
+    n_pages = sum(-(-max(n, 1) // page_size) for n in lengths) + 1
+    table = np.full((slots, per_row), n_pages, np.int32)
+    pages = np.full((slots, seq), n_pages, np.int32)
+    nxt = 0
+    for i, n in enumerate(lengths):
+        for j in range(-(-n // page_size)):
+            table[i, j] = nxt
+            nxt += 1
+        pages[i, :n] = table[i, np.arange(n) // page_size]
+    offs = np.broadcast_to(np.arange(seq) % page_size, (slots, seq))
+    kv = jax.random.normal(jax.random.key(3),
+                           (2, slots, heads, seq, head_dim), jnp.float32)
+    shape = (n_pages, heads, page_size, head_dim)
+    for quant in (False, True):
+        if quant:
+            zeros = jnp.zeros(shape[:3], jnp.float32)
+            pk, ks = paged_write_quant(jnp.zeros(shape, jnp.int8), zeros,
+                                       kv[0], pages, offs)
+            pv, vs = paged_write_quant(jnp.zeros(shape, jnp.int8), zeros,
+                                       kv[1], pages, offs)
+            pool = {"k": pk, "v": pv, "k_scale": ks, "v_scale": vs}
+        else:
+            pool = {"k": paged_write(jnp.zeros(shape), kv[0], pages, offs),
+                    "v": paged_write(jnp.zeros(shape), kv[1], pages, offs)}
+
+        def twin(q, pool, table, q_pos):
+            if quant:
+                kf = paged_gather_dequant(pool["k"], pool["k_scale"], table,
+                                          jnp.float32)
+                vf = paged_gather_dequant(pool["v"], pool["v_scale"], table,
+                                          jnp.float32)
+            else:
+                kf = paged_gather(pool["k"], table)
+                vf = paged_gather(pool["v"], table)
+            return paged_attention(q, kf, vf, q_pos)
+
+        for c in (1, chunk):
+            q = jax.random.normal(jax.random.key(4 + c),
+                                  (slots, heads, c, head_dim), jnp.float32)
+            start = np.maximum(np.asarray(lengths) - c, 0)
+            q_pos = jnp.asarray(start[:, None] + np.arange(c), jnp.int32)
+            got = jax.jit(lambda *a: paged_pool_attention(
+                *a, interpret=False))(q, pool, table, q_pos)
+            want = exact(twin)(q, pool, jnp.asarray(table), q_pos)
+            name = f"paged_{'int8' if quant else 'f32'}_c{c}"
+            # queries past a row's write frontier (and the empty slot) are
+            # junk on both paths; serving discards them too
+            written = (np.asarray(q_pos) < np.asarray(lengths)[:, None])
+            written = written[:, None, :, None]
+            err = _rel_err(np.where(written, got, 0.0),
+                           np.where(written, want, 0.0))
+            out[name] = round(err, 5)
+            _require(np.isfinite(np.asarray(got)).all(),
+                     f"{name}: not finite")
+            _require(err <= tol, f"{name}: rel err {err}")
+
+    # fused sampling: same key, same gumbel noise, same kept set
+    logits = 3.0 * jax.random.normal(jax.random.key(5), (slots, vocab))
+    temps = jnp.linspace(0.6, 1.3, slots)[:, None]
+    for top_k, top_p in ((40, None), (None, 0.9), (40, 0.9)):
+        diff = 0
+        for i in range(4):
+            key = jax.random.key(100 + i)
+            got = jax.jit(lambda l, k, t: fused_sample_logits(
+                l, k, t, top_k, top_p, interpret=False))(logits, key, temps)
+            want = jax.jit(lambda l, k, t: sample_logits(
+                l, k, t, top_k, top_p))(logits, key, temps)
+            diff += int((np.asarray(got) != np.asarray(want)).sum())
+        name = f"sampling_k{top_k}_p{top_p}"
+        out[name] = diff
+        _require(diff == 0, f"{name}: {diff} of {4 * slots} tokens differ")
+    return {"ok": True, "setup_s": round(time.perf_counter() - t_start, 2),
+            "tolerance": tol, "errors": out}
+
+
+# ------------------------------------------------------------------ train --
+def train_leg(model, x_shape, n_class, steps, compute_dtype, seed=0):
+    """A few optimizer steps on one repeated seeded batch through the
+    public ``Optimizer`` on ``Engine.create_mesh()`` (all devices): loss
+    finite at every step and lower at the end than at the start. Returns
+    the record. Optimizer shards and the batch must span the mesh (on
+    one chip that is trivially so; on a four-chip host it is the check
+    that nothing was left on device 0)."""
+    import jax
+
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu.dataset import DataSet, SampleToMiniBatch
+    from bigdl_tpu.dataset.sample import Sample
+    from bigdl_tpu.optim import SGD, Optimizer, Trigger
+    from bigdl_tpu.utils.engine import Engine
+    from bigdl_tpu.visualization.summary import TrainSummary
+
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal(x_shape).astype(np.float32)
+    y = rng.integers(0, n_class, x_shape[0]).astype(np.int32)
+    ds = DataSet.array([Sample(x[i], y[i]) for i in range(x_shape[0])]) \
+        >> SampleToMiniBatch(x_shape[0])
+
+    stamps = []
+
+    class Timed(TrainSummary):
+        def add_scalar(self, tag, value, step):
+            if tag == "Loss":
+                stamps.append(time.perf_counter())
+            return super().add_scalar(tag, value, step)
+
+    with tempfile.TemporaryDirectory() as logdir:
+        summary = Timed(logdir, "chip_smoke")
+        opt = Optimizer(model=model, dataset=ds,
+                        criterion=nn.ClassNLLCriterion(),
+                        mesh=Engine.create_mesh(),
+                        compute_dtype=compute_dtype)
+        opt.set_optim_method(SGD(learningrate=0.01, momentum=0.9))
+        opt.set_train_summary(summary)
+        opt.set_end_when(Trigger.max_iteration(steps))
+        t0 = time.perf_counter()
+        with _compile_log() as compiled:
+            opt.optimize()
+        losses = [v for _, v in summary.read_scalar("Loss")]
+        summary.close()
+    _require(len(losses) == steps, f"{len(losses)} losses for {steps} steps")
+    _require(np.isfinite(losses).all(), f"loss not finite: {losses}")
+    _require(losses[-1] < losses[0], f"loss did not fall: {losses}")
+    late = [name for t, name in compiled if stamps[0] < t < stamps[-1]]
+    _require(not late, f"compiled between the first and the last step: "
+                       f"{late}")
+    n = int(opt.mesh.devices.size)
+    _spans([v for v in jax.tree_util.tree_leaves(opt._opt_state)
+            if v.ndim], n, "optimizer shards")
+    _spans(opt._shard_batch(next(iter(ds.data(train=False)))), n, "batch")
+    return {"ok": True, "steps": steps, "setup_s": round(stamps[0] - t0, 2),
+            "steady_s": round(stamps[-1] - stamps[0], 2),
+            "loss_first": round(losses[0], 4),
+            "loss_last": round(losses[-1], 4), "mesh_devices": n,
+            "feed_wait_frac": round(
+                opt.metrics_summary()["feed_wait_frac"], 3)}
+
+
+def train_resnet50(steps=8, batch=256):
+    import jax.numpy as jnp
+
+    from bigdl_tpu.models.resnet import ResNet
+    return train_leg(ResNet(class_num=1000, depth=50, format="NHWC"),
+                     (batch, 224, 224, 3), 1000, steps, jnp.bfloat16)
+
+
+# ------------------------------------------------------------- four chips --
+def memory_leg():
+    """Every chip holds something: code that has only ever run on a
+    virtual CPU mesh may have put everything on device 0."""
+    import jax
+    used = [d.memory_stats()["peak_bytes_in_use"] for d in jax.devices()]
+    _require(min(used) > 0, f"a chip was never used: {used}")
+    return {"ok": True, "peak_bytes_in_use": used}
+
+
+def dryrun_leg(n):
+    import __graft_entry__
+    t0 = time.perf_counter()
+    __graft_entry__.dryrun_multichip(n)
+    return {"ok": True, "setup_s": round(time.perf_counter() - t0, 2)}
+
+
+# ------------------------------------------------------------------- main --
+def _run_leg(fn):
+    """A failed leg is printed and recorded under its own name, and the
+    run goes on so one call to the chip reports every failure."""
+    try:
+        return fn()
+    except Exception as e:
+        traceback.print_exc()
+        return {"ok": False, "error": f"{type(e).__name__}: {e}"[:2000]}
+
+
+def native_leg():
+    """The host kernels are built on this machine from
+    csrc/bigdl_tpu_native.cpp (the checkout carries no binary) and give
+    the same CRC32C as the Python fallback."""
+    from bigdl_tpu.utils import native
+    from bigdl_tpu.visualization.tensorboard import _crc32c_py
+
+    had = os.path.exists(native._SO)
+    lib = native.native_lib()
+    _require(lib is not None, "native library unavailable")
+    data = bytes(range(256)) * 33
+    _require(lib.crc32c_bytes(data) == _crc32c_py(data), "crc32c mismatch")
+    return {"ok": True,
+            "library": "found built" if had else "built on this machine"}
+
+
+def _cache_record(cache_dir, before):
+    if not cache_dir:
+        return {"dir": None}
+    files = [os.path.join(cache_dir, f) for f in os.listdir(cache_dir)]
+    return {"dir": cache_dir, "entries_before": before,
+            "entries_added": len(files) - before,
+            "mbytes": round(sum(map(os.path.getsize, files)) / 2 ** 20, 1)}
+
+
+def require_tpu():
+    """Force the platform so a missing chip raises instead of JAX quietly
+    choosing the CPU; returns the device record."""
+    import jax
+
+    from bigdl_tpu.utils.engine import Engine
+    asked = os.environ.get("JAX_PLATFORMS")
+    try:
+        Engine.init("tpu")
+    except RuntimeError as e:
+        raise SystemExit(
+            f"chip_smoke.py runs on a TPU only and JAX found none "
+            f"(JAX_PLATFORMS was {asked!r}): {e}")
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(f"chip_smoke.py runs on a TPU only; JAX found "
+                         f"platform {dev.platform!r}")
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+
+
+def report(device, legs, cache):
+    """Print the record and then the verdict; returns the exit code. The
+    verdict, the last line, holds ``ok`` and ``device`` and no other key:
+    the driver's check reads it, the record before it is for people."""
+    import jax
+    import jaxlib
+    from importlib import metadata
+    ok = all(rec["ok"] for rec in legs.values())
+    print("chip_smoke: report: " + json.dumps({
+        "versions": {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                     "libtpu": metadata.version("libtpu")},
+        "cache": cache, "legs": legs}))
+    print(json.dumps({"ok": ok, "device": device}), flush=True)
+    return 0 if ok else 1
+
+
+def main():
+    device = require_tpu()
+    import jax
+
+    from bigdl_tpu.models.gpt import gpt2_small
+    from bigdl_tpu.ops.pallas_util import use_interpret
+    if use_interpret():
+        raise SystemExit("the Pallas kernels would run interpreted")
+    cache_dir = jax.config.jax_compilation_cache_dir
+    before = len(os.listdir(cache_dir)) if cache_dir else 0
+    print(f"chip_smoke: {device}, compile cache {cache_dir} "
+          f"({before} entries)", flush=True)
+
+    params, _ = gpt2_small().setup(jax.random.key(0), None)
+    kernel_flags = ("BIGDL_TPU_PAGED_KERNEL", "BIGDL_TPU_FUSED_SAMPLING")
+    plan = [
+        ("native", native_leg),
+        ("serve", lambda: serve_leg({}, params, GPT2_PROMPT_WAVES,
+                                    GPT2_NEW_TOKENS, MARGIN_TOL)),
+        ("serve_kernels", lambda: serve_leg(
+            {}, params, GPT2_PROMPT_WAVES, GPT2_NEW_TOKENS, MARGIN_TOL,
+            engine_kw={"paged": True}, flags=kernel_flags)),
+        ("serve_kernels_int8_kv", lambda: serve_leg(
+            {}, params, GPT2_PROMPT_WAVES, GPT2_NEW_TOKENS,
+            MARGIN_TOL_INT8_KV, engine_kw={"paged": True, "int8_kv": True},
+            flags=kernel_flags)),
+        ("kernels", kernels_leg),
+        ("train", train_resnet50),
+    ]
+    if device["count"] >= 4:
+        # the train leg above already ran on Engine.create_mesh() over
+        # every device; these add tensor-parallel serving and the dry run
+        plan += [
+            ("serve_tp4", lambda: serve_leg(
+                {}, params, GPT2_PROMPT_WAVES, GPT2_NEW_TOKENS, MARGIN_TOL,
+                engine_kw={"tp": 4})),
+            ("dryrun_multichip", lambda: dryrun_leg(4)),
+            ("memory_on_every_chip", memory_leg),
+        ]
+    legs = {}
+    for name, fn in plan:
+        t0 = time.perf_counter()
+        legs[name] = _run_leg(fn)
+        legs[name]["wall_s"] = round(time.perf_counter() - t0, 2)
+        print(f"chip_smoke: {name}: {json.dumps(legs[name])}", flush=True)
+
+    return report(device, legs, _cache_record(cache_dir, before))
+
+
+if __name__ == "__main__":
+    sys.exit(main())

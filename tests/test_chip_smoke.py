@@ -1,0 +1,91 @@
+"""The tier-1 twin of ``chip_smoke.py``: the same leg functions at toy
+size on the CPU mesh (Pallas kernels interpreted), and the script's own
+entry refusing to run without a TPU."""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+TOY = dict(vocab_size=64, hidden_size=32, n_layers=2, n_heads=4,
+           max_position=64)
+WAVES = ((5,), (9, 12), (20, 24, 28))
+KERNEL_FLAGS = ("BIGDL_TPU_PAGED_KERNEL", "BIGDL_TPU_FUSED_SAMPLING")
+# true-f32 CPU matmuls: the reference and the engine differ by summation
+# order only
+TOL = 1e-4
+
+
+@pytest.fixture(scope="module")
+def toy_params():
+    from bigdl_tpu.models.gpt import gpt2_small
+    return gpt2_small(**TOY).setup(jax.random.key(0), None)[0]
+
+
+def test_serve_leg(toy_params):
+    rec = chip_smoke.serve_leg(TOY, toy_params, WAVES, 6, TOL, wait_s=120)
+    assert rec["ok"] and rec["requests"] == 14
+    assert rec["step_traces"] == 1
+    assert rec["second_pass_identical"] == "6/6"
+
+
+def test_serve_leg_kernel_paths(toy_params):
+    rec = chip_smoke.serve_leg(
+        TOY, toy_params, WAVES, 6, TOL,
+        engine_kw={"paged": True, "page_size": 8, "prefill_chunk": 8},
+        flags=KERNEL_FLAGS, wait_s=120)
+    assert rec["ok"] and rec["second_pass_identical"] == "6/6"
+    assert not any(f in os.environ for f in KERNEL_FLAGS)
+
+
+def test_serve_leg_tp_reports_placement(toy_params, multi_device_cpu):
+    rec = chip_smoke.serve_leg(TOY, toy_params, WAVES[:2], 4, TOL,
+                               engine_kw={"tp": 2}, wait_s=120)
+    assert rec["mesh_devices"] == 2 and rec["tp_degree"] == 2
+    assert rec["replicated_despite_spec"] == []
+
+
+def test_train_leg(multi_device_cpu):
+    from bigdl_tpu.models.resnet import ResNet
+    rec = chip_smoke.train_leg(
+        ResNet(class_num=10, depth=8, data_set="cifar10", format="NHWC"),
+        (8, 32, 32, 3), 10, 4, jnp.bfloat16)
+    assert rec["ok"] and rec["mesh_devices"] == len(multi_device_cpu)
+    assert rec["loss_last"] < rec["loss_first"]
+
+
+def test_verdict_is_last_line_with_two_keys(capsys):
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    legs = {"serve": {"ok": True, "setup_s": 1.0},
+            "train": {"ok": False, "error": "boom"}}
+    assert chip_smoke.report(device, legs, {"dir": None}) == 1
+    record, verdict = capsys.readouterr().out.strip().splitlines()
+    assert json.loads(verdict) == {"ok": False, "device": device}
+    assert record.startswith("chip_smoke: report: ")
+    assert json.loads(record.split("report: ", 1)[1])["legs"] == legs
+    legs["train"] = {"ok": True}
+    assert chip_smoke.report(device, legs, {"dir": None}) == 0
+    assert json.loads(capsys.readouterr().out.splitlines()[-1]) == {
+        "ok": True, "device": device}
+
+
+def test_entry_refuses_without_tpu():
+    # TPU_SKIP_MDS_QUERY: libtpu otherwise waits seconds on a cloud
+    # metadata server this host does not have before reporting no device
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_SKIP_MDS_QUERY="1")
+    r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       env=env, cwd=REPO, capture_output=True, text=True,
+                       timeout=300)
+    assert r.returncode != 0
+    assert "runs on a TPU only" in r.stderr
+    assert "JAX_PLATFORMS was 'cpu'" in r.stderr
+    assert '"ok"' not in r.stdout

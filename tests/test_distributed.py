@@ -560,7 +560,7 @@ class TestInMeshValidation:
         # margin 2.5e-5 < 1e-4) — deterministic order de-flakes it
         ds.shuffle = lambda seed=None: ds
         # seed 8: top-2 logit margin ~3e-3 after training on this config
-        # (seed 6 lands a 6e-6 near-tie on the 0.4.x-jax CPU backend,
+        # (seed 6 lands a 6e-6 near-tie on the CPU backend,
         # tripping _wire_host_model's guard)
         vx, vy = _batch(128, seed=8)
         vsamples = [Sample(vx[i], vy[i]) for i in range(len(vx))]

@@ -53,7 +53,8 @@ def test_uneven_blocks():
 
 
 def test_non_dividing_block_auto_fits():
-    # s=300 with requested 128 blocks: _fit_block falls back to a divisor
+    # s=300 with requested 128 blocks: no 128-multiple divides it, so
+    # fit_block takes the whole sequence as one block
     q, k, v = _qkv(1, 1, 300, 16)
     o1 = np.asarray(flash_attention(q, k, v, block_q=128, block_k=128))
     o2 = np.asarray(full_attention(q, k, v))

@@ -78,7 +78,7 @@ def test_host_sync_reaches_through_call_graph(tmp_path):
 def test_host_sync_sees_scan_body_and_shard_map(tmp_path):
     findings = lint_src(tmp_path, """
         import jax
-        from bigdl_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         def outer(xs):
             def body(carry, x):

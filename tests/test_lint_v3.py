@@ -180,7 +180,7 @@ def test_collective_axis_quiet_on_declared_and_parameterized(tmp_path):
 
 def test_shardmap_spec_count_mismatch_fires(tmp_path):
     findings = lint_src(tmp_path, """
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def body(a, b):
@@ -197,7 +197,7 @@ def test_shardmap_spec_count_mismatch_fires(tmp_path):
 def test_shardmap_spec_quiet_on_match_partial_and_prefix(tmp_path):
     findings = lint_src(tmp_path, """
         import functools
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def body(a, b, c=None):

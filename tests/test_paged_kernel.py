@@ -63,13 +63,17 @@ class TestFitBlock:
         assert fit_block(5, 8) == 5
 
     def test_divisor_at_want(self):
-        assert fit_block(48, 8) == 8
+        assert fit_block(48, 8, align=8) == 8
+        assert fit_block(48, 16, align=8) == 16
 
-    def test_non_power_of_two_falls_to_divisor(self):
-        assert fit_block(10, 4) == 2      # 4 and 3 don't divide 10
-
-    def test_prime_falls_to_one(self):
-        assert fit_block(7, 4) == 1
+    def test_no_aligned_divisor_takes_the_whole_dimension(self):
+        """A block that merely divides (6 of 12 heads, 2 of 10 rows) is
+        refused by the TPU lowering; the whole dimension never is
+        (tests/test_pallas_lowering.py lowers these cases for TPU)."""
+        assert fit_block(12, 8, align=8) == 12   # GPT-2's heads
+        assert fit_block(10, 4, align=8) == 10
+        assert fit_block(7, 4, align=8) == 7
+        assert fit_block(1000, 512) == 1000
 
     def test_prefers_128_multiples(self):
         assert fit_block(384, 256) == 128  # 256 ∤ 384; 128 | 384
@@ -172,6 +176,20 @@ class TestKernelParity:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
 
+    @pytest.mark.parametrize("c", [1, 4], ids=["decode", "chunk"])
+    def test_twelve_heads_int8_match_xla_gather(self, c):
+        lengths = [9, 30]
+        pool, table = _build_pool(jax.random.PRNGKey(19), 2, 12, self.SMAX,
+                                  self.D, self.PS, lengths, int8=True)
+        q = jax.random.normal(jax.random.PRNGKey(23), (2, 12, c, self.D),
+                              jnp.float32)
+        q_pos = jnp.asarray([[n - c] for n in lengths],
+                            jnp.int32) + jnp.arange(c)
+        got = paged_pool_attention(q, pool, table, q_pos)
+        want = _reference(q, pool, table, q_pos)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
     @pytest.mark.parametrize("tp", [1, 2, 4])
     def test_tp_shard_map_matches_single_device(self, multi_device_cpu,
                                                 tp, monkeypatch):
@@ -218,7 +236,7 @@ class TestFusedSampling:
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
     def test_non_divisible_row_count(self):
-        # S=6 with block 4 -> fit_block picks 3; grid covers every row
+        # S=6 with block 4 -> no aligned divisor, one block of all 6 rows
         key = jax.random.PRNGKey(5)
         logits = jax.random.normal(jax.random.PRNGKey(105), (6, self.V))
         want = sample_logits(logits, key, 0.9, None, None)

@@ -135,7 +135,7 @@ class TestMoE:
             yy, _ = mp.apply(params, (), xloc)
             return yy
 
-        from bigdl_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         f = jax.jit(shard_map(
             ep_apply, mesh=mesh,
             in_specs=(mp.param_specs(), P("expert")),

@@ -143,7 +143,7 @@ class TestSPTrainStep:
             out, _ = sp.apply(params, (), x, training=False)
             return out
 
-        from bigdl_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         sharded = shard_map(
             fwd, mesh=mesh, in_specs=(P2(), P2("data", "seq")),
             out_specs=P2("data", "seq"), check_vma=False)

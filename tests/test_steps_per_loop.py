@@ -286,19 +286,3 @@ class TestDistriParity:
                         jax.tree_util.tree_leaves(p4)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-5, atol=1e-6)
-
-
-@pytest.mark.slow
-def test_k_sweep_perf_probe():
-    """CPU K-sweep: the fused loop must not be SLOWER than per-step
-    dispatch (on real TPU the win is the amortized ~25 ms host overhead;
-    on in-process CPU the dispatch saving is small but non-negative)."""
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import _bench_cpu_fallback
-    out = _bench_cpu_fallback(loops=4)
-    assert out["value"] > 0
-    assert out["extra"]["steps_per_loop_1"] > 0
-    # generous floor: jit'd scan overhead must not devour the win
-    assert out["extra"]["fused_loop_speedup"] > 0.7
