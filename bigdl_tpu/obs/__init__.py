@@ -8,8 +8,9 @@ stacks (docs/observability.md):
   snapshots, a process-global default registry.
 - :mod:`~bigdl_tpu.obs.spans` — host-side span tracer (nested,
   thread-aware, bounded ring buffer) exporting Chrome trace-event JSON
-  loadable in Perfetto. Never inside jit-traced code (the
-  ``span-in-jit`` lint rule enforces it).
+  loadable in Perfetto; a leaf span also enters the profiler's trace
+  through an annotator that ``utils.profiling`` installs. Never inside
+  jit-traced code (the ``span-in-jit`` lint rule enforces it).
 - :mod:`~bigdl_tpu.obs.reqtrace` — request-scoped timelines (bounded
   lifecycle-event rings per trace ID, Perfetto export with one track
   per request) and the flight recorder (last-N scheduler iterations,
@@ -37,12 +38,14 @@ from bigdl_tpu.obs.reqtrace import (FlightRecorder, ReqTraceRecorder,
                                     default_flight, default_recorder,
                                     flight_dump, mint)
 from bigdl_tpu.obs.spans import (Span, SpanTracer, default_tracer,
-                                 record_span, span)
+                                 leaf_span, record_span, record_span_at,
+                                 span)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "counter",
     "gauge", "histogram", "default_registry", "enabled", "set_enabled",
-    "Span", "SpanTracer", "span", "record_span", "default_tracer",
+    "Span", "SpanTracer", "span", "leaf_span", "record_span",
+    "record_span_at", "default_tracer",
     "ReqTraceRecorder", "FlightRecorder", "default_recorder",
     "default_flight", "flight_dump", "mint", "reqtrace",
     "MetricsServer", "JsonlSink", "SummaryBridge",

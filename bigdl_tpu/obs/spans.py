@@ -23,6 +23,20 @@ Usage::
 
     obs.record_span("train/feed", t_data, t0, step=n)   # after the fact
 
+    with obs.leaf_span("serve/step.dispatch", iter=n):  # also in the
+        step_fn(...)                                    # profiler's trace
+
+Two sinks, one call site. Every span lands in the ring; a *leaf* span
+(:func:`leaf_span`: a phase that encloses no other span and ends within
+the loop iteration that opened it) ALSO enters the tracer's *annotator*,
+a factory ``(name, attrs) -> context manager`` that ``obs`` never builds
+itself (it stays stdlib-only): ``utils.profiling.install_trace_annotator``
+installs ``jax.profiler.TraceAnnotation``, so that while a profiler
+session runs the leaf lies on its thread's line of the ``/host:CPU``
+plane, on the device trace's clock, and a device idle gap can be named by
+the phase the host was in. Only leaves: a tool that names a gap by the
+longest host event over it would give every gap to an enclosing span.
+
 Spans land in a bounded ring buffer (old spans fall off; a soak can run
 forever at O(capacity) memory) and export as Chrome trace-event JSON —
 ``chrome://tracing`` / https://ui.perfetto.dev load it directly, with
@@ -75,36 +89,62 @@ class _SpanContext:
     generator ``@contextmanager`` costs several microseconds per use in
     interpreter machinery alone, which matters for a per-step probe.
     The enabled check happens at ``__enter__`` (not construction) so a
-    pre-built context still respects a later kill-switch flip."""
+    pre-built context still respects a later kill-switch flip; it is the
+    one check that silences both sinks.
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_start")
+    The clock is read on entry and exit whatever the kill switch says
+    (``start``/``end`` in tracer-epoch seconds, :attr:`duration` once
+    closed), so a caller that needs the interval itself (the scheduler's
+    ``step_seconds``) takes it from the span instead of timing the same
+    section a second time."""
 
-    def __init__(self, tracer, name, attrs):
+    __slots__ = ("_tracer", "_name", "_attrs", "_leaf", "_on", "_ann",
+                 "start", "end")
+
+    def __init__(self, tracer, name, attrs, leaf=False):
         self._tracer = tracer
         self._name = name
         self._attrs = attrs
-        self._start = None
+        self._leaf = leaf
+        self._on = False
+        self._ann = None
+        self.start = self.end = 0.0
+
+    @property
+    def duration(self):
+        return self.end - self.start
+
+    def set(self, **attrs):
+        """Attributes known only once the section has run (a batch size,
+        a token count). They reach the ring; the profiler's event keeps
+        the attributes the span was opened with."""
+        self._attrs.update(attrs)
 
     def __enter__(self):
-        if not _metrics._enabled:
-            return self
         tracer = self._tracer
-        stack = getattr(tracer._local, "stack", None)
-        if stack is None:
-            stack = tracer._local.stack = []
-        self._start = time.perf_counter() - tracer.epoch_perf
-        stack.append(self._name)
+        self._on = _metrics._enabled
+        if self._on:
+            if self._leaf and tracer.annotator is not None:
+                self._ann = tracer.annotator(self._name, self._attrs)
+                self._ann.__enter__()
+            stack = getattr(tracer._local, "stack", None)
+            if stack is None:
+                stack = tracer._local.stack = []
+            stack.append(self._name)
+        self.start = time.perf_counter() - tracer.epoch_perf
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        start, self._start = self._start, None
-        if start is None:  # was disabled at __enter__
-            return False
         tracer = self._tracer
-        end = time.perf_counter() - tracer.epoch_perf
+        self.end = time.perf_counter() - tracer.epoch_perf
+        if not self._on:  # was disabled at __enter__
+            return False
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(exc_type, exc, tb)
         stack = tracer._local.stack
         stack.pop()
-        tracer._append(self._name, start, end,
+        tracer._append(self._name, self.start, self.end,
                        parent=stack[-1] if stack else None,
                        depth=len(stack), attrs=self._attrs)
         return False
@@ -115,9 +155,12 @@ class SpanTracer:
     stacks. All methods are thread-safe; recording is a clock read plus
     one locked deque append."""
 
-    def __init__(self, capacity=None):
+    def __init__(self, capacity=None, annotator=None):
         if capacity is None:
             capacity = get_flag("BIGDL_TPU_OBS_SPAN_CAPACITY", 8192, int)
+        # the second sink of a leaf span (module docstring): a factory
+        # ``(name, attrs) -> context manager``, or None for the ring alone
+        self.annotator = annotator
         self._lock = threading.Lock()
         self._buf = deque(maxlen=max(1, int(capacity)))
         self._local = threading.local()
@@ -132,12 +175,19 @@ class SpanTracer:
         another is open on the same thread records it as its parent."""
         return _SpanContext(self, name, attrs)
 
+    def leaf_span(self, name, **attrs):
+        """:meth:`span` for a leaf phase: ring AND the annotator (module
+        docstring). Never for a span that encloses other spans, nor for
+        one that outlives the loop iteration that opened it."""
+        return _SpanContext(self, name, attrs, leaf=True)
+
     def record(self, name, start, end, **attrs):
         """Record an already-timed section (``time.time()`` or
         ``perf_counter`` values both work — anything monotonic enough
-        that ``end - start`` is the duration). For instrumenting existing
-        timed code without restructuring it; records at the current
-        thread's nesting depth."""
+        that ``end - start`` is the duration), placed so that it ends
+        now. For instrumenting existing timed code without restructuring
+        it; records at the current thread's nesting depth. Ring only: the
+        section is over, so there is nothing for the annotator to enter."""
         if not _metrics._enabled:
             return
         dur = max(0.0, end - start)
@@ -146,6 +196,17 @@ class SpanTracer:
         self._append(name, now - dur, now,
                      parent=stack[-1] if stack else None,
                      depth=len(stack), attrs=attrs)
+
+    def record_at(self, name, start, end, **attrs):
+        """Record the interval between two ``perf_counter`` readings,
+        placed where they were taken: for one that several threads or
+        loop iterations bound (a request's wait in the queue), so it has
+        no parent, and its ends meet those of its neighbours exactly.
+        Ring only."""
+        if not _metrics._enabled:
+            return
+        self._append(name, start - self.epoch_perf, end - self.epoch_perf,
+                     parent=None, depth=0, attrs=attrs)
 
     def _append(self, name, start, end, parent, depth, attrs):
         t = threading.current_thread()
@@ -248,6 +309,19 @@ def span(name, **attrs):
     return _default.span(name, **attrs)
 
 
+def leaf_span(name, **attrs):
+    """:func:`span` for a leaf phase on the default tracer: it also
+    enters the profiler's trace (:meth:`SpanTracer.leaf_span`)."""
+    return _default.leaf_span(name, **attrs)
+
+
 def record_span(name, start, end, **attrs):
-    """Record an already-timed section on the default tracer."""
+    """Record an already-timed section on the default tracer (ring only:
+    it never reaches the profiler's trace)."""
     _default.record(name, start, end, **attrs)
+
+
+def record_span_at(name, start, end, **attrs):
+    """Record the interval between two ``perf_counter`` readings on the
+    default tracer, placed where they were taken (ring only)."""
+    _default.record_at(name, start, end, **attrs)

@@ -21,3 +21,6 @@ from bigdl_tpu.optim.evaluator import (  # noqa: F401
 from bigdl_tpu.optim.prediction_service import (  # noqa: F401
     PredictionService, predict_image, serialize_activity,
     deserialize_activity)
+from bigdl_tpu.utils import profiling as _profiling
+
+_profiling.install_trace_annotator()   # leaf spans enter the profiler's trace

@@ -24,3 +24,6 @@ from bigdl_tpu.serving.scheduler import (  # noqa: F401
 from bigdl_tpu.serving.slots import SlotManager  # noqa: F401
 from bigdl_tpu.serving.snapshot import (  # noqa: F401
     KVSnapshot, PageStore, RequestJournal, SnapshotError)
+from bigdl_tpu.utils import profiling as _profiling
+
+_profiling.install_trace_annotator()   # leaf spans enter the profiler's trace

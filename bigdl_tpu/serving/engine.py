@@ -483,7 +483,7 @@ class ServingEngine:
         reqtrace.event(trace, "submit", request=req.id,
                        engine=self.obs_label, prompt_tokens=int(t),
                        max_new_tokens=int(req.max_new_tokens))
-        with obs.span("serve/submit", request=req.id,
+        with obs.span("serve/submit", request=req.id, trace=trace,
                       engine=self.scheduler.obs_label):
             return self.scheduler.submit(req)
 

@@ -140,6 +140,13 @@ class Request:
             raise ValueError(f"deadline_s must be > 0, got {deadline_s}")
         self.deadline = (None if deadline_s is None
                          else self.submitted_at + float(deadline_s))
+        # popped from the waiting queue for its first admission: splits
+        # the time to the first token into the wait in the queue
+        # (``serve/queue_wait``) and admission to first token
+        # (``serve/first_token``); ``prefill_bucket`` is the padded
+        # prompt length of that admission (dense path)
+        self.admitted_at = None
+        self.prefill_bucket = None
         self.first_token_at = None
         self.finished_at = None
         # True when the slot table ran out of positions before
@@ -288,6 +295,9 @@ class Scheduler:
         self._stall_admissions = False
         self._paged_published = {}
         self.heartbeat = time.monotonic()
+        # count of loop iterations: every span the loop thread opens in
+        # one iteration carries it as ``iter`` (the slot manager's too)
+        self._iter = 0
         self._busy = False
         self._ttft_sum = 0.0
         # registry instruments: families are process-global, each engine
@@ -911,15 +921,33 @@ class Scheduler:
         else:
             self._obs["recovery_reprefill"].inc()
 
+    def _stamp_admitted(self, batch):
+        """A batch was just popped for admission: one clock read gives
+        each request its ``admitted_at`` and its ``serve/queue_wait``
+        span, once a request (one put back by a cold adapter or a full
+        page pool keeps its first pop, and waits again inside
+        ``serve/first_token``)."""
+        now = time.perf_counter()
+        for r in batch:
+            if r.admitted_at is None:
+                r.admitted_at = now
+                obs.record_span_at("serve/queue_wait", r.submitted_at, now,
+                                   request=r.id, trace=r.trace,
+                                   priority=r.priority)
+
     def _trace_admitted(self, r):
         """One ``admit`` timeline event (obs/reqtrace.py), carrying the
         prefix-restore split when the paged manager reports it —
         ``shared`` tokens came out of the cache/tier/store, the rest
         re-prefilled. ``delivered`` > 0 marks a re-placement (recovery,
-        preemption resume, migration), not a first admission."""
+        preemption resume, migration), not a first admission.
+        ``queue_wait_s`` is the ``serve/queue_wait`` span's length, from
+        the same clock read (``admitted_at``)."""
         reqtrace.event(
             r.trace, "admit", request=r.id, engine=self.obs_label,
             delivered=len(r.tokens),
+            queue_wait_s=(None if r.admitted_at is None
+                          else r.admitted_at - r.submitted_at),
             shared=int(getattr(self.slots, "last_admit_shared", 0)),
             total=int(getattr(self.slots, "last_admit_total", 0)))
 
@@ -945,11 +973,15 @@ class Scheduler:
             if self._abandoned:
                 raise _Halt
             self._beat(busy=False)
+            self._iter = slots.iter = it = self._iter + 1
             batch = []
             with self._cond:
-                while (self._accepting and not self._waiting
-                       and not self._inflight):
-                    self._cond.wait()
+                if (self._accepting and not self._waiting
+                        and not self._inflight):
+                    with obs.leaf_span("serve/idle", iter=it):
+                        while (self._accepting and not self._waiting
+                               and not self._inflight):
+                            self._cond.wait()
                 if self._abandoned:
                     raise _Halt
                 if not self._accepting and not self._drain:
@@ -966,46 +998,57 @@ class Scheduler:
                     self._obs["queue_depth"].set(0)
                     self._obs["slot_occupancy"].set(0)
                     return
-                self._sweep_waiting_locked()
-                if not self._waiting and not self._inflight:
-                    if not self._accepting:
-                        return
-                    continue
                 # time-based prefill batching: with nothing decoding yet,
                 # hold admission up to admit_wait_s so a burst of arrivals
                 # lands in ONE prefill dispatch instead of a ragged series
-                # of partial batches (costs bounded TTFT, only when idle)
+                # of partial batches (costs bounded TTFT, only when idle).
+                # Before the sweep below, so that the two waits and the
+                # pick are three disjoint leaves: a request whose deadline
+                # ran out in the queue fails once the hold is over
                 if (self.admit_wait_s > 0 and self._accepting
                         and not self._inflight
                         and 0 < len(self._waiting) < slots.window):
-                    deadline = time.perf_counter() + self.admit_wait_s
-                    remaining = self.admit_wait_s
-                    while (self._accepting and remaining > 0
-                           and len(self._waiting) < slots.window):
-                        self._cond.wait(remaining)
-                        remaining = deadline - time.perf_counter()
+                    with obs.leaf_span("serve/idle", iter=it):
+                        deadline = time.perf_counter() + self.admit_wait_s
+                        remaining = self.admit_wait_s
+                        while (self._accepting and remaining > 0
+                               and len(self._waiting) < slots.window):
+                            self._cond.wait(remaining)
+                            remaining = deadline - time.perf_counter()
+                with obs.leaf_span("serve/pick", iter=it) as pick:
                     self._sweep_waiting_locked()
-                # FIFO admission, bounded by the prefill window and the
-                # free slots — one batched prefill dispatch per iteration
-                n = min(len(self._waiting), slots.window,
-                        slots.free_slots())
-                if self._stall_admissions:
-                    if self._inflight:
-                        n = 0      # paged: wait for a retirement to free
-                    else:          # pages before re-admitting
-                        self._stall_admissions = False
-                if n and self._policy is not None:
-                    batch = self._pop_batch_locked(n, slots.free_slots())
-                else:
-                    batch = [self._waiting.popleft() for _ in range(n)]
-                if batch:
-                    self._limbo = list(batch)
-                self._obs["queue_depth"].set(len(self._waiting))
+                    queued = len(self._waiting)
+                    pick.set(queued=queued, n=0)
+                    if not queued and not self._inflight:
+                        if not self._accepting:
+                            return
+                        continue
+                    # FIFO admission, bounded by the prefill window and
+                    # the free slots — one batched prefill dispatch per
+                    # iteration
+                    n = min(queued, slots.window, slots.free_slots())
+                    if self._stall_admissions:
+                        if self._inflight:
+                            n = 0  # paged: wait for a retirement to free
+                        else:      # pages before re-admitting
+                            self._stall_admissions = False
+                    if n and self._policy is not None:
+                        batch = self._pop_batch_locked(n,
+                                                       slots.free_slots())
+                    else:
+                        batch = [self._waiting.popleft() for _ in range(n)]
+                    if batch:
+                        self._limbo = list(batch)
+                    self._obs["queue_depth"].set(len(self._waiting))
+                    pick.set(n=len(batch))
+            if batch:
+                self._stamp_admitted(batch)
             self._beat(busy=True)
-            self._sweep_inflight()
             paged = getattr(slots, "paged", False)
-            if paged and getattr(slots, "host_tier", None) is not None:
-                self._prefetch_host_tier()
+            with obs.leaf_span("serve/sweep", iter=it):
+                self._sweep_inflight()
+                if paged and getattr(slots, "host_tier", None) is not None:
+                    self._prefetch_host_tier()
             if batch:
                 if paged:
                     self._admit_paged(batch)
@@ -1048,12 +1091,12 @@ class Scheduler:
                     self._recover(list(self._inflight.values()), e)
                     continue
             pre_lengths = slots.lengths.copy()
-            t0 = time.perf_counter()
             try:
                 fault_point("serving.step",
                             requests=tuple(r.id
                                            for r in self._inflight.values()))
-                with obs.span("serve/step", live=len(self._inflight)):
+                with obs.span("serve/step", iter=it,
+                              live=len(self._inflight)) as step_span:
                     toks = slots.step()    # (steps_per_sync, max_slots)
             except _Halt:
                 raise
@@ -1065,25 +1108,30 @@ class Scheduler:
             if self._abandoned:
                 raise _Halt
             self._beat()
-            dt = time.perf_counter() - t0
+            # the decode block's seconds are the span's: one interval, one
+            # pair of clock reads (taken with telemetry off as well)
+            dt = step_span.duration
             self.step_seconds += dt
             self._obs["step_seconds"].inc(dt)
-            self._deliver_block(toks, pre_lengths)
-            self._maybe_snapshot()
-            self._update_spec_gauges()
-            if paged:
-                self._update_paged_gauges()
-            if reqtrace.enabled():
-                with self._cond:
-                    queued = len(self._waiting)
-                it = {"live": len(self._inflight),
-                      "queued": queued, "step_s": dt,
-                      "generated": self.generated_tokens}
-                if getattr(slots, "spec_proposed", 0):
-                    it["spec_proposed"] = slots.spec_proposed
-                    it["spec_accepted"] = slots.spec_accepted
-                reqtrace.default_flight().note_iteration(self.obs_label,
-                                                         **it)
+            with obs.leaf_span("serve/deliver", iter=it) as deliver:
+                tokens, retired = self._deliver_block(toks, pre_lengths)
+                deliver.set(tokens=tokens, retired=retired)
+            with obs.leaf_span("serve/after", iter=it):
+                self._maybe_snapshot()
+                self._update_spec_gauges()
+                if paged:
+                    self._update_paged_gauges()
+                if reqtrace.enabled():
+                    with self._cond:
+                        queued = len(self._waiting)
+                    note = {"live": len(self._inflight),
+                            "queued": queued, "step_s": dt,
+                            "generated": self.generated_tokens}
+                    if getattr(slots, "spec_proposed", 0):
+                        note["spec_proposed"] = slots.spec_proposed
+                        note["spec_accepted"] = slots.spec_accepted
+                    reqtrace.default_flight().note_iteration(
+                        self.obs_label, **note)
 
     # ------------------------------------------------------- admission ----
     def _admit(self, batch):
@@ -1097,11 +1145,21 @@ class Scheduler:
         try:
             fault_point("serving.admit",
                         requests=tuple(r.id for r in batch))
-            with obs.span("serve/prefill", n=len(batch)):
+            with obs.span("serve/prefill", iter=self._iter,
+                          n=len(batch)) as prefill:
                 assigned = slots.admit(
                     [r.context() for r in batch],
                     [r.temperature for r in batch],
                     adapter_slots=[r._adapter_slot for r in batch])
+                # what the dispatch was padded to, against what was asked
+                # for: rows x bucket positions computed for ``tokens``
+                rows, bucket = slots.last_prefill_shape
+                prefill.set(rows=rows, bucket=bucket,
+                            tokens=sum(r.prompt.size + len(r.tokens)
+                                       for r in batch),
+                            requests=[r.id for r in batch])
+                for r in batch:
+                    r.prefill_bucket = bucket
         except _Halt:
             raise
         except BaseException as e:
@@ -1368,7 +1426,8 @@ class Scheduler:
         column to the positions the slot table can actually hold: a
         request whose ``prompt_len + generated`` reaches
         ``max_position`` is force-retired (``Request.truncated``)
-        instead of being fed clamped-position junk."""
+        instead of being fed clamped-position junk. Returns
+        ``(tokens delivered, requests retired)``."""
         done = []
         tokens_before = self.generated_tokens
         # speculative managers commit a VARIABLE count per slot each
@@ -1401,7 +1460,14 @@ class Scheduler:
                 finished = True
                 if col.size < r.remaining():
                     r.truncated = True
+            first = r.first_token_at is None
             r._deliver(col.tolist())
+            if first and r.admitted_at is not None:
+                obs.record_span_at(
+                    "serve/first_token", r.admitted_at, r.first_token_at,
+                    request=r.id, trace=r.trace,
+                    prompt_tokens=int(r.prompt.size),
+                    bucket=r.prefill_bucket)
             self._journal_delivered(r, col.size)
             if col.size:
                 # stream offsets, not counts: the failover-continuity
@@ -1440,6 +1506,7 @@ class Scheduler:
                 self.generated_tokens / self.step_seconds)
         if done:
             self._obs["slot_occupancy"].set(self.slots.occupancy())
+        return int(delivered), len(done)
 
     # -------------------------------------------- cancel/deadline sweeps --
     def _swept(self, r, err):

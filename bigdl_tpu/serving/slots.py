@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from bigdl_tpu import obs
 from bigdl_tpu.models.gpt import prompt_bucket, sample_logits
 from bigdl_tpu.obs import reqtrace
 from bigdl_tpu.resilience.faults import fault_point
@@ -151,6 +152,12 @@ class SlotManager:
         # cache/logits/key buffers are invalid either way) — poisoned
         # means nothing but reset() may touch device state again
         self.poisoned = False
+        # the owner's loop iteration, stamped on the spans opened here so
+        # that they join the scheduler's own (``Scheduler._serve`` sets
+        # it); ``last_prefill_shape`` is the (rows, bucket) the latest
+        # admission was padded to, for its ``serve/prefill`` span
+        self.iter = 0
+        self.last_prefill_shape = None
         self._dtype = params["gpt"]["tok_emb"].dtype
         self._alloc()
         self._prefill_fn, self._step_fn = self._build_fns()
@@ -304,6 +311,13 @@ class SlotManager:
                 length=n_steps)
             return cache, logits_buf, key, toks     # toks (n_steps, S)
 
+        # ``jax.jit`` names an executable ``jit_`` + its function's name,
+        # and the benchmark finds this pair in the device trace as
+        # ``jit_prefill`` and ``jit_step`` (``executables`` in
+        # benchmarks/configs/gpt2-medium-serve.json): named here on
+        # purpose, whatever the local functions come to be called
+        # (tests/test_executable_names.py holds it)
+        prefill.__name__, step.__name__ = "prefill", "step"
         # the cache, logits table and PRNG key are single-owner buffers
         # threaded call-to-call — donate them; params never are. Under a
         # layout the out_shardings pin every donated output to its input
@@ -455,44 +469,48 @@ class SlotManager:
             raise ValueError(
                 f"admit batch of {len(prompts)} exceeds window "
                 f"{self.window} / free slots {len(self._free)}")
-        w = self.window
-        arrs = [np.asarray(p, np.int32).reshape(-1) for p in prompts]
-        for a in arrs:
-            if a.size > self.max_position - 1:
-                # reject instead of silently clamping (the table cannot
-                # hold the prompt AND a generated token in bounds)
-                raise ValueError(
-                    f"prompt of {a.size} tokens exceeds the slot "
-                    f"capacity of {self.max_position - 1} "
-                    f"(max_position {self.max_position} minus one "
-                    f"generated token)")
-        bucket = prompt_bucket(max(a.size for a in arrs),
-                               self.max_position)
-        ids = np.zeros((w, bucket), np.int32)
-        lens = np.ones(w, np.int32)            # padding rows: length 1
-        slot_idx = np.full(w, self.max_slots, np.int32)  # OOB -> dropped
-        arows = np.zeros(w, np.int32)          # padding rows: base row 0
-        assigned = []
-        # before any slot is claimed: a fault here must not leak slots
-        fault_point("serving.prefill", n=len(arrs))
-        for i, a in enumerate(arrs):
-            ids[i, :a.size] = a
-            lens[i] = a.size
-            slot_idx[i] = heapq.heappop(self._free)
-            assigned.append(int(slot_idx[i]))
-            if adapter_slots is not None:
-                arows[i] = int(adapter_slots[i])
-        self._occupied += len(assigned)
-        extra = self._adapter_args(arows)
+        with obs.leaf_span("serve/prefill.pack", iter=self.iter):
+            w = self.window
+            arrs = [np.asarray(p, np.int32).reshape(-1) for p in prompts]
+            for a in arrs:
+                if a.size > self.max_position - 1:
+                    # reject instead of silently clamping (the table cannot
+                    # hold the prompt AND a generated token in bounds)
+                    raise ValueError(
+                        f"prompt of {a.size} tokens exceeds the slot "
+                        f"capacity of {self.max_position - 1} "
+                        f"(max_position {self.max_position} minus one "
+                        f"generated token)")
+            bucket = prompt_bucket(max(a.size for a in arrs),
+                                   self.max_position)
+            ids = np.zeros((w, bucket), np.int32)
+            lens = np.ones(w, np.int32)            # padding rows: length 1
+            slot_idx = np.full(w, self.max_slots, np.int32)  # OOB -> dropped
+            arows = np.zeros(w, np.int32)          # padding rows: base row 0
+            assigned = []
+            # before any slot is claimed: a fault here must not leak slots
+            fault_point("serving.prefill", n=len(arrs))
+            for i, a in enumerate(arrs):
+                ids[i, :a.size] = a
+                lens[i] = a.size
+                slot_idx[i] = heapq.heappop(self._free)
+                assigned.append(int(slot_idx[i]))
+                if adapter_slots is not None:
+                    arows[i] = int(adapter_slots[i])
+            self._occupied += len(assigned)
+            self.last_prefill_shape = (w, bucket)
+            extra = self._adapter_args(arows)
         try:
-            if self.spec_tokens > 1:
-                self._cache, self._logits, self._table = self._prefill_fn(
-                    self.params, self._cache, self._logits, self._table,
-                    ids, lens, slot_idx, *extra)
-            else:
-                self._cache, self._logits = self._prefill_fn(
-                    self.params, self._cache, self._logits, ids, lens,
-                    slot_idx, *extra)
+            with obs.leaf_span("serve/prefill.dispatch", iter=self.iter):
+                if self.spec_tokens > 1:
+                    self._cache, self._logits, self._table = \
+                        self._prefill_fn(
+                            self.params, self._cache, self._logits,
+                            self._table, ids, lens, slot_idx, *extra)
+                else:
+                    self._cache, self._logits = self._prefill_fn(
+                        self.params, self._cache, self._logits, ids, lens,
+                        slot_idx, *extra)
         except BaseException:
             self.poisoned = True
             raise
@@ -514,25 +532,32 @@ class SlotManager:
         (steps_per_sync * spec_tokens, max_slots) and ``last_counts``
         holds each slot's committed count — callers read column ``s``
         up to ``last_counts[s]``."""
-        extra = self._adapter_args(self.adapter_slots)
         try:
-            if self.spec_tokens > 1:
-                (self._cache, self._logits, self._key, self._table, toks,
-                 counts, tele) = self._step_fn(
-                    self.params, self._cache, self._logits, self.lengths,
-                    self.active, self.temps, self._key, self._table,
-                    self._last_tok, *extra)
-            else:
-                self._cache, self._logits, self._key, toks = self._step_fn(
-                    self.params, self._cache, self._logits, self.lengths,
-                    self.active, self.temps, self._key, *extra)
+            # argument hand-over, the call and (with request tracing on)
+            # ``CostStampedJit``, until the executable's call returns
+            with obs.leaf_span("serve/step.dispatch", iter=self.iter):
+                extra = self._adapter_args(self.adapter_slots)
+                if self.spec_tokens > 1:
+                    (self._cache, self._logits, self._key, self._table,
+                     toks, counts, tele) = self._step_fn(
+                        self.params, self._cache, self._logits,
+                        self.lengths, self.active, self.temps, self._key,
+                        self._table, self._last_tok, *extra)
+                else:
+                    self._cache, self._logits, self._key, toks = \
+                        self._step_fn(
+                            self.params, self._cache, self._logits,
+                            self.lengths, self.active, self.temps,
+                            self._key, *extra)
         except BaseException:
             self.poisoned = True
             raise
         self.stats.dispatched()
         if self.spec_tokens > 1:
             return self._finish_spec_block(toks, counts, tele)
-        toks = jax.device_get(toks)            # ONE readback per block
+        # ONE readback per block: the host blocked on the device
+        with obs.leaf_span("serve/step.readback", iter=self.iter):
+            toks = jax.device_get(toks)
         self.lengths[self.active] = np.minimum(
             self.lengths[self.active] + self.steps_per_sync,
             self.max_position)
@@ -543,7 +568,8 @@ class SlotManager:
         tokens + commit counts + accept telemetry, then advance lengths
         by each slot's ACTUAL committed count (speculation makes block
         progress variable, 1..block_span tokens per slot)."""
-        toks, counts, tele = jax.device_get((toks, counts, tele))
+        with obs.leaf_span("serve/step.readback", iter=self.iter):
+            toks, counts, tele = jax.device_get((toks, counts, tele))
         counts = np.asarray(counts, np.int64)
         self.last_counts = counts
         self.lengths[self.active] = np.minimum(

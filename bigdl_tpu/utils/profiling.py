@@ -248,6 +248,22 @@ class CostStampedJit:
         return out
 
 
+def _trace_annotation(name, attrs):
+    return jax.profiler.TraceAnnotation(name, **attrs)
+
+
+def install_trace_annotator():
+    """Make ``jax.profiler.TraceAnnotation`` the default span tracer's
+    annotator (``obs/spans.py``: ``obs`` itself imports no jax), so that
+    every leaf span also lies on its thread's line of the profiler's
+    ``/host:CPU`` plane, on the device trace's clock, while a profiler
+    session runs; with no session an annotation costs about a
+    microsecond. ``bigdl_tpu.serving`` and ``bigdl_tpu.optim`` call this
+    when they are imported."""
+    from bigdl_tpu import obs
+    obs.default_tracer().annotator = _trace_annotation
+
+
 def profiling_enabled():
     return _ENABLED
 

@@ -343,6 +343,110 @@ def test_record_span_after_the_fact(tracer):
     assert s.attrs == {"neval": 2}
 
 
+# ------------------------------------------------- the second sink: leaves
+
+class _FakeAnnotation:
+    def __init__(self, log, name, attrs):
+        self.log, self.name, self.attrs = log, name, dict(attrs)
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.attrs))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("leave", self.name, self.attrs))
+        return False
+
+
+@pytest.fixture
+def annotated():
+    """``(tracer, log)``: a fresh tracer whose annotator writes what it
+    is asked to enter and leave into ``log``."""
+    log = []
+    return SpanTracer(capacity=64, annotator=lambda name, attrs:
+                      _FakeAnnotation(log, name, attrs)), log
+
+
+def test_leaf_span_enters_and_leaves_the_annotator(annotated):
+    tracer, log = annotated
+    with tracer.span("serve/step", iter=3, live=2):           # a parent
+        with tracer.leaf_span("serve/step.dispatch", iter=3):
+            pass
+        with tracer.leaf_span("serve/step.readback", iter=3) as leaf:
+            leaf.set(late=1)       # the ring gets it, the event does not
+    assert log == [("enter", "serve/step.dispatch", {"iter": 3}),
+                   ("leave", "serve/step.dispatch", {"iter": 3}),
+                   ("enter", "serve/step.readback", {"iter": 3}),
+                   ("leave", "serve/step.readback", {"iter": 3})]
+    by_name = {s.name: s for s in tracer.spans()}
+    assert by_name["serve/step.readback"].attrs == {"iter": 3, "late": 1}
+    assert by_name["serve/step.dispatch"].parent == "serve/step"
+    assert by_name["serve/step"].attrs == {"iter": 3, "live": 2}
+
+
+@pytest.mark.parametrize("kind", ["parent", "record", "record_at",
+                                  "leaf_while_disabled"])
+def test_only_an_enabled_leaf_reaches_the_annotator(annotated, kind):
+    tracer, log = annotated
+    if kind == "parent":
+        with tracer.span("serve/prefill", n=2):
+            pass
+    elif kind == "record":
+        tracer.record("train/feed", 10.0, 10.25)
+    elif kind == "record_at":
+        tracer.record_at("serve/queue_wait", tracer.epoch_perf + 1.0,
+                         tracer.epoch_perf + 1.5, request=7)
+    else:
+        prev = obs.set_enabled(False)
+        try:
+            with tracer.leaf_span("serve/idle", iter=1):
+                pass
+        finally:
+            obs.set_enabled(prev)
+    assert log == []
+    assert len(tracer) == (0 if kind == "leaf_while_disabled" else 1)
+
+
+def test_record_at_places_a_span_where_its_readings_were_taken(tracer):
+    a = tracer.epoch_perf + 2.0
+    with tracer.span("outer"):
+        tracer.record_at("serve/queue_wait", a, a + 0.5, request=1)
+        tracer.record_at("serve/first_token", a + 0.5, a + 0.75, request=1)
+    wait, first, _ = tracer.spans()
+    assert (wait.start, wait.end) == (2.0, 2.5)
+    assert first.start == wait.end and first.duration == 0.25
+    assert wait.parent is None and wait.depth == 0   # no thread owns it
+
+
+def test_a_span_is_timed_whatever_the_kill_switch_says(tracer):
+    """The scheduler takes a decode block's seconds from its span, and
+    ``engine.metrics()`` reports them with telemetry off as well."""
+    prev = obs.set_enabled(False)
+    try:
+        with tracer.span("serve/step") as sp:
+            pass
+    finally:
+        obs.set_enabled(prev)
+    assert sp.end >= sp.start > 0.0 and sp.duration == sp.end - sp.start
+    assert len(tracer) == 0
+
+
+def test_obs_imports_no_jax():
+    """``bigdl_tpu.obs`` stays stdlib-only: the annotator that needs jax
+    is installed from ``bigdl_tpu.utils.profiling``, by the packages that
+    import jax anyway."""
+    import subprocess
+    import sys
+    code = ("import sys; import bigdl_tpu.obs; "
+            "assert 'jax' not in sys.modules, 'obs imported jax'; "
+            "assert bigdl_tpu.obs.default_tracer().annotator is None; "
+            "import bigdl_tpu.serving; "
+            "assert bigdl_tpu.obs.default_tracer().annotator is not None")
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+
+
 # ----------------------------------------------------------------- exporters
 
 def test_metrics_server_endpoints(reg, tracer):
