@@ -70,11 +70,13 @@ class TransformerDecoderBlock(Module):
         x = x + h
         return x + self._mlp(params, x), cache
 
-    def decode_step(self, params, cache, x, index):
+    def decode_step(self, params, cache, x, index, in_place=False):
         """One incremental token (x: (B, 1, H)) through the block; the
-        attention K/V for slot ``index`` land in ``cache``."""
+        attention K/V for slot ``index`` land in ``cache`` (``in_place``:
+        see ``_MHA.decode_step``)."""
         h, cache = self.attn.decode_step(
-            params["attn"], self.ln1.call(params["ln1"], x), cache, index)
+            params["attn"], self.ln1.call(params["ln1"], x), cache, index,
+            in_place=in_place)
         x = x + h
         return x + self._mlp(params, x), cache
 
@@ -201,19 +203,21 @@ class GPT(Module):
         return (jnp.take_along_axis(h, idx[:, None, None], axis=1)[:, 0],
                 new_cache)
 
-    def decode_step(self, params, cache, tok, pos):
+    def decode_step(self, params, cache, tok, pos, in_place=False):
         """One incremental token: embed ``tok`` (B,) at position ``pos``
         (traced scalar, or a (B,) vector when every row sits at its own
         length — the serving engine's slot batch), run every block in
         cache mode, and return the (B, H) final-norm hidden state plus
-        the updated cache."""
+        the updated cache. ``in_place`` is the slot table's word that
+        its buffers take the write kernel (``_MHA.decode_step``)."""
         h = jnp.take(params["tok_emb"], tok.astype(jnp.int32), axis=0)
         h = h + jnp.take(params["pos_emb"], jnp.asarray(pos, jnp.int32),
                          axis=0)
         h = h[:, None, :]
         new_cache = []
         for i, layer in enumerate(self.layers):
-            h, c = layer.decode_step(params["layers"][i], cache[i], h, pos)
+            h, c = layer.decode_step(params["layers"][i], cache[i], h, pos,
+                                     in_place=in_place)
             new_cache.append(c)
         h = self.ln_f.call(params["ln_f"], h)
         return h[:, 0], new_cache

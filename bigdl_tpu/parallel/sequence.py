@@ -491,36 +491,51 @@ class MultiHeadAttention:
                 out = out.transpose(0, 2, 1, 3).reshape(b, t, hs)
                 return qmatmul(out, params["wo"]), cache
 
-            def decode_step(self, params, x, cache, index):
+            def decode_step(self, params, x, cache, index,
+                            in_place=False):
                 """Incremental mode: attend ONE query token (x: (B, 1, H))
                 against the cache, after writing its own K/V at slot
-                ``index``. ``index`` is a traced scalar (one shared
-                position for the whole batch — the ``generate`` path) or
-                a traced (B,) vector (each row writes and attends at its
-                own length — the serving engine's slot batch, where dim 0
-                of the cache is the slot table). Either way
-                ``lax.dynamic_update_slice`` keeps the buffers
-                static-shaped, so the step is scannable and the cache
-                donatable. The length mask admits exactly slots
-                [0, index] per row."""
+                ``index``. The buffers keep their shapes, so the step is
+                scannable and the cache donatable; the length mask
+                admits exactly slots [0, index] per row. The write takes
+                one of three paths, all writing the same bits:
+
+                - ``index`` a traced scalar (one shared position for
+                  the whole batch, the ``generate`` path): one
+                  ``lax.dynamic_update_slice`` a buffer.
+                - ``index`` a traced (B,) vector (each row writes and
+                  attends at its own length: the serving engine's slot
+                  batch, where dim 0 of the cache is the slot table):
+                  ``jax.vmap`` over ``dynamic_update_slice``, a scatter
+                  (``kv_write.plain_write``). On a TPU, XLA expands it
+                  into a loop over the rows,
+                  B trips of four small launches for K and again for V.
+                - the same vector with ``in_place=True``: one Pallas
+                  call for K and V (``ops/kv_write.py``) that rewrites
+                  only the tiles holding the B positions. The owner of
+                  the table says so: ``SlotManager`` asks
+                  ``kv_write.in_place_applies`` of the table it
+                  allocated (on a TPU, float32 or bfloat16, not laid out
+                  over a mesh, positions minor on the device) when it
+                  builds its step. No flag selects it, and off the chip
+                  or under a layout the step is the scatter's.
+
+                A row out of bounds is clamped on every path (the
+                serving step clamps its own beforehand)."""
                 b, t, hs = x.shape
                 q, k, v = self._qkv(params, x)
                 idx = jnp.asarray(index, jnp.int32)
+                k = k.astype(cache["k"].dtype)
+                v = v.astype(cache["v"].dtype)
                 if idx.ndim == 0:
-                    kc = lax.dynamic_update_slice(
-                        cache["k"], k.astype(cache["k"].dtype),
-                        (0, 0, idx, 0))
-                    vc = lax.dynamic_update_slice(
-                        cache["v"], v.astype(cache["v"].dtype),
-                        (0, 0, idx, 0))
+                    kc = lax.dynamic_update_slice(cache["k"], k,
+                                                  (0, 0, idx, 0))
+                    vc = lax.dynamic_update_slice(cache["v"], v,
+                                                  (0, 0, idx, 0))
                 else:
-                    def put(buf, new, i):   # (H, S, D) <- (H, 1, D) at i
-                        return lax.dynamic_update_slice(buf, new, (0, i, 0))
-
-                    kc = jax.vmap(put)(cache["k"],
-                                       k.astype(cache["k"].dtype), idx)
-                    vc = jax.vmap(put)(cache["v"],
-                                       v.astype(cache["v"].dtype), idx)
+                    from bigdl_tpu.ops.kv_write import kv_write, plain_write
+                    write = kv_write if in_place else plain_write
+                    kc, vc = write(cache["k"], cache["v"], k, v, idx)
                 out = cached_attention(q, kc, vc, idx + 1)
                 out = out.transpose(0, 2, 1, 3).reshape(b, t, hs)
                 return qmatmul(out, params["wo"]), {"k": kc, "v": vc}

@@ -1096,7 +1096,8 @@ class Scheduler:
                             requests=tuple(r.id
                                            for r in self._inflight.values()))
                 with obs.span("serve/step", iter=it,
-                              live=len(self._inflight)) as step_span:
+                              live=len(self._inflight),
+                              kv_write=slots.kv_write) as step_span:
                     toks = slots.step()    # (steps_per_sync, max_slots)
             except _Halt:
                 raise

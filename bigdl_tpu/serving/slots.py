@@ -40,6 +40,7 @@ from jax import lax
 from bigdl_tpu import obs
 from bigdl_tpu.models.gpt import prompt_bucket, sample_logits
 from bigdl_tpu.obs import reqtrace
+from bigdl_tpu.ops.kv_write import in_place_applies
 from bigdl_tpu.resilience.faults import fault_point
 from bigdl_tpu.utils.profiling import CostStampedJit, DecodeCounters
 
@@ -99,6 +100,11 @@ class SlotManager:
     # the scheduler branches on this: the paged manager
     # (serving/paging.py) admits per-request and prefills in chunks
     paged = False
+    # how the decode step writes a new position into the cache, fixed
+    # when the pair is built and stamped on every ``serve/step`` span:
+    # "kernel" is ``ops/kv_write.py`` in place, "scatter" the plain XLA
+    # write (the paged and the speculative steps scatter too)
+    kv_write = "scatter"
     _stat_keys = ("prefill_traces", "step_traces")
     _obs_name = "serving"
 
@@ -265,6 +271,10 @@ class SlotManager:
         top_k, top_p = self.top_k, self.top_p
         pmax = self.max_position
         wrap = self._wrap_fn()
+        # the table as it was allocated says whether the write kernel
+        # applies (a TPU, no mesh, positions minor on the device)
+        in_place = in_place_applies(self._cache[0]["k"], self.layout)
+        self.kv_write = "kernel" if in_place else "scatter"
 
         def prefill(params, cache, logits_buf, ids, prompt_len, slot_idx,
                     *adapter):
@@ -300,7 +310,8 @@ class SlotManager:
                 # junk the host discards; the clamp keeps its cache writes
                 # and position lookups in bounds near max_position
                 pos = jnp.minimum(lengths, pmax - 1)
-                h, cache = gpt.decode_step(params["gpt"], cache, tok, pos)
+                h, cache = gpt.decode_step(params["gpt"], cache, tok, pos,
+                                           in_place=in_place)
                 logits = model._lm_logits(params, h).astype(logits.dtype)
                 lengths = lengths + active.astype(lengths.dtype)
                 return (cache, logits, lengths, key), tok

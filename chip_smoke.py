@@ -11,6 +11,8 @@ at the full width of models the repo lists, on whatever TPU JAX reports:
   more with int8 K/V. Checked on logits, not tokens (see ``MARGIN_TOL``).
 - **kernels**: each Pallas kernel with ``interpret=False`` against its
   XLA twin at that model's shapes.
+- **kv_write**: the decode step's in-place K/V write against the plain
+  write at the GPT-2 medium serving cell's table, bit for bit.
 - **train**: ResNet-50 NHWC, bf16 compute, batch 256, a few steps through
   ``Optimizer(...).optimize()`` on one repeated seeded batch.
 - **four chips** (only when JAX reports four or more): the train leg then
@@ -174,6 +176,7 @@ def serve_leg(model_kw, params, prompt_waves, n_new, tol, engine_kw=None,
             steady_s = time.perf_counter() - t0
             metrics = engine.metrics()
             placement = _placement(engine, model)
+            kv_write = engine.slots.kv_write
         finally:
             engine.shutdown()
 
@@ -224,7 +227,8 @@ def serve_leg(model_kw, params, prompt_waves, n_new, tol, engine_kw=None,
             "margin_deficit": round(deficit, 5),
             "precision_gap": round(gap, 5), "tolerance": tol,
             "second_pass_identical": f"{same}/{len(greedy)}",
-            "tp_degree": metrics["tp_degree"], **placement}
+            "tp_degree": metrics["tp_degree"], "kv_write": kv_write,
+            **placement}
 
 
 def _placement(engine, model):
@@ -437,6 +441,58 @@ def kernels_leg(heads=12, head_dim=64, seq=1024, long_shape=(1, 8, 8192, 64),
             "tolerance": tol, "errors": out}
 
 
+def kv_write_leg(slots=48, heads=16, seq=1024, head_dim=64,
+                 interpret=False):
+    """``ops/kv_write.py`` against the plain write it replaces in the
+    serving step at the GPT-2 medium cell's table, ``f32[48,16,1024,64]``
+    and its bfloat16 twin, every slot at another position: the two tables
+    must come back the same bit for bit, so a Mosaic that accepts the
+    kernel and writes the wrong lane is caught outside the benchmark.
+    Also that the table as allocated selects the kernel here
+    (``in_place_applies``: a TPU, positions minor on the device)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from bigdl_tpu.ops.kv_write import (in_place_applies, kv_write,
+                                        plain_write)
+
+    def differing(a, b):
+        whole = {2: jnp.uint16, 4: jnp.uint32}[a.dtype.itemsize]
+        return jnp.sum(lax.bitcast_convert_type(a, whole)
+                       != lax.bitcast_convert_type(b, whole))
+
+    t_start = time.perf_counter()
+    pos = np.random.default_rng(0).integers(0, seq, slots)
+    # both ends of the table and both sides of a tile's edge among them
+    edges = [0, seq - 1, seq // 2 - 1, seq // 2, 7, 8][:slots]
+    pos[:len(edges)] = edges
+    pos = jnp.asarray(pos, jnp.int32)
+    kernel = jax.jit(functools.partial(kv_write, interpret=interpret),
+                     donate_argnums=(0, 1))
+    selected = {}
+    for dtype in (jnp.float32, jnp.bfloat16):
+        name = jnp.dtype(dtype).name
+        draw = jax.jit(lambda k, shape: jax.random.normal(
+            k, shape, jnp.float32).astype(dtype), static_argnums=1)
+        keys = jax.random.split(jax.random.key(7), 4)
+        table = (slots, heads, seq, head_dim)
+        new = (slots, heads, 1, head_dim)
+        k_table, v_table = draw(keys[0], table), draw(keys[1], table)
+        rest = (draw(keys[2], new), draw(keys[3], new), pos)
+        selected[name] = in_place_applies(k_table)
+        _require(selected[name] or interpret,
+                 f"kv_write {name}: the table as allocated does not select "
+                 f"the kernel (layout {k_table.format.layout})")
+        want = jax.jit(plain_write)(k_table, v_table, *rest)
+        got = kernel(k_table, v_table, *rest)        # consumes the tables
+        wrong = int(differing(got[0], want[0]) + differing(got[1], want[1]))
+        _require(wrong == 0, f"kv_write {name}: {wrong} elements differ "
+                             f"from the plain write")
+    return {"ok": True, "setup_s": round(time.perf_counter() - t_start, 2),
+            "differing_elements": 0, "selected": selected}
+
+
 # ------------------------------------------------------------------ train --
 def train_leg(model, x_shape, n_class, steps, compute_dtype, seed=0):
     """A few optimizer steps on one repeated seeded batch through the
@@ -625,6 +681,7 @@ def main():
             MARGIN_TOL_INT8_KV, engine_kw={"paged": True, "int8_kv": True},
             flags=kernel_flags)),
         ("kernels", kernels_leg),
+        ("kv_write", kv_write_leg),
         ("train", train_resnet50),
     ]
     if device["count"] >= 4:

@@ -54,6 +54,14 @@ def test_serve_leg_tp_reports_placement(toy_params, multi_device_cpu):
     assert rec["replicated_despite_spec"] == []
 
 
+def test_kv_write_leg():
+    rec = chip_smoke.kv_write_leg(slots=6, heads=2, seq=256, head_dim=32,
+                                  interpret=True)
+    assert rec["ok"] and rec["differing_elements"] == 0
+    # not on a TPU: the serving step would keep the plain write
+    assert rec["selected"] == {"float32": False, "bfloat16": False}
+
+
 def test_train_leg(multi_device_cpu):
     from bigdl_tpu.models.resnet import ResNet
     rec = chip_smoke.train_leg(

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import pytest
 
 from bigdl_tpu.ops.flash_attention import flash_attention
+from bigdl_tpu.ops.kv_write import kv_write
 from bigdl_tpu.ops.paged_attention import paged_pool_attention
 from bigdl_tpu.ops.sampling import fused_sample_logits
 
@@ -71,3 +72,13 @@ def test_fused_sampling(top_k, top_p, slots):
             logits, key, temps, top_k, top_p, interpret=False),
         S((slots, VOCAB), jnp.float32),
         S((), jax.random.key(0).dtype), S((slots, 1), jnp.float32))
+
+
+@pytest.mark.parametrize("heads", [HEADS, 16], ids=["h12", "h16"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_kv_write(dtype, heads):
+    table = S((SLOTS, heads, SEQ, HEAD_DIM), dtype)
+    new = S((SLOTS, heads, 1, HEAD_DIM), dtype)
+    lower_for_tpu(lambda *a: kv_write(*a, interpret=False),
+                  table, table, new, new, S((SLOTS,), jnp.int32))
