@@ -438,6 +438,43 @@ class GPTForCausalLM(Module):
             return self.head.call(params["head"], h)
         return h @ params["gpt"]["tok_emb"].T
 
+    # ------------------------------------------ the serving protocol --
+    # (bigdl_tpu/serving/protocol.py): the engine and the dense slot
+    # table reach ``.gpt`` through these and through nothing else; the
+    # features below (paging, speculation, LoRA, int8, tp, snapshots)
+    # still reach into ``.gpt`` from their own modules
+    serving_features = frozenset(("paged", "spec_tokens", "lora",
+                                  "int8_weights", "int8_kv", "tp",
+                                  "kv_snapshot"))
+    logits_dtype = None
+    experts_per_token = 0
+    expert_product = None
+    logits = _lm_logits
+
+    @property
+    def max_position(self):
+        return self.gpt.max_position
+
+    def serving_dtype(self, params):
+        return params["gpt"]["tok_emb"].dtype
+
+    def check_servable(self):
+        if self.gpt.layers and \
+                self.gpt.layers[0].attn.sequence_parallel is not None:
+            raise ValueError(
+                "serving does not compose with sequence_parallel; build "
+                "the model without it for generation")
+
+    def init_cache(self, batch, dtype=jnp.float32, sharding=None):
+        return self.gpt.init_cache(batch, dtype, sharding=sharding)
+
+    def prefill(self, params, cache, ids, prompt_len):
+        return self.gpt.prefill(params["gpt"], cache, ids, prompt_len)
+
+    def decode_step(self, params, cache, tok, pos, in_place=False):
+        return self.gpt.decode_step(params["gpt"], cache, tok, pos,
+                                    in_place=in_place)
+
     def partition_specs(self, params, spec=None):
         """Canonical GSPMD PartitionSpec pytree for ``params`` — the
         model owns the parameter-name -> layout-role mapping

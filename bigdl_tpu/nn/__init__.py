@@ -20,7 +20,8 @@ from bigdl_tpu.nn.pooling import (  # noqa: F401
     VolumetricMaxPooling, VolumetricAveragePooling)
 from bigdl_tpu.nn.normalization import (  # noqa: F401
     BatchNormalization, SpatialBatchNormalization,
-    VolumetricBatchNormalization, LayerNormalization, SpatialCrossMapLRN,
+    VolumetricBatchNormalization, LayerNormalization, RMSNorm,
+    SpatialCrossMapLRN,
     SpatialWithinChannelLRN, Normalize, NormalizeScale)
 from bigdl_tpu.nn.basic import (  # noqa: F401
     Reshape, View, Flatten, Transpose, Squeeze, Unsqueeze, Select, Narrow,
@@ -81,4 +82,5 @@ from bigdl_tpu.nn.misc import (  # noqa: F401
     Highway, ResizeBilinear)
 from bigdl_tpu.nn.conv import (  # noqa: F401
     SpatialSeperableConvolution)
-from bigdl_tpu.nn.moe import MoE  # noqa: F401
+from bigdl_tpu.nn.moe import MoE, RoutedExperts  # noqa: F401
+from bigdl_tpu.nn.gated import GatedMLP, GatedShortConv  # noqa: F401

@@ -11,6 +11,11 @@ Dispatch (per top-k choice c): tokens pick expert e = argmax of the
 drops tokens beyond ``capacity``; one-hot dispatch (N, E, C) routes token
 vectors into per-expert buffers, experts run a GELU MLP batched over E,
 and the combine einsum scatters outputs back weighted by the gate.
+
+:class:`RoutedExperts`, further down, is the layer that serves: it drops
+no token (``MoE`` does, beyond ``capacity``), scores with a sigmoid and
+a selection bias, and computes its experts as one grouped product over
+the assignments sorted by expert.
 """
 
 from __future__ import annotations
@@ -152,3 +157,118 @@ class MoE(Module):
         p = jnp.mean(probs, axis=0).astype(f.dtype)
         aux = self.n_experts * jnp.sum(f * p) / self.k
         return y.reshape(shape), {"aux_loss": aux}
+
+
+class RoutedExperts(Module):
+    """Dropless routed SwiGLU experts, as the sparse decoders after 2024
+    route (sigmoid scores, a bias that moves the CHOICE only, weights
+    renormalised over the chosen):
+
+        s = sigmoid(x @ wg)                       ``num_experts`` scores
+        chosen = the ``k`` largest of s + expert_bias
+        w_e = s_e / (sum of the chosen s + 1e-6) * scaling
+        y = sum over chosen e of w_e * w2[e](silu(w1[e] x) * w3[e] x)
+
+    The layer is told which experts it HOLDS: ``count`` of them from
+    ``first`` on (default: all). It routes over all ``num_experts``,
+    keeps the assignments to its own, sorts them by expert and computes
+    them as one grouped product a matrix (``jax.lax.ragged_dot``, which
+    the TPU compiler turns into its own grouped-matmul kernel: the
+    operations named ``ragged-dot`` in a device trace). What the absent
+    experts would have added is left out: the shares of every holder add
+    up to the whole layer. No assignment is dropped, whatever the
+    routing: the product's groups are as long as the routing makes them.
+
+    Scores, choice and weights are float32 with true-float32 products;
+    the expert products take the weights' dtype and sum in float32.
+    """
+
+    product = "ragged_dot"
+
+    def __init__(self, hidden_size, ffn_size, num_experts, k, first=0,
+                 count=None, use_bias=True, norm_topk_prob=True,
+                 scaling=1.0):
+        super().__init__()
+        count = num_experts - first if count is None else count
+        if k < 1 or k > num_experts:
+            raise ValueError(f"k={k} outside [1, {num_experts}]")
+        if first < 0 or count < 1 or first + count > num_experts:
+            raise ValueError(f"experts [{first}, {first + count}) outside "
+                             f"[0, {num_experts})")
+        self.hidden_size = hidden_size
+        self.ffn_size = ffn_size
+        self.num_experts = num_experts
+        self.k = k
+        self.first = first
+        self.count = count
+        self.use_bias = use_bias
+        self.norm_topk_prob = norm_topk_prob
+        self.scaling = scaling
+
+    def make_params(self, rng, input_spec):
+        d, f, e, c = (self.hidden_size, self.ffn_size, self.num_experts,
+                      self.count)
+        kg, k1, k2, k3 = jax.random.split(rng, 4)
+        params = {"wg": jax.random.normal(kg, (d, e)) * d ** -0.5,
+                  "w1": jax.random.normal(k1, (c, d, f)) * d ** -0.5,
+                  "w3": jax.random.normal(k2, (c, d, f)) * d ** -0.5,
+                  "w2": jax.random.normal(k3, (c, f, d)) * f ** -0.5}
+        if self.use_bias:
+            params["expert_bias"] = jnp.zeros((e,))
+        return params
+
+    def route(self, params, x):
+        """``x`` (N, hidden) -> ``(chosen (N, k) int32, weights (N, k)
+        float32)`` over all ``num_experts``, in float32."""
+        logits = jnp.dot(x.astype(jnp.float32),
+                         params["wg"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        biased = scores
+        if self.use_bias:
+            biased = scores + params["expert_bias"].astype(jnp.float32)
+        _, chosen = lax.top_k(biased, self.k)
+        w = jnp.take_along_axis(scores, chosen, axis=-1)
+        if self.norm_topk_prob:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        return chosen.astype(jnp.int32), w * self.scaling
+
+    def routed(self, params, x, live=None):
+        """``x`` (N, hidden) -> ``(y (N, hidden) float32, hit)``. ``live``
+        (N,) bool marks the rows that count (default: all): a dead row
+        (a free slot, a prompt's padding) is left out of the product and
+        gets zeros. ``hit`` is how many of the experts held got at least
+        one assignment from a live row: the groups that are not empty,
+        whose weights the product has to read."""
+        n, k, c = x.shape[0], self.k, self.count
+        chosen, w = self.route(params, x)
+        local = chosen.reshape(-1) - self.first               # (N * k,)
+        mine = (local >= 0) & (local < c)
+        if live is not None:
+            mine = mine & jnp.repeat(jnp.asarray(live, bool), k)
+        # what is not computed here sorts behind every group and falls
+        # outside the product's groups
+        group = jnp.where(mine, local, c)
+        order = jnp.argsort(group, stable=True)
+        back = jnp.argsort(order)
+        sizes = jnp.sum(
+            group[:, None] == jnp.arange(c, dtype=group.dtype)[None, :],
+            axis=0, dtype=jnp.int32)
+        dt = params["w1"].dtype
+        xs = jnp.take(x.astype(dt), order // k, axis=0)       # (N * k, d)
+
+        def grouped(a, b):
+            return lax.ragged_dot(a.astype(dt), b, sizes,
+                                  preferred_element_type=jnp.float32)
+
+        h = jax.nn.silu(grouped(xs, params["w1"])) \
+            * grouped(xs, params["w3"])
+        ys = jnp.take(grouped(h, params["w2"]), back, axis=0)
+        ys = jnp.where(mine[:, None], ys, 0.0).reshape(n, k, -1)
+        y = jnp.sum(ys * w[:, :, None], axis=1)
+        return y, jnp.sum(sizes > 0, dtype=jnp.int32)
+
+    def call(self, params, x):
+        shape = x.shape
+        y, _ = self.routed(params, x.reshape(-1, shape[-1]))
+        return y.reshape(shape).astype(x.dtype)

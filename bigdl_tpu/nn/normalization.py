@@ -107,6 +107,27 @@ class LayerNormalization(Module):
         return y * params["weight"] + params["bias"]
 
 
+class RMSNorm(Module):
+    """Root-mean-square norm, no mean and no shift:
+    ``x * rsqrt(mean(x^2) + eps) * weight`` over the last axis. The
+    statistics are taken in float32 whatever ``x`` is stored in; the
+    result comes back in ``x``'s dtype."""
+
+    def __init__(self, hidden_size, eps=1e-5):
+        super().__init__()
+        self.hidden_size = hidden_size
+        self.eps = eps
+
+    def make_params(self, rng, input_spec):
+        return {"weight": jnp.ones((self.hidden_size,))}
+
+    def call(self, params, x):
+        xf = x.astype(jnp.float32)
+        y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                           + self.eps)
+        return (y * params["weight"].astype(jnp.float32)).astype(x.dtype)
+
+
 class SpatialCrossMapLRN(Module):
     """Local response normalization across channels
     (reference ``nn/SpatialCrossMapLRN.scala``)."""

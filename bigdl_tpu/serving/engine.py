@@ -1,7 +1,8 @@
 """ServingEngine: the public continuous-batching inference facade.
 
-``ServingEngine(model, max_slots=8, max_queue=64)`` turns a
-KV-cache-capable causal LM (``models/gpt.py``) into a concurrent
+``ServingEngine(model, max_slots=8, max_queue=64)`` turns a causal LM
+that speaks the model protocol (``serving/protocol.py``:
+``models/gpt.py``, ``models/lfm2.py``) into a concurrent
 serving system: callers ``submit()`` prompts from any thread and stream
 tokens back, while one scheduler thread batches every live request into
 a single masked decode dispatch per token step (see ``slots.py`` /
@@ -20,6 +21,7 @@ import time
 from bigdl_tpu import obs
 from bigdl_tpu.obs import reqtrace
 from bigdl_tpu.serving.paging import PagedSlotManager, PagePoolExhausted
+from bigdl_tpu.serving.protocol import check_model, need
 from bigdl_tpu.serving.scheduler import QueueFullError, Request, Scheduler
 from bigdl_tpu.serving.slots import SlotManager
 
@@ -29,8 +31,14 @@ class ServingEngine:
 
     Parameters
     ----------
-    model: a ``GPTForCausalLM``-style module (``.gpt`` KV-cache
-        primitives + ``._lm_logits``); must not be sequence-parallel.
+    model: a module that speaks the model protocol
+        (``serving/protocol.py``, docs/serving.md): ``init_cache`` /
+        ``prefill`` / ``decode_step`` / ``logits`` and what it tells of
+        itself. Each optional feature below (``paged``, ``spec_tokens``,
+        ``lora``, ``int8_weights``, ``int8_kv``, ``tp`` / ``mesh``,
+        ``kv_snapshot``) is for a model whose ``serving_features`` carry
+        it: asked of another, the constructor raises a ``TypeError``
+        that names the feature.
     params: live parameters; defaults to ``model.params`` (built model).
     max_slots: concurrent in-flight requests (the preallocated cache's
         slot-table size — HBM cost scales with it).
@@ -178,16 +186,13 @@ class ServingEngine:
             else params
         if params is None:
             raise ValueError("setup()/build() the model before serving")
-        if getattr(model, "gpt", None) is None:
-            raise TypeError(
-                "ServingEngine drives GPTForCausalLM-style models (needs "
-                "the .gpt KV-cache primitives)")
-        sp = (model.gpt.layers[0].attn.sequence_parallel
-              if model.gpt.layers else None)
-        if sp is not None:
-            raise ValueError(
-                "serving does not compose with sequence_parallel; build "
-                "the model without it for generation")
+        check_model(model)
+        # asked outright, whatever else is: both are read again, with
+        # their flags, where the paged manager is built
+        if int8_kv:
+            need(model, "int8_kv")
+        if kv_snapshot:
+            need(model, "kv_snapshot")
         self.model = model
         self.default_deadline_s = default_deadline_s
         from bigdl_tpu.models.spec import spec_config
@@ -196,10 +201,13 @@ class ServingEngine:
             # BIGDL_TPU_SPEC_TOKENS sizes the draft (models/spec.py)
             spec_tokens = spec_config()
         self.spec_tokens = max(1, int(spec_tokens))
+        if self.spec_tokens > 1:
+            need(model, "spec_tokens")
         if int8_weights is None:
             int8_weights = get_flag("BIGDL_TPU_INT8_WEIGHTS", False, bool)
         self.int8_weights = bool(int8_weights)
         if self.int8_weights:
+            need(model, "int8_weights")
             from bigdl_tpu.nn.quantized import quantize_params
             params = quantize_params(params)
         # tensor-parallel layout — built AFTER int8 quantization so the
@@ -208,6 +216,7 @@ class ServingEngine:
             tp = get_flag("BIGDL_TPU_SERVING_TP", 0, int)
         tp = int(tp or 0)
         if mesh is not None or tp > 1:
+            need(model, "tp")
             from bigdl_tpu.parallel.layout import ModelLayout, serving_mesh
             layout = ModelLayout(mesh if mesh is not None
                                  else serving_mesh(tp))
@@ -224,6 +233,7 @@ class ServingEngine:
         if lora is None:
             lora = get_flag("BIGDL_TPU_LORA", False, bool)
         if lora:
+            need(model, "lora")
             from bigdl_tpu.serving.adapters import AdapterPool
             if lora_rank is None:
                 lora_rank = get_flag("BIGDL_TPU_LORA_RANK", 8, int)
@@ -252,6 +262,7 @@ class ServingEngine:
             paged = get_flag("BIGDL_TPU_PAGED_KV", False, bool)
         self.paged = bool(paged)
         if self.paged:
+            need(model, "paged")
             if page_size is None:
                 page_size = get_flag("BIGDL_TPU_PAGE_SIZE", 16, int)
             if prefill_chunk is None:
@@ -461,7 +472,7 @@ class ServingEngine:
             trace = reqtrace.mint()
         req.trace = trace
         t = req.prompt.size
-        pmax = self.model.gpt.max_position
+        pmax = self.model.max_position
         if t + req.max_new_tokens > pmax:
             raise ValueError(
                 f"prompt ({t}) + max_new_tokens ({req.max_new_tokens}) "

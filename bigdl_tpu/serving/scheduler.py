@@ -1099,6 +1099,9 @@ class Scheduler:
                               live=len(self._inflight),
                               kv_write=slots.kv_write) as step_span:
                     toks = slots.step()    # (steps_per_sync, max_slots)
+                    # a model with routed experts: ``experts``,
+                    # ``assignments``, ``experts_hit``
+                    step_span.set(**slots.step_attrs)
             except _Halt:
                 raise
             except BaseException as e:
@@ -1158,7 +1161,8 @@ class Scheduler:
                 prefill.set(rows=rows, bucket=bucket,
                             tokens=sum(r.prompt.size + len(r.tokens)
                                        for r in batch),
-                            requests=[r.id for r in batch])
+                            requests=[r.id for r in batch],
+                            **slots.prefill_attrs)
                 for r in batch:
                     r.prefill_bucket = bucket
         except _Halt:

@@ -1,17 +1,21 @@
-"""Fixed-capacity slot manager: ONE preallocated K/V cache, many requests.
+"""Fixed-capacity slot manager: ONE preallocated cache, many requests.
 
 Iteration-level serving (Orca, OSDI '22) needs the decode batch to change
 membership every token without changing any array shape: requests arrive
 and retire at different times, but XLA wants a single executable. The
 slot table delivers that on the PR 3 KV-cache primitives:
 
-- the cache is ``n_layers`` dicts of (S, H, max_position, D) K/V buffers
-  (S = ``max_slots``, dim 0 is the slot table) allocated ONCE at
-  construction — a request borrows one slot row for its lifetime;
+- the cache is whatever the model's ``init_cache`` makes (the model
+  protocol, ``serving/protocol.py``): one dict a layer, every leaf with
+  the slot axis first (S = ``max_slots``) — K and V of (S, H,
+  max_position, D), or fixed-size state such as a convolution's last
+  taps — allocated ONCE at construction; a request borrows one slot row
+  of every leaf for its lifetime;
 - :meth:`admit` prefills up to ``window`` waiting prompts in ONE batched
-  causal forward and scatters their K/V rows + next-token logits into
-  the table (padding rows of a short admission batch scatter to index
-  ``max_slots``, which JAX drops as out-of-bounds);
+  causal forward and scatters their rows of every cache leaf + their
+  next-token logits into the table (padding rows of a short admission
+  batch scatter to index ``max_slots``, which JAX drops as
+  out-of-bounds);
 - :meth:`step` advances ALL slots by ``steps_per_sync`` tokens in a
   single dispatch: per-slot lengths drive per-row cache writes and
   length-masked attention (``parallel.sequence.cached_attention`` with a
@@ -75,10 +79,12 @@ def select_tokens(logits, temps, key, top_k, top_p):
 
 
 class SlotManager:
-    """Slot-table over one preallocated K/V cache (see module docstring).
+    """Slot-table over one preallocated cache (see module docstring).
 
-    ``model`` is a ``GPTForCausalLM``-style module (needs ``.gpt`` with
-    ``init_cache``/``prefill``/``decode_step`` and ``._lm_logits``);
+    ``model`` speaks the serving protocol (``serving/protocol.py``:
+    ``init_cache``/``prefill``/``decode_step``/``logits`` and what it
+    tells of itself); speculation, a layout and an adapter pool are for a
+    model whose ``serving_features`` carry them (the engine checks).
     ``params`` its live parameters. ``window`` is the prefill-batching
     width (admissions per dispatch), ``steps_per_sync`` the number of
     decode steps fused into one dispatch between host syncs (tokens past
@@ -105,6 +111,12 @@ class SlotManager:
     # "kernel" is ``ops/kv_write.py`` in place, "scatter" the plain XLA
     # write (the paged and the speculative steps scatter too)
     kv_write = "scatter"
+    # what a model with routed experts adds to the latest
+    # ``serve/prefill`` and ``serve/step`` span (docs/observability.md);
+    # empty for a model without
+    experts = None
+    prefill_attrs = {}
+    step_attrs = {}
     _stat_keys = ("prefill_traces", "step_traces")
     _obs_name = "serving"
 
@@ -149,9 +161,16 @@ class SlotManager:
         self.last_counts = None
         self.top_k = top_k
         self.top_p = top_p
-        self.max_position = model.gpt.max_position
+        self.max_position = model.max_position
         self.stats = DecodeCounters(*self._stat_keys,
                                     obs_name=self._obs_name)
+        if model.experts_per_token:
+            self.experts = model.expert_product
+            # running sums beside the compile gates: assignments made by
+            # admitted prompts and live slots (a routed layer), and the
+            # steps' ``experts_hit``
+            self.stats["moe_assignments"] = 0
+            self.stats["moe_experts_hit"] = 0.0
         self._seed = int(seed)
         self._resets = 0
         # a failed dispatch may have consumed its DONATED operands (the
@@ -164,7 +183,7 @@ class SlotManager:
         # admission was padded to, for its ``serve/prefill`` span
         self.iter = 0
         self.last_prefill_shape = None
-        self._dtype = params["gpt"]["tok_emb"].dtype
+        self._dtype = model.serving_dtype(params)
         self._alloc()
         self._prefill_fn, self._step_fn = self._build_fns()
         # with request tracing on, AOT-wrap the pair so each executable
@@ -184,6 +203,7 @@ class SlotManager:
         ``out_shardings`` prefix."""
         if self.layout is None:
             return None
+        # a layout is a model's that carries ``tp``: GPT-2's heads
         attn = self.model.gpt.layers[0].attn
         shape = (self.max_slots, attn.n_heads, self.max_position,
                  attn.head_dim)
@@ -192,9 +212,10 @@ class SlotManager:
 
     def _alloc(self):
         model, dtype = self.model, self._dtype
-        self._cache = model.gpt.init_cache(self.max_slots, dtype,
-                                           sharding=self._cache_sharding())
-        self._logits = jnp.zeros((self.max_slots, model.vocab_size), dtype)
+        self._cache = model.init_cache(self.max_slots, dtype,
+                                       sharding=self._cache_sharding())
+        self._logits = jnp.zeros((self.max_slots, model.vocab_size),
+                                 model.logits_dtype or dtype)
         # distinct stream per incarnation so a rebuilt table does not
         # replay the sampled tokens of the one it replaces
         self._key = jax.random.fold_in(jax.random.key(self._seed),
@@ -265,16 +286,21 @@ class SlotManager:
     def _build_fns(self):
         if self.spec_tokens > 1:
             return self._build_spec_fns()
-        model, gpt = self.model, self.model.gpt
+        model = self.model
         stats = self.stats
         n_steps = self.steps_per_sync
         top_k, top_p = self.top_k, self.top_p
         pmax = self.max_position
         wrap = self._wrap_fn()
-        # the table as it was allocated says whether the write kernel
+        cache_dtype = self._dtype
+        # a K table as it was allocated says whether the write kernel
         # applies (a TPU, no mesh, positions minor on the device)
-        in_place = in_place_applies(self._cache[0]["k"], self.layout)
+        in_place = in_place_applies(
+            next(c["k"] for c in self._cache if "k" in c), self.layout)
         self.kv_write = "kernel" if in_place else "scatter"
+        # routed experts: the step also counts the experts its live slots
+        # hit, one number a step beside the tokens
+        routed = bool(model.experts_per_token)
 
         def prefill(params, cache, logits_buf, ids, prompt_len, slot_idx,
                     *adapter):
@@ -284,12 +310,12 @@ class SlotManager:
             # (pre-gathered per-row slab tree,) when a pool is bound.
             stats.tick("prefill_traces")   # trace-time only: counts compiles
             params = wrap(params, adapter)
-            tmp = gpt.init_cache(ids.shape[0], cache[0]["k"].dtype)
-            h_last, tmp = gpt.prefill(params["gpt"], tmp, ids, prompt_len)
-            rows = model._lm_logits(params, h_last)          # (W, vocab)
-            cache = [{"k": c["k"].at[slot_idx].set(t["k"]),
-                      "v": c["v"].at[slot_idx].set(t["v"])}
-                     for c, t in zip(cache, tmp)]
+            tmp = model.init_cache(ids.shape[0], cache_dtype)
+            h_last, tmp = model.prefill(params, tmp, ids, prompt_len)
+            rows = model.logits(params, h_last)              # (W, vocab)
+            # every leaf has the slot axis first, whatever it holds
+            cache = jax.tree_util.tree_map(
+                lambda c, t: c.at[slot_idx].set(t), cache, tmp)
             logits_buf = logits_buf.at[slot_idx].set(
                 rows.astype(logits_buf.dtype))
             return cache, logits_buf
@@ -310,9 +336,15 @@ class SlotManager:
                 # junk the host discards; the clamp keeps its cache writes
                 # and position lookups in bounds near max_position
                 pos = jnp.minimum(lengths, pmax - 1)
-                h, cache = gpt.decode_step(params["gpt"], cache, tok, pos,
-                                           in_place=in_place)
-                logits = model._lm_logits(params, h).astype(logits.dtype)
+                if routed:
+                    h, cache, hit = model.decode_step(
+                        params, cache, tok, pos, in_place=in_place,
+                        live=active)
+                    tok = (tok, hit)
+                else:
+                    h, cache = model.decode_step(params, cache, tok, pos,
+                                                 in_place=in_place)
+                logits = model.logits(params, h).astype(logits.dtype)
                 lengths = lengths + active.astype(lengths.dtype)
                 return (cache, logits, lengths, key), tok
 
@@ -320,7 +352,8 @@ class SlotManager:
             (cache, logits_buf, _, key), toks = lax.scan(
                 one, (cache, logits_buf, lengths, key), None,
                 length=n_steps)
-            return cache, logits_buf, key, toks     # toks (n_steps, S)
+            # toks (n_steps, S); routed: (toks, hits (n_steps,))
+            return cache, logits_buf, key, toks
 
         # ``jax.jit`` names an executable ``jit_`` + its function's name,
         # and the benchmark finds this pair in the device trace as
@@ -344,7 +377,9 @@ class SlotManager:
                         out_shardings=(ckv, repl, repl, repl)))
 
     def _build_spec_fns(self):
-        """Speculative (prefill, step) pair — same host contract shapes
+        """Speculative (prefill, step) pair, for a model that carries
+        ``spec_tokens`` (GPT-2: it reaches ``.gpt``'s ``decode_chunk``,
+        which the protocol does not name) — same host contract shapes
         as the sequential pair except the step's token block is
         ``(steps_per_sync * gamma, max_slots)`` with per-slot commit
         counts: each of ``steps_per_sync`` scan iterations proposes
@@ -526,6 +561,10 @@ class SlotManager:
             self.poisoned = True
             raise
         self.stats.dispatched()
+        if self.experts is not None:
+            asked = self.model.experts_per_token * sum(a.size for a in arrs)
+            self.stats.add("moe_assignments", asked)
+            self.prefill_attrs = {"assignments": asked}
         for i, s in enumerate(assigned):
             self.lengths[s] = lens[i]
             self.active[s] = True
@@ -569,6 +608,14 @@ class SlotManager:
         # ONE readback per block: the host blocked on the device
         with obs.leaf_span("serve/step.readback", iter=self.iter):
             toks = jax.device_get(toks)
+        if self.experts is not None:
+            toks, hits = toks
+            asked = int(self.active.sum()) * self.model.experts_per_token
+            self.stats.add("moe_experts_hit", float(hits.sum()))
+            self.stats.add("moe_assignments", self.steps_per_sync * asked)
+            self.step_attrs = {"experts": self.experts,
+                               "assignments": asked,
+                               "experts_hit": float(hits.mean())}
         self.lengths[self.active] = np.minimum(
             self.lengths[self.active] + self.steps_per_sync,
             self.max_position)

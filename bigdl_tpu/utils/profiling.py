@@ -95,7 +95,11 @@ class DecodeCounters(dict):
             counters = ref()
             if counters is None:
                 return None   # instance gone: unregister this collector
-            samples = [("bigdl_decode_traces",
+            # ``*_traces`` count compilations; any other key is a running
+            # sum its owner keeps beside them (the slot table's
+            # ``moe_assignments`` / ``moe_experts_hit``)
+            samples = [("bigdl_decode_traces" if k.endswith("_traces")
+                        else "bigdl_decode_sums",
                         {"source": source, "kind": k}, v)
                        for k, v in counters.items() if k != "dispatches"]
             samples.append(("bigdl_decode_dispatches", {"source": source},
@@ -132,6 +136,11 @@ class DecodeCounters(dict):
     def dispatched(self, n=1):
         """Count ``n`` executable launches (call on the host per call)."""
         self["dispatches"] += n
+
+    def add(self, name, value):
+        """Add to a running sum that its owner created beside the gates
+        (host side, the owner's thread)."""
+        self[name] += value
 
     def add_cost(self, flops, hbm_bytes):
         """Accumulate one dispatch's modeled device work (host side;

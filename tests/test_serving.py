@@ -370,7 +370,7 @@ def test_engine_rejects_unbuilt_and_non_kv_models():
         ServingEngine(m)
     from bigdl_tpu import nn
     mlp = nn.Sequential(nn.Linear(4, 4)).build(0, (2, 4))
-    with pytest.raises(TypeError, match="KV-cache"):
+    with pytest.raises(TypeError, match="protocol"):
         ServingEngine(mlp)
 
 
