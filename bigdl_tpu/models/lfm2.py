@@ -108,6 +108,10 @@ class GroupedQueryAttention(Module):
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bgrqk,bgkd->bgrqd", p.astype(dt), v,
                        preferred_element_type=jnp.float32)
+        return self._project(params, o)
+
+    def _project(self, params, o):
+        """The heads' outputs ``o`` (B, kv, rep, T, hd) through ``wo``."""
         b, _, _, t, _ = o.shape
         return mm(o.transpose(0, 3, 1, 2, 4).reshape(b, t, -1), params["wo"])
 
@@ -135,19 +139,27 @@ class GroupedQueryAttention(Module):
         return self._attend(params, q, k, v,
                             jnp.tril(jnp.ones((t, t), bool))), cache
 
-    def decode_step(self, params, x, cache, pos, in_place=False):
+    def decode_step(self, params, x, cache, pos, in_place=False, read=None):
         """One position a row: ``x`` (B, hidden), ``pos`` (B,) the
-        position each row writes and attends up to. ``in_place`` as in
-        ``parallel.sequence``'s attention: the table's owner says that
-        ``ops/kv_write.py`` takes the write."""
+        position each row writes and attends up to. ``in_place`` and
+        ``read`` as in ``parallel.sequence``'s attention: the table's
+        owner says that ``ops/kv_write.py`` takes the write, and hands
+        over the per-row counts that ``ops/decode_attention.py`` reads
+        (None: every position of every row, masked)."""
         from bigdl_tpu.ops.kv_write import kv_write, plain_write
         pos = jnp.asarray(pos, jnp.int32)
         q, k, v = self._qkv(params, x[:, None], pos[:, None])
         write = kv_write if in_place else plain_write
         kc, vc = write(cache["k"], cache["v"], k.astype(cache["k"].dtype),
                        v.astype(cache["v"].dtype), pos)
-        seen = jnp.arange(kc.shape[2])[None, :] <= pos[:, None]   # (B, S)
-        out = self._attend(params, q, kc, vc, seen[:, None, None, None, :])
+        if read is None:
+            seen = jnp.arange(kc.shape[2])[None, :] <= pos[:, None]  # (B, S)
+            out = self._attend(params, q, kc, vc,
+                               seen[:, None, None, None, :])
+        else:
+            from bigdl_tpu.ops.decode_attention import decode_attention
+            out = self._project(params, decode_attention(
+                q[:, :, :, 0], kc, vc, read)[:, :, :, None])
         return out[:, 0], {"k": kc, "v": vc}
 
 
@@ -228,14 +240,14 @@ class LFM2Block(Module):
         real = jnp.arange(x.shape[1])[None, :] < prompt_len[:, None]
         return self._ffn(params, x + y, real.reshape(-1))[0], cache
 
-    def decode_step(self, params, cache, x, pos, in_place, live):
+    def decode_step(self, params, cache, x, pos, in_place, live, read):
         u = self.op_norm.call(params["op_norm"], x)
         p = params[self._names[0]]
         if self.kind == CONV:
             y, state = self.op.decode_step(p, u, cache["conv"])
             cache = {"conv": state}
         else:
-            y, cache = self.op.decode_step(p, u, cache, pos, in_place)
+            y, cache = self.op.decode_step(p, u, cache, pos, in_place, read)
         x, hit = self._ffn(params, x + y, live)
         return x, cache, hit
 
@@ -339,18 +351,20 @@ class LFM2ForCausalLM(Module):
         return self.out_norm.call(params["out_norm"], h), new_cache
 
     def decode_step(self, params, cache, tok, pos, in_place=False,
-                    live=None):
+                    live=None, read=None):
         """One token a row at position ``pos`` (B,): ``(h, cache)`` with
         ``h`` (B, hidden) the final-norm rows. Given ``live`` (B,) bool
         the routed layers leave the dead rows out (their ``h`` is junk
         that nobody reads), and it also returns, third, the mean over
         the routed layers of how many experts the live rows chose
-        (float32 scalar)."""
+        (float32 scalar). ``in_place`` and ``read`` are the slot table's
+        words to the attention layers
+        (``GroupedQueryAttention.decode_step``)."""
         h = self._embed(params, tok)
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tok.shape)
         new_cache, hits = [], []
         for layer, p, c in zip(self.layers, params["layers"], cache):
-            h, c, hit = layer.decode_step(p, c, h, pos, in_place, live)
+            h, c, hit = layer.decode_step(p, c, h, pos, in_place, live, read)
             new_cache.append(c)
             if hit is not None:
                 hits.append(hit)

@@ -492,7 +492,7 @@ class MultiHeadAttention:
                 return qmatmul(out, params["wo"]), cache
 
             def decode_step(self, params, x, cache, index,
-                            in_place=False):
+                            in_place=False, read=None):
                 """Incremental mode: attend ONE query token (x: (B, 1, H))
                 against the cache, after writing its own K/V at slot
                 ``index``. The buffers keep their shapes, so the step is
@@ -521,7 +521,16 @@ class MultiHeadAttention:
                   or under a layout the step is the scatter's.
 
                 A row out of bounds is clamped on every path (the
-                serving step clamps its own beforehand)."""
+                serving step clamps its own beforehand).
+
+                The read takes one of two. ``read`` None:
+                :func:`cached_attention` scores every position of every
+                row and masks. ``read`` a (B,) vector of counts, which
+                the table's owner hands over where ``in_place`` holds
+                (``index + 1`` for a live row, 0 for a free slot): one
+                Pallas call (``ops/decode_attention.py``) that reads
+                row ``b``'s first ``read[b]`` positions in blocks of 128
+                and no others; a row of count 0 comes back as zeros."""
                 b, t, hs = x.shape
                 q, k, v = self._qkv(params, x)
                 idx = jnp.asarray(index, jnp.int32)
@@ -536,7 +545,12 @@ class MultiHeadAttention:
                     from bigdl_tpu.ops.kv_write import kv_write, plain_write
                     write = kv_write if in_place else plain_write
                     kc, vc = write(cache["k"], cache["v"], k, v, idx)
-                out = cached_attention(q, kc, vc, idx + 1)
+                if read is None:
+                    out = cached_attention(q, kc, vc, idx + 1)
+                else:
+                    from bigdl_tpu.ops.decode_attention import \
+                        decode_attention
+                    out = decode_attention(q, kc, vc, read).astype(q.dtype)
                 out = out.transpose(0, 2, 1, 3).reshape(b, t, hs)
                 return qmatmul(out, params["wo"]), {"k": kc, "v": vc}
 

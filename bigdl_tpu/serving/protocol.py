@@ -25,11 +25,15 @@ contract in prose):
     hidden row at each prompt's last real position, and ``cache`` (W
     rows, as ``init_cache(W, ...)`` made it) holding each row's state as
     of ITS length, whatever the padding holds.
-``decode_step(params, cache, tok, pos, in_place=False) -> (h, cache)``
+``decode_step(params, cache, tok, pos, in_place=False, read=None) -> (h, cache)``
     one token a slot, slot ``b`` at position ``pos[b]``. ``in_place``
     is the table's word that ``ops/kv_write.py`` applies to its K/V
-    leaves. A model with routed experts (``experts_per_token`` > 0) also
-    takes ``live=`` (slots,) bool: its routed layers leave the dead
+    leaves. ``read`` is None, or (slots,) int32 where the table also
+    takes ``ops/decode_attention.py``: the positions slot ``b``'s
+    attention reads, ``pos[b] + 1`` for a live slot and 0 for a free
+    one, whose row then comes back as junk nobody reads. A model with
+    routed experts (``experts_per_token`` > 0) also takes ``live=``
+    (slots,) bool: its routed layers leave the dead
     slots out, and it returns, third, the mean over those layers of how
     many experts the live slots chose.
 ``logits(params, h)``

@@ -1091,13 +1091,18 @@ class Scheduler:
                     self._recover(list(self._inflight.values()), e)
                     continue
             pre_lengths = slots.lengths.copy()
+            attn_blocks, attn_blocks_table = slots.attn_blocks()
             try:
                 fault_point("serving.step",
                             requests=tuple(r.id
                                            for r in self._inflight.values()))
                 with obs.span("serve/step", iter=it,
                               live=len(self._inflight),
-                              kv_write=slots.kv_write) as step_span:
+                              kv_write=slots.kv_write,
+                              attn_read=slots.attn_read,
+                              attn_blocks=attn_blocks,
+                              attn_blocks_table=attn_blocks_table
+                              ) as step_span:
                     toks = slots.step()    # (steps_per_sync, max_slots)
                     # a model with routed experts: ``experts``,
                     # ``assignments``, ``experts_hit``

@@ -44,6 +44,7 @@ from jax import lax
 from bigdl_tpu import obs
 from bigdl_tpu.models.gpt import prompt_bucket, sample_logits
 from bigdl_tpu.obs import reqtrace
+from bigdl_tpu.ops import decode_attention
 from bigdl_tpu.ops.kv_write import in_place_applies
 from bigdl_tpu.resilience.faults import fault_point
 from bigdl_tpu.utils.profiling import CostStampedJit, DecodeCounters
@@ -111,6 +112,12 @@ class SlotManager:
     # "kernel" is ``ops/kv_write.py`` in place, "scatter" the plain XLA
     # write (the paged and the speculative steps scatter too)
     kv_write = "scatter"
+    # how the decode step's attention reads the cache, fixed with it and
+    # stamped beside it: "kernel" is ``ops/decode_attention.py``, each
+    # live slot's own blocks of 128 positions and nothing of a free slot;
+    # "masked" scores the whole table and masks (the paged and the
+    # speculative steps too)
+    attn_read = "masked"
     # what a model with routed experts adds to the latest
     # ``serve/prefill`` and ``serve/step`` span (docs/observability.md);
     # empty for a model without
@@ -295,9 +302,12 @@ class SlotManager:
         cache_dtype = self._dtype
         # a K table as it was allocated says whether the write kernel
         # applies (a TPU, no mesh, positions minor on the device)
-        in_place = in_place_applies(
-            next(c["k"] for c in self._cache if "k" in c), self.layout)
+        table = next(c["k"] for c in self._cache if "k" in c)
+        in_place = in_place_applies(table, self.layout)
         self.kv_write = "kernel" if in_place else "scatter"
+        # ... and whether the length-bounded attention reads it
+        bounded = decode_attention.applies(table, self.layout)
+        self.attn_read = "kernel" if bounded else "masked"
         # routed experts: the step also counts the experts its live slots
         # hit, one number a step beside the tokens
         routed = bool(model.experts_per_token)
@@ -336,14 +346,18 @@ class SlotManager:
                 # junk the host discards; the clamp keeps its cache writes
                 # and position lookups in bounds near max_position
                 pos = jnp.minimum(lengths, pmax - 1)
+                # a live slot's attention reads up to the position it
+                # writes, a free slot's nothing
+                read = jnp.where(active, pos + 1, 0) if bounded else None
                 if routed:
                     h, cache, hit = model.decode_step(
                         params, cache, tok, pos, in_place=in_place,
-                        live=active)
+                        live=active, read=read)
                     tok = (tok, hit)
                 else:
                     h, cache = model.decode_step(params, cache, tok, pos,
-                                                 in_place=in_place)
+                                                 in_place=in_place,
+                                                 read=read)
                 logits = model.logits(params, h).astype(logits.dtype)
                 lengths = lengths + active.astype(lengths.dtype)
                 return (cache, logits, lengths, key), tok
@@ -642,6 +656,17 @@ class SlotManager:
         self.spec_accepted += int(tele[1])
         self.spec_rollbacks += int(tele[2])
         return toks
+
+    def attn_blocks(self):
+        """``(read, table)``: the blocks of 128 positions that the next
+        decode step's attention reads a layer, and those of the whole
+        table; from the host's own ``lengths`` and ``active``. The masked
+        read reads the table."""
+        table = self.max_slots * -(-self.max_position
+                                   // decode_attention.BLOCK)
+        if self.attn_read != "kernel":
+            return table, table
+        return decode_attention.blocks_read(self.lengths, self.active), table
 
     def retire(self, slot):
         """Free a slot row (host bookkeeping only — the stale K/V is

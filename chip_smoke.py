@@ -13,6 +13,8 @@ at the full width of models the repo lists, on whatever TPU JAX reports:
   XLA twin at that model's shapes.
 - **kv_write**: the decode step's in-place K/V write against the plain
   write at the GPT-2 medium serving cell's table, bit for bit.
+- **decode_attention**: the decode step's length-bounded attention against
+  the whole-table read in true float32, at the two serving cells' tables.
 - **train**: ResNet-50 NHWC, bf16 compute, batch 256, a few steps through
   ``Optimizer(...).optimize()`` on one repeated seeded batch.
 - **four chips** (only when JAX reports four or more): the train leg then
@@ -177,6 +179,7 @@ def serve_leg(model_kw, params, prompt_waves, n_new, tol, engine_kw=None,
             metrics = engine.metrics()
             placement = _placement(engine, model)
             kv_write = engine.slots.kv_write
+            attn_read = engine.slots.attn_read
         finally:
             engine.shutdown()
 
@@ -228,6 +231,7 @@ def serve_leg(model_kw, params, prompt_waves, n_new, tol, engine_kw=None,
             "precision_gap": round(gap, 5), "tolerance": tol,
             "second_pass_identical": f"{same}/{len(greedy)}",
             "tp_degree": metrics["tp_degree"], "kv_write": kv_write,
+            "attn_read": attn_read,
             **placement}
 
 
@@ -493,6 +497,61 @@ def kv_write_leg(slots=48, heads=16, seq=1024, head_dim=64,
             "differing_elements": 0, "selected": selected}
 
 
+def decode_attention_leg(tables=(((48, 16, 1024, 64), 1, "float32"),
+                                 ((96, 8, 2048, 64), 4, "bfloat16")),
+                         tol=2e-5, interpret=False):
+    """``ops/decode_attention.py`` against the read it replaces, the
+    softmax over every position under a length mask with true-float32
+    products, at the GPT-2 medium cell's table and at LFM2's (bfloat16,
+    four queries a K/V head): slots at both sides of a block's edge, at
+    the table's end, and free (count 0: zeros). A Mosaic that accepts the
+    kernel and reads a wrong block, or past a length, is caught outside
+    the benchmark. Also that the table as allocated selects the kernel
+    here (``applies``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.ops.decode_attention import applies, decode_attention
+
+    def whole_table(q, k, v, counts):
+        k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+        s = jnp.einsum("bgrd,bgkd->bgrk", q, k,
+                       precision="highest") * q.shape[-1] ** -0.5
+        seen = (jnp.arange(k.shape[2])[None, :]
+                < counts[:, None])[:, None, None, :]
+        p = jnp.where(seen, jax.nn.softmax(jnp.where(seen, s, -jnp.inf), -1),
+                      0.0)          # a free slot's row is 0/0
+        return jnp.einsum("bgrk,bgkd->bgrd", p, v, precision="highest")
+
+    t_start = time.perf_counter()
+    selected, errors = {}, {}
+    for shape, reps, dtype in tables:
+        slots, heads, seq, head_dim = shape
+        counts = np.random.default_rng(0).integers(1, seq + 1, slots)
+        edges = [1, 127, 128, 129, seq, 0, 0, seq - 1][:slots]
+        counts[:len(edges)] = edges
+        counts = jnp.asarray(counts, jnp.int32)
+        draw = jax.jit(lambda k, shape: jax.random.normal(
+            k, shape, jnp.float32).astype(dtype), static_argnums=1)
+        keys = jax.random.split(jax.random.key(11), 3)
+        q = jax.random.normal(keys[0], (slots, heads, reps, head_dim))
+        k_table, v_table = draw(keys[1], shape), draw(keys[2], shape)
+        selected[dtype] = applies(k_table)
+        _require(selected[dtype] or interpret,
+                 f"decode_attention {dtype}: the table as allocated does "
+                 f"not select the kernel (layout {k_table.format.layout})")
+        got = jax.jit(functools.partial(decode_attention,
+                                        interpret=interpret))(
+            q, k_table, v_table, counts)
+        want = jax.jit(whole_table)(q, k_table, v_table, counts)
+        errors[dtype] = float(jnp.max(jnp.abs(got - want)))
+        _require(errors[dtype] <= tol,
+                 f"decode_attention {dtype}: {errors[dtype]} from the "
+                 f"whole-table read, over {tol}")
+    return {"ok": True, "setup_s": round(time.perf_counter() - t_start, 2),
+            "tolerance": tol, "errors": errors, "selected": selected}
+
+
 # ------------------------------------------------------------------ train --
 def train_leg(model, x_shape, n_class, steps, compute_dtype, seed=0):
     """A few optimizer steps on one repeated seeded batch through the
@@ -682,6 +741,7 @@ def main():
             flags=kernel_flags)),
         ("kernels", kernels_leg),
         ("kv_write", kv_write_leg),
+        ("decode_attention", decode_attention_leg),
         ("train", train_resnet50),
     ]
     if device["count"] >= 4:

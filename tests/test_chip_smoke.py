@@ -62,6 +62,15 @@ def test_kv_write_leg():
     assert rec["selected"] == {"float32": False, "bfloat16": False}
 
 
+def test_decode_attention_leg():
+    rec = chip_smoke.decode_attention_leg(
+        tables=(((8, 2, 256, 32), 1, "float32"),
+                ((8, 2, 256, 32), 4, "bfloat16")), interpret=True)
+    assert rec["ok"] and max(rec["errors"].values()) <= rec["tolerance"]
+    # not on a TPU: the serving step would keep the masked read
+    assert rec["selected"] == {"float32": False, "bfloat16": False}
+
+
 def test_train_leg(multi_device_cpu):
     from bigdl_tpu.models.resnet import ResNet
     rec = chip_smoke.train_leg(

@@ -141,6 +141,60 @@ def test_a_prefill_counts_what_it_asked_for_and_what_it_computed(served):
     assert sum(s.attrs["retired"] for s in delivers) == len(handles)
 
 
+def test_a_step_says_how_its_attention_read_and_how_much(served):
+    """Off the chip the read is masked, which is the whole table: four
+    slots of one block of 128 positions each (64 positions, rounded up)."""
+    _, spans = served
+    steps = [s for s in spans if s.name == "serve/step"]
+    assert steps
+    for s in steps:
+        assert s.attrs["attn_read"] == "masked"
+        assert s.attrs["attn_blocks"] == s.attrs["attn_blocks_table"] == 4
+
+
+def test_attn_blocks_follow_admissions_and_retirements(monkeypatch):
+    """With the length-bounded kernel (interpreted; the table's word is
+    overridden, which no CPU table gives) a step reads the blocks of its
+    live slots only, ``ceil((length + 1) / 128)`` each: one request alone
+    crosses a block's edge, then three leave one after another."""
+    import numpy as np
+    from bigdl_tpu.serving import slots as slots_mod
+    monkeypatch.setattr(slots_mod, "in_place_applies", lambda *a: True)
+    monkeypatch.setattr(slots_mod.decode_attention, "applies",
+                        lambda *a: True)
+    model = GPTForCausalLM(vocab_size=61, hidden_size=32, n_layers=2,
+                           n_heads=4, max_position=256)
+    params, _ = model.setup(jax.random.PRNGKey(3), None)
+    tracer = obs.default_tracer()
+    tracer.clear()
+    with ServingEngine(model, params, max_slots=3) as engine:
+        loop = engine.scheduler._thread.ident
+        assert engine.slots.attn_read == "kernel"
+        long = np.arange(126, dtype=np.int32) % 61
+        engine.submit(long, 5).result(timeout=300)
+        alone = [s.attrs for s in tracer.spans()
+                 if s.thread_id == loop and s.name == "serve/step"]
+        tracer.clear()
+        handles = [engine.submit(PROMPTS[i], n)
+                   for i, n in enumerate((2, 4, 6))]
+        for h in handles:
+            h.result(timeout=300)
+    three = [s.attrs for s in tracer.spans()
+             if s.thread_id == loop and s.name == "serve/step"]
+    for a in alone + three:
+        assert a["attn_read"] == "kernel"
+        assert a["attn_blocks_table"] == 3 * 2
+    # lengths 126, 127 read one block, 128 and on two
+    assert [a["live"] for a in alone] == [1] * len(alone)
+    blocks = [a["attn_blocks"] for a in alone]
+    assert blocks == sorted(blocks) and blocks[:2] == [1, 1]
+    assert blocks[-1] == 2
+    # short streams read a block each: the count is the live slots'
+    assert all(a["attn_blocks"] == a["live"] for a in three)
+    assert {a["attn_blocks"] for a in three} >= {1, 2, 3}
+    assert three[-1]["attn_blocks"] == 1
+
+
 def test_step_seconds_are_the_step_spans():
     """One interval, one clock read: the scheduler's ``step_seconds`` is
     the sum of its ``serve/step`` spans, not a second timing of them."""
