@@ -9,8 +9,6 @@ from bigdl_tpu.models.rnn import SimpleRNN, PTBModel  # noqa: F401
 from bigdl_tpu.models.autoencoder import Autoencoder  # noqa: F401
 from bigdl_tpu.models.alexnet import AlexNet, AlexNet_OWT  # noqa: F401
 from bigdl_tpu.models.transformer import (  # noqa: F401
-    BERT, BertForMLM, TransformerEncoderLayer, bert_base,
-    bert_mlm_flops_per_token)
+    BERT, BertForMLM, TransformerEncoderLayer, bert_base)
 from bigdl_tpu.models.gpt import (  # noqa: F401
-    GPT, GPTForCausalLM, TransformerDecoderBlock, gpt2_small,
-    gpt_flops_per_token)
+    GPT, GPTForCausalLM, TransformerDecoderBlock, gpt2_small)

@@ -774,12 +774,3 @@ class GPTForCausalLM(Module):
 def gpt2_small(**kw):
     """GPT-2 124M config (12L, 768H, 12 heads, 1024 ctx)."""
     return GPTForCausalLM(**kw)
-
-
-def gpt_flops_per_token(n_layers=12, h=768, s=1024, vocab=50257,
-                        inter=None):
-    """Analytic forward FLOPs/token (QKV+O 8h^2, FFN 2*4h*inter per the
-    two matmuls, attention matmuls 4sh, tied vocab projection 2hV)."""
-    inter = inter or 4 * h
-    per_layer = 8 * h * h + 4 * h * inter + 4 * s * h
-    return n_layers * per_layer + 2 * h * vocab

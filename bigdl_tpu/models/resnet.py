@@ -5,8 +5,9 @@ and ImageNet bottleneck ResNet-18/34/50/101/152 with shortcut type A/B/C.
 Built as a Graph of SpatialConvolution/BatchNorm/ReLU — all MXU-shaped convs
 fused by XLA. ``format`` selects the image layout: NCHW matches the
 reference's default; NHWC is the TPU-preferred layout (channels ride the
-128-wide lanes with no relayout) and is what ``bench.py`` uses. The default
-comes from ``Engine.default_data_format()`` (BIGDL_TPU_ENABLE_NHWC).
+128-wide lanes with no relayout) and is what the benchmark's training cell
+uses. The default comes from ``Engine.default_data_format()``
+(BIGDL_TPU_ENABLE_NHWC).
 """
 
 from __future__ import annotations

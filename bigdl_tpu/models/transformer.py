@@ -138,7 +138,7 @@ class BertForMLM(Module):
     """BERT encoder + dense MLM head producing (B*T, vocab) logits — the
     pretraining configuration (pair with ``CrossEntropyCriterion`` on
     flattened token labels; use padding_value to mask unpredicted
-    positions). This is the flagship compute-bound model for bench.py."""
+    positions). ``examples/bert_mlm_pretrain.py`` trains it."""
 
     def __init__(self, vocab_size=30522, hidden_size=768, n_layers=12,
                  n_heads=12, max_position=512, **kw):
@@ -159,16 +159,6 @@ class BertForMLM(Module):
                                training=training, rng=rng)
         logits = self.head.call(params["head"], h)
         return logits.reshape(-1, self.vocab_size), state
-
-
-def bert_mlm_flops_per_token(n_layers=12, h=768, s=512, vocab=30522,
-                             inter=None):
-    """Analytic forward FLOPs/token for ``BertForMLM`` (standard transformer
-    accounting: QKV+O projections 8h^2, FFN 4h*inter*2, attention matmuls
-    4sh, MLM vocab projection 2hV; embedding lookups ignored)."""
-    inter = inter or 4 * h
-    per_layer = 8 * h * h + 4 * h * inter + 4 * s * h
-    return n_layers * per_layer + 2 * h * vocab
 
 
 def make_sp_train_step(model, criterion, optim_method, mesh,

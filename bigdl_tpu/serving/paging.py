@@ -56,10 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from bigdl_tpu.obs import reqtrace
 from bigdl_tpu.resilience.faults import FaultError, fault_point
 from bigdl_tpu.serving.slots import SlotManager, select_tokens
-from bigdl_tpu.utils.profiling import CostStampedJit
 
 logger = logging.getLogger("bigdl_tpu.serving")
 
@@ -455,11 +453,6 @@ class PagedSlotManager(SlotManager):
         else:
             self._copy_fn = jax.jit(copy, donate_argnums=(0,),
                                     out_shardings=pool_sh)
-        if reqtrace.enabled():
-            # cost-stamped like the (chunk, step) pair the base
-            # __init__ wraps: COW copies count toward the bandwidth
-            # gauges too. Same one-trace-per-signature compile behavior.
-            self._copy_fn = CostStampedJit(self._copy_fn, counters=stats)
         if self.spec_tokens > 1:
             return self._build_spec_fns()
         model, gpt = self.model, self.model.gpt
@@ -967,9 +960,6 @@ class PagedSlotManager(SlotManager):
                 # the scatter lands each chip's head slice in place
                 self._load_fn = jax.jit(load, donate_argnums=(0,),
                                         out_shardings=pool_sh)
-            if reqtrace.enabled():
-                self._load_fn = CostStampedJit(self._load_fn,
-                                               counters=stats)
         try:
             self._pools = self._load_fn(
                 self._pools, np.asarray(pages, np.int32), stacked)

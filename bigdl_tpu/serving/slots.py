@@ -43,11 +43,10 @@ from jax import lax
 
 from bigdl_tpu import obs
 from bigdl_tpu.models.gpt import prompt_bucket, sample_logits
-from bigdl_tpu.obs import reqtrace
 from bigdl_tpu.ops import decode_attention
 from bigdl_tpu.ops.kv_write import in_place_applies
 from bigdl_tpu.resilience.faults import fault_point
-from bigdl_tpu.utils.profiling import CostStampedJit, DecodeCounters
+from bigdl_tpu.utils.profiling import DecodeCounters
 
 
 def select_tokens(logits, temps, key, top_k, top_p):
@@ -193,16 +192,6 @@ class SlotManager:
         self._dtype = model.serving_dtype(params)
         self._alloc()
         self._prefill_fn, self._step_fn = self._build_fns()
-        # with request tracing on, AOT-wrap the pair so each executable
-        # carries its compile-time cost_analysis flops/bytes into the
-        # live MFU gauges. Trace/tick counts are identical (lower()
-        # traces once per signature, exactly like the lazy jit); with
-        # the flag off the raw jit pair runs byte-identically.
-        if reqtrace.enabled():
-            self._prefill_fn = CostStampedJit(self._prefill_fn,
-                                              counters=self.stats)
-            self._step_fn = CostStampedJit(self._step_fn,
-                                           counters=self.stats)
 
     def _cache_sharding(self):
         """The dense cache's fitted ``NamedSharding`` (head axis over
@@ -597,8 +586,8 @@ class SlotManager:
         holds each slot's committed count — callers read column ``s``
         up to ``last_counts[s]``."""
         try:
-            # argument hand-over, the call and (with request tracing on)
-            # ``CostStampedJit``, until the executable's call returns
+            # argument hand-over and the call, until the executable's call
+            # returns
             with obs.leaf_span("serve/step.dispatch", iter=self.iter):
                 extra = self._adapter_args(self.adapter_slots)
                 if self.spec_tokens > 1:

@@ -63,8 +63,8 @@ logger = logging.getLogger("bigdl_tpu")
 #   BIGDL_TPU_ANOMALY_WINDOW        rolling-median window in steps for the
 #                                   anomaly detector (default 64)
 #   BIGDL_TPU_REQ_TRACE             "0" -> disable per-request tracing,
-#                                   the flight recorder and MFU cost
-#                                   stamping (default on; host-side only
+#                                   the flight recorder and exemplars
+#                                   (default on; host-side only
 #                                   — docs/observability.md)
 #   BIGDL_TPU_REQ_TRACE_CAPACITY    per-request timeline ring size,
 #                                   default 256 events (oldest fall off,

@@ -60,15 +60,6 @@ class DecodeCounters(dict):
     forbidden (the ``span-in-jit`` lint rule); the registry samples the
     dict from the scrape thread instead. Registration holds only a
     weakref — dead instances prune themselves at the next scrape.
-
-    Cost accounting rides the same instance as plain *attributes*
-    (``flops`` / ``hbm_bytes``, fed by :meth:`add_cost` from
-    :class:`CostStampedJit` dispatches) — attributes, not dict keys,
-    because the dict IS the public counter namespace the collector and
-    the compile-gate tests enumerate. When costs are flowing the
-    collector derives ``bigdl_device_flops_per_sec`` /
-    ``bigdl_hbm_bytes_per_sec`` rates between scrapes and, when the
-    device kind has a known peak, a live ``bigdl_mfu`` gauge.
     """
 
     _obs_seq = None  # lazily an itertools.count (shared across instances)
@@ -76,8 +67,6 @@ class DecodeCounters(dict):
     def __init__(self, *trace_keys, obs_name=None):
         super().__init__({k: 0 for k in trace_keys})
         self["dispatches"] = 0
-        self.flops = 0.0
-        self.hbm_bytes = 0.0
         if obs_name is not None:
             self._register_obs(obs_name)
 
@@ -89,7 +78,6 @@ class DecodeCounters(dict):
             DecodeCounters._obs_seq = itertools.count()
         source = f"{obs_name}-{next(DecodeCounters._obs_seq)}"
         ref = weakref.ref(self)
-        rate_state = {}
 
         def collect():
             counters = ref()
@@ -104,27 +92,6 @@ class DecodeCounters(dict):
                        for k, v in counters.items() if k != "dispatches"]
             samples.append(("bigdl_decode_dispatches", {"source": source},
                             counters["dispatches"]))
-            if counters.flops > 0.0:
-                lbl = {"source": source}
-                samples.append(("bigdl_device_flops", lbl, counters.flops))
-                samples.append(("bigdl_hbm_bytes", lbl,
-                                counters.hbm_bytes))
-                now = time.monotonic()
-                prev = rate_state.get("prev")
-                rate_state["prev"] = (now, counters.flops,
-                                      counters.hbm_bytes)
-                if prev is not None and now > prev[0]:
-                    dt = now - prev[0]
-                    flops_rate = max(0.0, counters.flops - prev[1]) / dt
-                    samples.append(("bigdl_device_flops_per_sec", lbl,
-                                    flops_rate))
-                    samples.append(("bigdl_hbm_bytes_per_sec", lbl,
-                                    max(0.0,
-                                        counters.hbm_bytes - prev[2]) / dt))
-                    peak = device_peak_flops()
-                    if peak:
-                        samples.append(("bigdl_mfu", lbl,
-                                        flops_rate / peak))
             return samples
 
         obs.default_registry().register_collector(collect)
@@ -141,120 +108,6 @@ class DecodeCounters(dict):
         """Add to a running sum that its owner created beside the gates
         (host side, the owner's thread)."""
         self[name] += value
-
-    def add_cost(self, flops, hbm_bytes):
-        """Accumulate one dispatch's modeled device work (host side;
-        fed by :class:`CostStampedJit` from the executable's
-        compile-time ``cost_analysis``)."""
-        self.flops += flops
-        self.hbm_bytes += hbm_bytes
-
-
-# Peak dense bf16 FLOPS per chip by device kind, for the live MFU gauge
-# (public TPU spec-sheet numbers). Unknown kinds (CPU fallback, new
-# hardware) return None and the MFU gauge is omitted, never fabricated.
-_PEAK_FLOPS = {
-    "tpu v2": 45e12,
-    "tpu v3": 123e12,
-    "tpu v4": 275e12,
-    "tpu v4 lite": 138e12,
-    "tpu v5": 459e12,
-    "tpu v5p": 459e12,
-    "tpu v5 lite": 197e12,
-    "tpu v5e": 197e12,
-    "tpu v6 lite": 918e12,
-    "tpu v6e": 918e12,
-}
-_peak_cache = []
-
-
-def device_peak_flops():
-    """Peak dense bf16 FLOPS of ``jax.devices()[0]``'s kind, or None
-    when the kind is unknown (memoized after the first lookup)."""
-    if not _peak_cache:
-        try:
-            kind = jax.devices()[0].device_kind
-        except Exception:
-            kind = ""
-        _peak_cache.append(_PEAK_FLOPS.get(str(kind).strip().lower()))
-    return _peak_cache[0]
-
-
-def _executable_cost(compiled):
-    """(flops, bytes_accessed) from a compiled executable's
-    ``cost_analysis`` — 0.0s when the backend reports nothing (the
-    gauges then simply stay silent)."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return 0.0, 0.0
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    if not isinstance(ca, dict):
-        return 0.0, 0.0
-    try:
-        return (float(ca.get("flops", 0.0) or 0.0),
-                float(ca.get("bytes accessed", 0.0) or 0.0))
-    except (TypeError, ValueError):
-        return 0.0, 0.0
-
-
-class CostStampedJit:
-    """A ``jax.jit`` wrapper that AOT-compiles per argument-shape
-    signature and stamps each executable with its compile-time
-    ``cost_analysis()`` flops/bytes, accumulating them into a
-    :class:`DecodeCounters` on every dispatch — the input to the live
-    ``bigdl_mfu``/bandwidth gauges.
-
-    Compile behavior is identical to the lazy jit it replaces:
-    ``lower(*args)`` traces exactly once per new signature (any
-    ``tick`` inside the body fires there, so the compile-gate tests
-    see the same counts), and the cached ``compiled`` dispatches with
-    ZERO further traces — numpy args, python scalars and donated
-    buffers all verified to rebind without retracing. Serving call
-    sites only wrap when request tracing is enabled; flag-off keeps
-    the raw jit functions and is byte-identical.
-    """
-
-    __slots__ = ("_jit", "_counters", "_compiled")
-
-    def __init__(self, fn, counters=None, **jit_kwargs):
-        # accept a raw callable (jitted here) or an existing jax.jit
-        # wrapper (identified by its .lower) so call sites keep their
-        # own donate_argnums/out_shardings construction
-        self._jit = fn if hasattr(fn, "lower") else jax.jit(fn,
-                                                            **jit_kwargs)
-        self._counters = counters
-        self._compiled = {}
-
-    @staticmethod
-    def _leaf_sig(leaf):
-        shape = getattr(leaf, "shape", None)
-        if shape is None:        # python scalar: weak-typed under trace
-            return (type(leaf).__name__,)
-        return (tuple(shape), str(getattr(leaf, "dtype", "?")))
-
-    def signature(self, args):
-        return tuple(self._leaf_sig(leaf)
-                     for leaf in jax.tree_util.tree_leaves(args))
-
-    @property
-    def executables(self):
-        """{signature: (flops, bytes)} for every compiled variant."""
-        return {sig: cost for sig, (_, cost) in self._compiled.items()}
-
-    def __call__(self, *args):
-        sig = self.signature(args)
-        entry = self._compiled.get(sig)
-        if entry is None:
-            compiled = self._jit.lower(*args).compile()
-            entry = self._compiled[sig] = (compiled,
-                                           _executable_cost(compiled))
-        compiled, (flops, hbm_bytes) = entry
-        out = compiled(*args)
-        if self._counters is not None and (flops or hbm_bytes):
-            self._counters.add_cost(flops, hbm_bytes)
-        return out
 
 
 def _trace_annotation(name, attrs):

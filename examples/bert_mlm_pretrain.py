@@ -3,8 +3,7 @@
 
 BertForMLM (models/transformer.py) + CrossEntropyCriterion + Adam in bf16;
 attention kernel auto-selected per shape (parallel/sequence.py
-flash_profitable). This is the runnable form of bench.py's
-``bert_pretrain`` leg with real masked-LM data handling: 15% of tokens are
+flash_profitable), with real masked-LM data handling: 15% of tokens are
 masked, only those positions contribute loss (ClassNLL padding_value).
 
   python examples/bert_mlm_pretrain.py --steps 20           # synthetic data

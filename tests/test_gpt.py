@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 import bigdl_tpu.nn as nn
-from bigdl_tpu.models.gpt import GPT, GPTForCausalLM, gpt_flops_per_token
+from bigdl_tpu.models.gpt import GPT, GPTForCausalLM
 
 
 def _tiny(**kw):
@@ -123,10 +123,6 @@ def test_sequence_parallel_train_step():
     tgt = jax.device_put(jnp.zeros((4, seq_len), jnp.int32), sh)
     p2, opt, loss = step(m.params, opt, ids, tgt)
     assert np.isfinite(float(loss))
-
-
-def test_flops_accounting_positive():
-    assert gpt_flops_per_token() > 1e8
 
 
 def test_generate_past_max_position_slides_window():

@@ -6,6 +6,7 @@ it. See docs/linting.md for the workflow.
 """
 
 import os
+import re
 
 from bigdl_tpu.lint import DEFAULT_BASELINE_PATH, lint_paths, load_baseline
 
@@ -72,3 +73,45 @@ def test_interprocedural_rule_catalog_is_registered():
     }
     missing = expected - set(RULES_BY_NAME)
     assert missing == set(), f"rules dropped from the catalog: {missing}"
+
+
+# Records of what the repo was (they name deleted scripts as history) and
+# descriptions of the reference upstream (they name its files, not ours).
+_HISTORY = {"CHANGES.md", "PERF.md", "ROADMAP.md", "ISSUE.md", "REVIEW.md",
+            "SURVEY.md", "PAPER.md", "PAPERS.md", "SNIPPETS.md"}
+# Bare names that are files of another project or a document's own example.
+_FOREIGN = {"modeling_lfm2_moe.py",      # transformers' file (models/lfm2.py)
+            "ckptio.py", "trainer.py"}   # docs/linting.md's worked example
+_NOT_THE_TREE = {".git", "_parent", "_archive", "chiprun_out", ".jax_cache",
+                 ".bench_scratch", "__pycache__", ".pytest_cache",
+                 ".hypothesis"}
+
+
+def test_documents_and_code_name_no_script_that_is_not_in_the_tree():
+    """A ``scripts/<name>.py`` must be that file; a bare ``<name>.py`` (how
+    the documents name ``chip_smoke.py`` and the other scripts at the root)
+    must be a file somewhere in the tree. A deleted script leaves no
+    pointer behind in a guide, the package, an example or a script."""
+    root = os.path.dirname(PACKAGE_DIR)
+    tree = set()
+    for d, dirs, files in os.walk(root):
+        dirs[:] = [x for x in dirs if x not in _NOT_THE_TREE]
+        tree.update(os.path.relpath(os.path.join(d, f), root).replace(
+            os.sep, "/") for f in files)
+    names = {p.rsplit("/", 1)[-1] for p in tree} | _FOREIGN
+    guides = [p for p in tree if p.endswith(".md") and p not in _HISTORY
+              and ("/" not in p or p.startswith(("docs/", ".claude/")))]
+    code = [p for p in tree if p.endswith(".py")
+            and p.startswith(("bigdl_tpu/", "examples/", "scripts/"))]
+    assert "README.md" in guides and len(code) > 100
+    script = re.compile(r"(?<![\w./-])(scripts/)?([A-Za-z_]\w*\.py)\b")
+    dangling = {}
+    for p in sorted(guides + code):
+        with open(os.path.join(root, p), errors="replace") as f:
+            text = f.read()
+        bad = sorted({m.group(0) for m in script.finditer(text)
+                      if (m.group(0) not in tree if m.group(1) else
+                          m.group(2) not in names)})
+        if bad:
+            dangling[p] = bad
+    assert dangling == {}, dangling

@@ -1,9 +1,9 @@
 """Unit tests for the request-tracing layer (ISSUE 20): per-request
 timeline rings + Perfetto export (one synthetic track per request),
 histogram exemplars as the metrics->timeline join, the ``/requests``
-and ``/healthz`` endpoints, the flight-recorder dump paths,
-``CostStampedJit`` compile-gate equivalence, and the flag-off no-op
-contract.
+and ``/healthz`` endpoints, the flight-recorder dump paths, the flag-off
+no-op contract, and that the flag does not decide how a serving
+executable is called.
 
 Recorder/flight tests run against FRESH ``ReqTraceRecorder`` /
 ``FlightRecorder`` instances (never the process globals) so they stay
@@ -12,6 +12,7 @@ pytest process; endpoint tests pass those instances into the server
 explicitly for the same reason.
 """
 
+import contextlib
 import json
 import re
 import urllib.error
@@ -294,62 +295,68 @@ def test_profile_endpoint_validates_and_serializes(reg, rec):
         assert e.value.code == 400
 
 
-# ------------------------------------------------------------ cost stamping
+# ------------------------------------------- how executables are called
 
-def test_cost_stamped_jit_compile_gate_and_cost_accounting():
-    import jax.numpy as jnp
-    import numpy as np
-    from bigdl_tpu.utils.profiling import CostStampedJit, DecodeCounters
-
-    counters = DecodeCounters("step_traces")
-    traces = {"n": 0}
-
-    def step(x):
-        traces["n"] += 1            # fires at trace time only
-        counters.tick("step_traces")
-        return x * 2.0 + 1.0
-
-    wrapped = CostStampedJit(step, counters=counters)
-    a = jnp.arange(4, dtype=jnp.float32)
-    out = wrapped(a)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.arange(4, dtype=np.float32) * 2 + 1)
-    assert traces["n"] == 1 and counters["step_traces"] == 1
-    wrapped(a)                      # same signature: ZERO retraces
-    wrapped(jnp.ones(4, jnp.float32))
-    assert traces["n"] == 1 and counters["step_traces"] == 1
-    wrapped(jnp.arange(8, dtype=jnp.float32))   # new shape: one more
-    assert traces["n"] == 2 and counters["step_traces"] == 2
-    assert len(wrapped.executables) == 2
-    # the compile-time cost stamp accumulates per DISPATCH, on the
-    # counters' attributes (never the public dict namespace)
-    costs = list(wrapped.executables.values())
-    sig4 = wrapped.signature((a,))
-    f4, b4 = wrapped.executables[sig4]
-    f8, b8 = [c for s, c in wrapped.executables.items() if s != sig4][0]
-    assert counters.flops == pytest.approx(3 * f4 + f8)
-    assert counters.hbm_bytes == pytest.approx(3 * b4 + b8)
-    assert "flops" not in counters and "hbm_bytes" not in counters
-    assert all(f >= 0.0 and b >= 0.0 for f, b in costs)
+_PROMPTS = [[5, 9, 2, 17, 3], [1, 1, 4, 60, 8], [7, 3, 3]]
 
 
-def test_cost_stamped_jit_accepts_prejitted_callable():
+def _tiny_engine(**kw):
     import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from bigdl_tpu.utils.profiling import CostStampedJit, DecodeCounters
+    from bigdl_tpu.models.gpt import GPTForCausalLM
+    from bigdl_tpu.serving import ServingEngine
 
-    counters = DecodeCounters("step_traces")
-    jitted = jax.jit(lambda x, y: x + y)
-    wrapped = CostStampedJit(jitted, counters=counters)
-    out = wrapped(jnp.arange(3, dtype=jnp.float32),
-                  jnp.ones(3, jnp.float32))
-    np.testing.assert_allclose(np.asarray(out), [1.0, 2.0, 3.0])
-    assert len(wrapped.executables) == 1
+    m = GPTForCausalLM(vocab_size=61, hidden_size=32, n_layers=2,
+                       n_heads=4, max_position=64)
+    params, _ = m.setup(jax.random.PRNGKey(3), None)
+    return ServingEngine(m, params, max_slots=2, max_queue=8, **kw)
 
 
-def test_device_peak_flops_unknown_kind_is_none_on_cpu():
-    from bigdl_tpu.utils import profiling
-    # CPU device kinds are not in the TPU peak table: the MFU gauge is
-    # omitted, never fabricated from a made-up denominator
-    assert profiling.device_peak_flops() is None
+@contextlib.contextmanager
+def _request_tracing(value):
+    prev = reqtrace.set_enabled(value)
+    try:
+        yield
+    finally:
+        reqtrace.set_enabled(prev)
+
+
+def _serve(tracing, **kw):
+    """(type of each held executable, tokens, trace counts) of one
+    engine built and driven with request tracing ``tracing``."""
+    with _request_tracing(tracing), _tiny_engine(**kw) as engine:
+        assert reqtrace.enabled() is tracing
+        held = {n: getattr(engine.slots, n)
+                for n in ("_prefill_fn", "_step_fn", "_copy_fn")
+                if hasattr(engine.slots, n)}
+        # what ``jax.jit`` returned: it lowers, and nothing stands
+        # between the loop and its lazy call
+        assert all(hasattr(f, "lower") for f in held.values()), held
+        tokens = [list(engine.result(engine.submit(p, 6), timeout=120))
+                  for p in _PROMPTS]
+        traces = {k: v for k, v in engine.stats.items()
+                  if k.endswith("_traces")}
+    return {n: type(f) for n, f in held.items()}, tokens, traces
+
+
+@pytest.mark.parametrize("kw", [{}, {"paged": True}],
+                         ids=["dense", "paged"])
+def test_request_tracing_does_not_change_how_executables_are_called(kw):
+    on, off = _serve(True, **kw), _serve(False, **kw)
+    assert set(on[0]) >= {"_prefill_fn", "_step_fn"}
+    assert ("_copy_fn" in on[0]) == bool(kw)
+    assert on == off
+
+
+def test_metrics_exposition_has_decode_counters_and_no_cost_gauges():
+    with _request_tracing(True), _tiny_engine() as engine, \
+            obs.MetricsServer(port=0) as srv:
+        engine.result(engine.submit(_PROMPTS[0], 4), timeout=120)
+        with urllib.request.urlopen(srv.url + "/metrics") as r:
+            text = r.read().decode()
+    families = {ln.split("{")[0].split(" ")[0]
+                for ln in text.splitlines() if ln and not ln.startswith("#")}
+    assert {"bigdl_decode_traces", "bigdl_decode_dispatches"} <= families
+    # no utilisation, FLOP/s or bandwidth gauge: the benchmark counts those
+    # from shapes (docs/observability.md)
+    assert not [f for f in families
+                if re.search(r"mfu|flops|hbm_bytes", f)]

@@ -422,22 +422,55 @@ def test_int8_kv_paged_engine_top1_agreement():
     assert agree >= 0.9
 
 
+# The int8 engine rounds every matmul input to a per-tensor step of
+# amax/127 and every cached K/V row to a per-page step of amax/127; the
+# reference below keeps the same int8 WEIGHTS (dequantised) and rounds
+# nothing else. On this toy model the logits' standard deviation is 0.11
+# and the two best lie 0.03-0.06 apart, so greedy TOKENS flip on those
+# roundings (the old ``agree >= 0.9`` turned on one); what holds is that a
+# served token's reference logit trails the reference's best by a
+# rounding: 0.0000-0.0048 over seeds 0-9 on the CPU. 0.02 is four times
+# the largest reading and a fifth of the standard deviation, which is what
+# a defect of the cache path (a wrong row, a stale page, a rejected draft
+# committed) moves a logit by: a token drawn wrong trails by 0.17-0.43.
+INT8_MARGIN_TOL = 0.02
+
+
 def test_full_stack_spec_int8_weights_int8_kv():
     """The whole PR in one engine: speculative blocks over int8 weights
-    and int8 K/V pages, chunked prefill, prefix sharing."""
+    and int8 K/V pages, chunked prefill, prefix sharing. Checked as
+    ``chip_smoke.serve_leg`` and ``benchmarks/`` check serving: each
+    served greedy token's logit in a true-float32 uncached forward over
+    the served sequence, not the token itself."""
+    from bigdl_tpu.nn.quantized import is_quantized_leaf, quantize_params
+
     m, params = _built(seed=6)
-    base = _sequential(m, params, PROMPTS[:4], 12)
+    n_new = 12
     engine = ServingEngine(m, params, max_slots=4, paged=True,
                            page_size=16, prefill_chunk=4, spec_tokens=4,
                            int8_weights=True, int8_kv=True)
-    hs = [engine.submit(p, 12) for p in PROMPTS[:4]]
-    got = [engine.result(h, timeout=120) for h in hs]
+    hs = [engine.submit(p, n_new) for p in PROMPTS[:4]]
+    got = [np.asarray(engine.result(h, timeout=120)) for h in hs]
     met = engine.metrics()
     engine.shutdown()
-    agree = np.mean([_agreement(a, b) for a, b in zip(base, got)])
-    assert agree >= 0.9
     assert met["kv_dtype"] == "int8"
     assert met["spec_proposed"] > 0
+    dequantised = jax.tree_util.tree_map(
+        lambda w: (w["q"].astype(jnp.float32) * w["scale"]
+                   if is_quantized_leaf(w) else w),
+        quantize_params(params), is_leaf=is_quantized_leaf)
+    deficit = 0.0
+    for p, out in zip(PROMPTS[:4], got):
+        assert len(out) == len(p) + n_new and list(out[:len(p)]) == p
+        with jax.default_matmul_precision("highest"):
+            h, _ = m.gpt.apply(dequantised["gpt"], (),
+                               jnp.asarray(out, jnp.int32)[None])
+            rows = np.asarray(m._lm_logits(
+                dequantised, h[0, len(p) - 1:len(out) - 1]))
+        assert np.isfinite(rows).all()
+        chosen = rows[np.arange(n_new), out[len(p):]]
+        deficit = max(deficit, float((rows.max(-1) - chosen).max()))
+    assert deficit <= INT8_MARGIN_TOL, deficit
 
 
 def test_int8_kv_pool_doubles_pages_at_equal_budget():
