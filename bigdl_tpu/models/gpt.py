@@ -16,6 +16,7 @@ from jax import lax
 
 import bigdl_tpu.nn as nn
 from bigdl_tpu.nn.module import Module
+from bigdl_tpu.ops import sampling
 from bigdl_tpu.parallel.sequence import MultiHeadAttention
 
 
@@ -559,24 +560,21 @@ class GPTForCausalLM(Module):
             return self._lm_logits(params, h_last), cache
 
         def decode(params, cache, logits, key, prompt_len, temperature,
-                   n_new, greedy, top_k, top_p):
+                   n_new, greedy, top_k, top_p, kernel):
             stats.tick("decode_traces")    # trace-time only: counts compiles
-            from bigdl_tpu.utils.engine import get_flag
-            fused = get_flag("BIGDL_TPU_FUSED_SAMPLING", False, bool)
+            # ``kernel``: the caller's word that ``ops/sampling.py`` applies
+            # to the logits as prefill returned them (the cuts by
+            # threshold, no sort; the same key and the same kept set)
+            draw = sampling.threshold_sample_logits if kernel \
+                else sample_logits
 
             def step(carry, _):
                 cache, logits, key, pos = carry
                 if greedy:
                     tok = jnp.argmax(logits, axis=-1)
-                elif fused:
-                    from bigdl_tpu.ops.sampling import fused_sample_logits
-                    key, sub = jax.random.split(key)
-                    tok = fused_sample_logits(logits, sub, temperature,
-                                              top_k, top_p)
                 else:
                     key, sub = jax.random.split(key)
-                    tok = sample_logits(logits, sub, temperature,
-                                        top_k, top_p)
+                    tok = draw(logits, sub, temperature, top_k, top_p)
                 tok = tok.astype(jnp.int32)
                 h, cache = self.gpt.decode_step(params["gpt"], cache, tok,
                                                 pos)
@@ -592,7 +590,7 @@ class GPTForCausalLM(Module):
         # all single-use buffers — donate them; params are reused across
         # calls and deliberately are not
         fns = (jax.jit(prefill, donate_argnums=(1,)),
-               jax.jit(decode, static_argnums=(6, 7, 8, 9),
+               jax.jit(decode, static_argnums=(6, 7, 8, 9, 10),
                        donate_argnums=(1, 2, 3)))
         self._gen_fns = fns
         return fns
@@ -738,7 +736,8 @@ class GPTForCausalLM(Module):
         logits0, cache = prefill_fn(params, ids_pad, t)
         toks = decode_fn(params, cache, logits0, rng, t,
                          0.0 if temperature is None else temperature,
-                         int(n_new), greedy, top_k, top_p)
+                         int(n_new), greedy, top_k, top_p,
+                         not greedy and sampling.applies(logits0))
         self.decode_stats.dispatched(2)
         return jnp.concatenate([ids, toks.astype(jnp.int32)], axis=1)
 

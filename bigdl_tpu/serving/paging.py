@@ -457,7 +457,7 @@ class PagedSlotManager(SlotManager):
             return self._build_spec_fns()
         model, gpt = self.model, self.model.gpt
         n_steps = self.steps_per_sync
-        top_k, top_p = self.top_k, self.top_p
+        top_k, top_p, sampler = self.top_k, self.top_p, self.sampler
         pmax = self.max_position
         ps = self.page_size
         wrap = self._wrap_fn()
@@ -494,7 +494,8 @@ class PagedSlotManager(SlotManager):
 
             def one(carry, _):
                 pools, logits, lengths, key = carry
-                tok, key = select_tokens(logits, temps, key, top_k, top_p)
+                tok, key = select_tokens(logits, temps, key, top_k, top_p,
+                                         sampler)
                 # same clamp as the dense step: a slot that hit EOS/max
                 # mid-block keeps decoding junk the host discards
                 pos = jnp.minimum(lengths, pmax - 1)
@@ -533,7 +534,7 @@ class PagedSlotManager(SlotManager):
         stats = self.stats
         n_steps = self.steps_per_sync
         gamma = self.spec_tokens
-        top_k, top_p = self.top_k, self.top_p
+        top_k, top_p, sampler = self.top_k, self.top_p, self.sampler
         ps = self.page_size
         draft = self._draft
         s_all = self.max_slots
@@ -583,7 +584,8 @@ class PagedSlotManager(SlotManager):
 
             def one(carry, _):
                 pools, logits, out, counts, key, table, last, tele = carry
-                tok0, key = select_tokens(logits, temps, key, top_k, top_p)
+                tok0, key = select_tokens(logits, temps, key, top_k, top_p,
+                                          sampler)
                 props = draft.propose(table, tok0, gamma)
                 h, pools = gpt.paged_verify_chunk(
                     params["gpt"], pools, page_table, props,

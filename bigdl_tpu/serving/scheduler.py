@@ -1101,8 +1101,9 @@ class Scheduler:
                               kv_write=slots.kv_write,
                               attn_read=slots.attn_read,
                               attn_blocks=attn_blocks,
-                              attn_blocks_table=attn_blocks_table
-                              ) as step_span:
+                              attn_blocks_table=attn_blocks_table,
+                              sampler=slots.sampler,
+                              sampled=slots.sampled()) as step_span:
                     toks = slots.step()    # (steps_per_sync, max_slots)
                     # a model with routed experts: ``experts``,
                     # ``assignments``, ``experts_hit``
@@ -1167,6 +1168,8 @@ class Scheduler:
                             tokens=sum(r.prompt.size + len(r.tokens)
                                        for r in batch),
                             requests=[r.id for r in batch],
+                            sampled=sum(r.temperature > 0.0
+                                        for r in batch),
                             **slots.prefill_attrs)
                 for r in batch:
                     r.prefill_bucket = bucket

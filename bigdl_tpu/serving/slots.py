@@ -43,37 +43,35 @@ from jax import lax
 
 from bigdl_tpu import obs
 from bigdl_tpu.models.gpt import prompt_bucket, sample_logits
-from bigdl_tpu.ops import decode_attention
+from bigdl_tpu.ops import decode_attention, sampling
 from bigdl_tpu.ops.kv_write import in_place_applies
 from bigdl_tpu.resilience.faults import fault_point
 from bigdl_tpu.utils.profiling import DecodeCounters
 
 
-def select_tokens(logits, temps, key, top_k, top_p):
+def select_tokens(logits, temps, key, top_k, top_p, sampler="sort"):
     """Per-slot greedy/sampled token selection shared by the dense and
     paged step traces: greedy argmax everywhere, with the PRNG + softmax
     sampling path behind a runtime ``lax.cond`` so an all-greedy batch
-    skips it entirely. ``BIGDL_TPU_FUSED_SAMPLING`` swaps the multi-op
-    XLA chain for the one-pass ``ops.sampling`` kernel (same key, same
-    truncated distribution). Returns ``(tok int32 (S,), key)``."""
-    from bigdl_tpu.utils.engine import get_flag
+    skips it entirely. ``sampler`` is the manager's word for how the
+    sampled branch finds its top-k and nucleus cuts: ``"sort"`` is
+    ``sample_logits``, ``"kernel"`` is ``ops.sampling`` (no sort; the
+    same key, the same kept set, the same draw; blocks of rows without a
+    sampled stream skipped). Returns ``(tok int32 (S,), key)``."""
     greedy_tok = jnp.argmax(logits, axis=-1)
-    fused = get_flag("BIGDL_TPU_FUSED_SAMPLING", False, bool)
+    draws = temps > 0.0
+    scale = jnp.maximum(temps, 1e-6)[:, None]
 
     def pick_sampled(key):
         key, sub = jax.random.split(key)
-        if fused:
-            from bigdl_tpu.ops.sampling import fused_sample_logits
-            sampled = fused_sample_logits(
-                logits, sub, jnp.maximum(temps, 1e-6)[:, None],
-                top_k, top_p)
+        if sampler == "kernel":
+            sampled = sampling.threshold_sample_logits(
+                logits, sub, scale, top_k, top_p, rows=draws)
         else:
-            sampled = sample_logits(
-                logits, sub, jnp.maximum(temps, 1e-6)[:, None],
-                top_k, top_p)
-        return jnp.where(temps > 0.0, sampled, greedy_tok), key
+            sampled = sample_logits(logits, sub, scale, top_k, top_p)
+        return jnp.where(draws, sampled, greedy_tok), key
 
-    tok, key = lax.cond(jnp.any(temps > 0.0), pick_sampled,
+    tok, key = lax.cond(jnp.any(draws), pick_sampled,
                         lambda key: (greedy_tok, key), key)
     return tok.astype(jnp.int32), key
 
@@ -117,6 +115,12 @@ class SlotManager:
     # "masked" scores the whole table and masks (the paged and the
     # speculative steps too)
     attn_read = "masked"
+    # how the sampled branch of token selection finds its top-k and
+    # nucleus cuts, fixed with them from the logits table as allocated
+    # and stamped beside them: "kernel" is ``ops/sampling.py``, per-row
+    # thresholds by bisection; "sort" is ``sample_logits``, two sorts of
+    # the table (every step of every manager follows it)
+    sampler = "sort"
     # what a model with routed experts adds to the latest
     # ``serve/prefill`` and ``serve/step`` span (docs/observability.md);
     # empty for a model without
@@ -191,6 +195,8 @@ class SlotManager:
         self.last_prefill_shape = None
         self._dtype = model.serving_dtype(params)
         self._alloc()
+        if sampling.applies(self._logits, layout):
+            self.sampler = "kernel"
         self._prefill_fn, self._step_fn = self._build_fns()
 
     def _cache_sharding(self):
@@ -285,7 +291,7 @@ class SlotManager:
         model = self.model
         stats = self.stats
         n_steps = self.steps_per_sync
-        top_k, top_p = self.top_k, self.top_p
+        top_k, top_p, sampler = self.top_k, self.top_p, self.sampler
         pmax = self.max_position
         wrap = self._wrap_fn()
         cache_dtype = self._dtype
@@ -330,7 +336,8 @@ class SlotManager:
                 # recompile); at runtime an all-greedy batch skips the
                 # PRNG + softmax sampling work entirely — a measurable
                 # per-step cost at small model sizes
-                tok, key = select_tokens(logits, temps, key, top_k, top_p)
+                tok, key = select_tokens(logits, temps, key, top_k, top_p,
+                                         sampler)
                 # clamp: a slot that hit EOS/max mid-block keeps decoding
                 # junk the host discards; the clamp keeps its cache writes
                 # and position lookups in bounds near max_position
@@ -398,7 +405,7 @@ class SlotManager:
         stats = self.stats
         n_steps = self.steps_per_sync
         gamma = self.spec_tokens
-        top_k, top_p = self.top_k, self.top_p
+        top_k, top_p, sampler = self.top_k, self.top_p, self.sampler
         draft = self._draft
         s_all = self.max_slots
         width = n_steps * gamma
@@ -443,7 +450,8 @@ class SlotManager:
 
             def one(carry, _):
                 cache, logits, out, counts, key, table, last, tele = carry
-                tok0, key = select_tokens(logits, temps, key, top_k, top_p)
+                tok0, key = select_tokens(logits, temps, key, top_k, top_p,
+                                          sampler)
                 props = draft.propose(table, tok0, gamma)      # (S, g)
                 h, cache = gpt.decode_chunk(params["gpt"], cache, props,
                                             lengths + counts)
@@ -656,6 +664,12 @@ class SlotManager:
         if self.attn_read != "kernel":
             return table, table
         return decode_attention.blocks_read(self.lengths, self.active), table
+
+    def sampled(self):
+        """Live slots whose next token is drawn (``temps`` > 0), from the
+        host's own table: 0 means the step's sampled branch is not
+        taken."""
+        return int(np.count_nonzero(self.active & (self.temps > 0.0)))
 
     def retire(self, slot):
         """Free a slot row (host bookkeeping only — the stale K/V is

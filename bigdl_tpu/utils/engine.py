@@ -150,14 +150,6 @@ logger = logging.getLogger("bigdl_tpu")
 #                                   token-identical (default off: the
 #                                   XLA gather path, bit-identical to
 #                                   previous releases)
-#   BIGDL_TPU_FUSED_SAMPLING        "1" -> temperature / top-k / top-p /
-#                                   categorical collapse into one pallas
-#                                   pass over the (slots, vocab) logits
-#                                   (ops/sampling.py) in generate() and
-#                                   both slot managers; same PRNG key,
-#                                   same draw — sampled tokens are
-#                                   bit-identical to the XLA chain
-#                                   (default off)
 # Crash-consistent recovery (docs/resilience.md#crash-consistent-recovery):
 #   BIGDL_TPU_KV_SNAPSHOT           "1" -> paged engines snapshot
 #                                   prefix-cached / hot K/V pages and

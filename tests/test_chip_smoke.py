@@ -19,7 +19,7 @@ import chip_smoke  # noqa: E402
 TOY = dict(vocab_size=64, hidden_size=32, n_layers=2, n_heads=4,
            max_position=64)
 WAVES = ((5,), (9, 12), (20, 24, 28))
-KERNEL_FLAGS = ("BIGDL_TPU_PAGED_KERNEL", "BIGDL_TPU_FUSED_SAMPLING")
+KERNEL_FLAGS = ("BIGDL_TPU_PAGED_KERNEL",)
 # true-f32 CPU matmuls: the reference and the engine differ by summation
 # order only
 TOL = 1e-4
@@ -69,6 +69,14 @@ def test_decode_attention_leg():
     assert rec["ok"] and max(rec["errors"].values()) <= rec["tolerance"]
     # not on a TPU: the serving step would keep the masked read
     assert rec["selected"] == {"float32": False, "bfloat16": False}
+
+
+def test_sampling_leg():
+    rec = chip_smoke.sampling_leg(tables=((12, 1000), (4, 300)), keys=2,
+                                  interpret=True)
+    assert rec["ok"] and set(rec["differing_tokens"].values()) == {0}
+    # not on a TPU: the serving step would keep the sorts
+    assert rec["selected"] == {"12x1000": False, "4x300": False}
 
 
 def test_train_leg(multi_device_cpu):

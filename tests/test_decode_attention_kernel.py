@@ -310,3 +310,27 @@ def test_compiled_at_the_cell_size_moves_no_table(one_chip, shape, reps,
     assert not moved, moved[:2]
     # nothing of a table's size is held beside the tables
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("shape", [(48, 50257), (96, 65536)],
+                         ids=["gpt2_cell", "lfm2_cell"])
+def test_sampled_branch_compiled_at_the_cell_size_sorts_nothing(
+        one_chip, shape, monkeypatch):
+    """The step's other kernel, kept beside these because one worker may
+    describe the chip: ``select_tokens`` built with ``ops/sampling.py``
+    passes Mosaic at both cells' logits tables (LFM2's step compiles the
+    branch though its cell never takes it: a kernel over the scoped VMEM
+    would fail that cell outright), and the compiled branch holds no sort
+    and nothing of the table's size but the table's own padded copy."""
+    from bigdl_tpu.ops import sampling
+    from bigdl_tpu.serving.slots import select_tokens
+    monkeypatch.setattr(sampling, "use_interpret", lambda: False)
+    at = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    pick = jax.jit(lambda logits, temps, key: select_tokens(
+        logits, temps, key, 40, 0.9, "kernel"))
+    compiled = pick.lower(at(shape, jnp.float32), at(shape[:1], jnp.float32),
+                          at((), jax.random.key(0).dtype)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "sample_cutoffs" in text
+    assert not re.search(r"\bsort\(|top[_-]?k", text, re.I)
+    assert sampling._vmem_bytes(shape[1]) <= 16 * 2 ** 20

@@ -4,7 +4,7 @@ Contract under test: (a) ``ops.paged_attention.paged_pool_attention``
 matches the XLA gather reference (``paged_gather`` →
 ``paged_attention``) on fp32 and int8 pools, decode (C=1) and chunk
 (C>1) shapes, sentinel page-table tails, and head-sharded tp pools via
-``shard_map``; (b) ``ops.sampling.fused_sample_logits`` is
+``shard_map``; (b) ``ops.sampling.threshold_sample_logits`` is
 BIT-identical to ``models.gpt.sample_logits`` — same key, same gumbel
 draw, same kept set; (c) with ``BIGDL_TPU_PAGED_KERNEL=1`` the serving
 stack is token-identical at temperature 0 across dense-prompt decode,
@@ -21,15 +21,17 @@ import numpy as np
 import pytest
 
 from bigdl_tpu.models.gpt import GPTForCausalLM, sample_logits
+from bigdl_tpu.ops import sampling
 from bigdl_tpu.ops.pallas_util import fit_block
 from bigdl_tpu.ops.paged_attention import paged_pool_attention
-from bigdl_tpu.ops.sampling import fused_sample_logits
+from bigdl_tpu.ops.sampling import threshold_sample_logits
 from bigdl_tpu.parallel.layout import serving_mesh
 from bigdl_tpu.parallel.sequence import (paged_attention, paged_gather,
                                          paged_gather_dequant, paged_write,
                                          paged_write_quant)
 from bigdl_tpu.serving import ServingEngine
 from bigdl_tpu.serving.paging import PagedSlotManager
+from bigdl_tpu.serving.slots import select_tokens
 
 WAIT = 120.0
 
@@ -221,7 +223,7 @@ class TestFusedSampling:
             logits = jax.random.normal(jax.random.PRNGKey(seed + 100),
                                        (self.S, self.V)) * 3.0
             want = sample_logits(logits, key, temp, top_k, top_p)
-            got = fused_sample_logits(logits, key, temp, top_k, top_p)
+            got = threshold_sample_logits(logits, key, temp, top_k, top_p)
             np.testing.assert_array_equal(np.asarray(want),
                                           np.asarray(got))
 
@@ -232,16 +234,15 @@ class TestFusedSampling:
         temps = jnp.asarray([[0.5], [0.8], [1.0], [1.3], [0.7], [0.9],
                              [1.1], [0.6]], jnp.float32)
         want = sample_logits(logits, key, temps, 10, 0.9)
-        got = fused_sample_logits(logits, key, temps, 10, 0.9)
+        got = threshold_sample_logits(logits, key, temps, 10, 0.9)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
     def test_non_divisible_row_count(self):
-        # S=6 with block 4 -> no aligned divisor, one block of all 6 rows
+        # S=12: a whole block of 8 rows and one padded out from 4
         key = jax.random.PRNGKey(5)
-        logits = jax.random.normal(jax.random.PRNGKey(105), (6, self.V))
-        want = sample_logits(logits, key, 0.9, None, None)
-        got = fused_sample_logits(logits, key, 0.9, None, None,
-                                  block_s=4)
+        logits = jax.random.normal(jax.random.PRNGKey(105), (12, self.V))
+        want = sample_logits(logits, key, 0.9, 7, 0.8)
+        got = threshold_sample_logits(logits, key, 0.9, 7, 0.8)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
@@ -359,47 +360,62 @@ class TestPagedKernelFlagOn:
 
 
 class TestFusedSamplingFlagOn:
-    """``BIGDL_TPU_FUSED_SAMPLING=1``: sampled tokens are bit-identical
-    to the XLA chain (same key, same gumbel). The flag is read at
-    trace time, so each side builds fresh jitted closures."""
+    """The sampled branch through ``ops/sampling.py``: sampled tokens are
+    bit-identical to the XLA chain (same key, same gumbel). No CPU table
+    selects the kernel, so each test overrides the table's word
+    (``sampling.applies``) or hands ``select_tokens`` its argument; each
+    side builds fresh jitted closures."""
 
     def test_generate_bit_identical(self, monkeypatch):
         ids = jnp.asarray([PROMPTS[0]], jnp.int32)
         outs = {}
-        for flag in ("0", "1"):
-            monkeypatch.setenv("BIGDL_TPU_FUSED_SAMPLING", flag)
+        for kernel in (False, True):
+            monkeypatch.setattr(sampling, "applies", lambda *a: kernel)
             m, params = _built(seed=7)      # fresh _gen_fns per side
-            outs[flag] = np.asarray(m.generate(
+            outs[kernel] = np.asarray(m.generate(
                 params, ids, 6, temperature=0.8, top_k=20, top_p=0.9,
                 rng=jax.random.PRNGKey(42)))
-        np.testing.assert_array_equal(outs["0"], outs["1"])
+            assert m.decode_stats["decode_traces"] == 1
+        np.testing.assert_array_equal(outs[False], outs[True])
 
     def test_serving_select_tokens_bit_identical(self, monkeypatch):
         outs = {}
-        for flag in ("0", "1"):
-            monkeypatch.setenv("BIGDL_TPU_FUSED_SAMPLING", flag)
+        for sampler in ("sort", "kernel"):
+            monkeypatch.setattr(sampling, "applies",
+                                lambda *a: sampler == "kernel")
             m, params = _built(seed=8)
             pm = PagedSlotManager(m, params, max_slots=2, page_size=16,
                                   top_k=10, top_p=0.9, seed=7)
+            assert pm.sampler == sampler
             slots = pm.admit(PROMPTS[:2], temperatures=[0.7, 0.9])
+            assert pm.sampled() == 2
             toks = []
             for _ in range(4):
                 pm.reserve_block()
                 toks.append(pm.step()[0])
-            outs[flag] = [[int(t[s]) for t in toks] for s in slots]
-        assert outs["0"] == outs["1"]
+            outs[sampler] = [[int(t[s]) for t in toks] for s in slots]
+        assert outs["sort"] == outs["kernel"]
+        # and by ``select_tokens``' own argument, one greedy row of three
+        logits = jax.random.normal(jax.random.PRNGKey(3), (3, 97)) * 3.0
+        temps = jnp.asarray([0.7, 0.0, 1.1])
+        want, _ = select_tokens(logits, temps, jax.random.key(5), 10, 0.9,
+                                "sort")
+        got, _ = select_tokens(logits, temps, jax.random.key(5), 10, 0.9,
+                               "kernel")
+        np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
     def test_both_kernels_compose(self, monkeypatch):
-        """Paged kernel + fused sampling together, temp-0 rows greedy:
+        """Paged kernel + sampling kernel together, temp-0 rows greedy:
         token-identical to the all-XLA engine."""
         monkeypatch.setenv("BIGDL_TPU_PAGED_KERNEL", "1")
-        monkeypatch.setenv("BIGDL_TPU_FUSED_SAMPLING", "1")
+        monkeypatch.setattr(sampling, "applies", lambda *a: True)
         m, params = _built(seed=9)
         n_new = 6
         expected = _sequential(m, params, PROMPTS[:3], n_new)
         engine = ServingEngine(m, params, max_slots=4, max_queue=16,
                                paged=True, page_size=8)
         try:
+            assert engine.slots.sampler == "kernel"
             for exp, got in zip(expected,
                                 _serve(engine, PROMPTS[:3], n_new)):
                 np.testing.assert_array_equal(exp, got)

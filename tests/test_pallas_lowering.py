@@ -16,7 +16,7 @@ import pytest
 from bigdl_tpu.ops.flash_attention import flash_attention
 from bigdl_tpu.ops.kv_write import kv_write
 from bigdl_tpu.ops.paged_attention import paged_pool_attention
-from bigdl_tpu.ops.sampling import fused_sample_logits
+from bigdl_tpu.ops.sampling import threshold_sample_logits
 
 HEADS, HEAD_DIM, SEQ, VOCAB = 12, 64, 1024, 50257
 SLOTS, PAGE_SIZE, CHUNK = 8, 16, 64
@@ -63,15 +63,40 @@ def test_paged_attention(int8, c, heads):
         S((SLOTS, SEQ // PAGE_SIZE), jnp.int32), S((SLOTS, c), jnp.int32))
 
 
-@pytest.mark.parametrize("slots", [SLOTS, 12], ids=["s8", "s12"])
+@pytest.mark.parametrize("shape", [(SLOTS, VOCAB), (12, VOCAB), (48, VOCAB),
+                                   (4, VOCAB), (96, 65536)],
+                         ids=["s8", "s12", "gpt2_cell", "prefill_window",
+                              "lfm2_cell"])
 @pytest.mark.parametrize("top_k,top_p", [(40, None), (None, 0.9), (40, 0.9)],
                          ids=["top_k", "top_p", "both"])
-def test_fused_sampling(top_k, top_p, slots):
+def test_threshold_sampling(top_k, top_p, shape):
     lower_for_tpu(
-        lambda logits, key, temps: fused_sample_logits(
-            logits, key, temps, top_k, top_p, interpret=False),
-        S((slots, VOCAB), jnp.float32),
-        S((), jax.random.key(0).dtype), S((slots, 1), jnp.float32))
+        lambda logits, key, temps, rows: threshold_sample_logits(
+            logits, key, temps, top_k, top_p, rows=rows, interpret=False),
+        S(shape, jnp.float32), S((), jax.random.key(0).dtype),
+        S((shape[0], 1), jnp.float32), S((shape[0],), jnp.bool_))
+
+
+@pytest.mark.parametrize("sampler,sorts", [("kernel", False), ("sort", True)])
+def test_sampled_branch_built_with_the_kernel_holds_no_sort(sampler, sorts,
+                                                            monkeypatch):
+    """``select_tokens`` as the serving step traces it, lowered for a TPU
+    at the chat cell's table: with the kernel no operation sorts or takes
+    a top-k of the vocabulary; with the sorts (the control, so that the
+    words looked for are the lowering's own) both are there."""
+    from bigdl_tpu.ops import sampling
+    from bigdl_tpu.serving.slots import select_tokens
+    # the step asks the backend, which is the CPU here: compiled, as on
+    # the chip
+    monkeypatch.setattr(sampling, "use_interpret", lambda: False)
+    text = lower_for_tpu(
+        lambda logits, temps, key: select_tokens(logits, temps, key, 40, 0.9,
+                                                 sampler),
+        S((48, VOCAB), jnp.float32), S((48,), jnp.float32),
+        S((), jax.random.key(0).dtype)).mlir_module()
+    assert ("tpu_custom_call" in text) == (sampler == "kernel")
+    assert ("stablehlo.sort" in text) == sorts
+    assert ("top_k" in text) == sorts
 
 
 @pytest.mark.parametrize("heads", [HEADS, 16], ids=["h12", "h16"])

@@ -152,6 +152,47 @@ def test_a_step_says_how_its_attention_read_and_how_much(served):
         assert s.attrs["attn_blocks"] == s.attrs["attn_blocks_table"] == 4
 
 
+def test_a_step_says_how_it_samples_and_how_many_streams_it_draws_for(
+        served, monkeypatch):
+    """Off the chip the sampled branch sorts, and a greedy engine never
+    takes it: ``sampled`` 0 on every step and prefill. Then, the table's
+    word overridden (no CPU table selects the kernel), two sampled
+    requests beside a greedy one: ``sampled`` counts the live slots with a
+    temperature, falls as they retire, and the admissions count theirs."""
+    from bigdl_tpu.ops import sampling
+    _, spans = served
+    steps = [s for s in spans if s.name == "serve/step"]
+    assert {s.attrs["sampler"] for s in steps} == {"sort"}
+    assert {s.attrs["sampled"] for s in steps} == {0}
+    assert {s.attrs["sampled"] for s in spans
+            if s.name == "serve/prefill"} == {0}
+    monkeypatch.setattr(sampling, "applies", lambda *a: True)
+    tracer = obs.default_tracer()
+    tracer.clear()
+    model = GPTForCausalLM(vocab_size=61, hidden_size=32, n_layers=2,
+                           n_heads=4, max_position=64)
+    params, _ = model.setup(jax.random.PRNGKey(3), None)
+    with ServingEngine(model, params, max_slots=4, top_k=10,
+                       top_p=0.9) as engine:
+        loop = engine.scheduler._thread.ident
+        assert engine.slots.sampler == "kernel"
+        handles = [engine.submit(PROMPTS[0], 8),
+                   engine.submit(PROMPTS[1], 3, temperature=0.8),
+                   engine.submit(PROMPTS[2], 6, temperature=1.2)]
+        for h in handles:
+            h.result(timeout=300)
+    spans = [s for s in tracer.spans() if s.thread_id == loop]
+    steps = [s.attrs for s in spans if s.name == "serve/step"]
+    assert {a["sampler"] for a in steps} == {"kernel"}
+    assert all(0 <= a["sampled"] <= min(2, a["live"]) for a in steps)
+    assert {a["sampled"] for a in steps} >= {0, 1}
+    assert max(a["sampled"] for a in steps) <= 2
+    assert steps[-1]["sampled"] == 0          # the greedy one ends alone
+    assert sum(s.attrs["sampled"] for s in spans
+               if s.name == "serve/prefill") == 2
+    assert all(0 <= t < 61 for h in handles for t in h.tokens)
+
+
 def test_attn_blocks_follow_admissions_and_retirements(monkeypatch):
     """With the length-bounded kernel (interpreted; the table's word is
     overridden, which no CPU table gives) a step reads the blocks of its
