@@ -472,6 +472,10 @@ class GPTForCausalLM(Module):
     def init_cache(self, batch, dtype=jnp.float32, sharding=None):
         return self.gpt.init_cache(batch, dtype, sharding=sharding)
 
+    def cache_tables(self):
+        from bigdl_tpu.serving.protocol import positions_table
+        return (positions_table(self.max_position),)
+
     def prefill(self, params, cache, ids, prompt_len):
         return self.gpt.prefill(params["gpt"], cache, ids, prompt_len)
 

@@ -334,6 +334,14 @@ class LFM2ForCausalLM(Module):
         return [l.init_cache(batch, self.max_position, dtype)
                 for l in self.layers]
 
+    def cache_tables(self):
+        """K and V of the attention layers; a convolution's taps are
+        fixed-size state."""
+        from bigdl_tpu.serving.protocol import positions_table
+        if not any(l.kind == ATTENTION for l in self.layers):
+            return ()
+        return (positions_table(self.max_position),)
+
     def prefill(self, params, cache, ids, prompt_len):
         """``ids`` (W, bucket) right-padded prompts, ``prompt_len`` (W,):
         returns the final-norm row at each prompt's last real position

@@ -111,21 +111,28 @@ class RMSNorm(Module):
     """Root-mean-square norm, no mean and no shift:
     ``x * rsqrt(mean(x^2) + eps) * weight`` over the last axis. The
     statistics are taken in float32 whatever ``x`` is stored in; the
-    result comes back in ``x``'s dtype."""
+    result comes back in ``x``'s dtype. With ``unit_offset`` the gain is
+    ``1 + weight`` and ``weight`` starts at zero (the form some decoder
+    families store: EvaByte's ``norm_add_unit_offset``)."""
 
-    def __init__(self, hidden_size, eps=1e-5):
+    def __init__(self, hidden_size, eps=1e-5, unit_offset=False):
         super().__init__()
         self.hidden_size = hidden_size
         self.eps = eps
+        self.unit_offset = unit_offset
 
     def make_params(self, rng, input_spec):
-        return {"weight": jnp.ones((self.hidden_size,))}
+        fill = jnp.zeros if self.unit_offset else jnp.ones
+        return {"weight": fill((self.hidden_size,))}
 
     def call(self, params, x):
         xf = x.astype(jnp.float32)
         y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
                            + self.eps)
-        return (y * params["weight"].astype(jnp.float32)).astype(x.dtype)
+        gain = params["weight"].astype(jnp.float32)
+        if self.unit_offset:
+            gain = 1.0 + gain
+        return (y * gain).astype(x.dtype)
 
 
 class SpatialCrossMapLRN(Module):

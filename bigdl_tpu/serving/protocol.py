@@ -1,9 +1,10 @@
 """What ``ServingEngine`` and the dense slot table ask of a model.
 
 A model is served through these names and through nothing else of it
-(``models/gpt.py``'s ``GPTForCausalLM`` and ``models/lfm2.py``'s
-``LFM2ForCausalLM`` are the two implementations; docs/serving.md has the
-contract in prose):
+(``models/gpt.py``'s ``GPTForCausalLM``, ``models/lfm2.py``'s
+``LFM2ForCausalLM`` and ``models/evabyte.py``'s ``EvaByteForCausalLM``
+are the three implementations; docs/serving.md has the contract in
+prose):
 
 ``vocab_size``, ``max_position``
     ints: the width of a row of logits, and the positions a slot holds
@@ -15,27 +16,44 @@ contract in prose):
     None, or the dtype of the logits it returns.
 ``init_cache(slots, dtype, sharding=None)``
     the per-slot state, a list with one dict a layer. EVERY leaf has the
-    slot axis first; a layer that keeps keys and values names them
-    ``"k"`` and ``"v"``, ``(slots, heads, max_position, head_dim)``; any
-    other leaf is fixed-size state (a convolution's last taps). The slot
-    table allocates it once, scatters a prefill's rows into it leaf by
-    leaf, and donates it through every step.
+    slot axis first; the table reads nothing else off a leaf and looks
+    for no leaf by name. It allocates the cache once, scatters a
+    prefill's rows into it leaf by leaf (the WHOLE row of every leaf, so
+    nothing of a slot's former occupant outlives an admission), and
+    donates it through every step.
+``cache_tables()``
+    the model's own description of the leaves that a stream fills row
+    by row, a tuple of :class:`RowTable` (empty for a model whose state
+    is all fixed-size): which leaves, how many rows a slot has, which
+    row the step at position ``pos`` writes and how many rows it reads.
+    Every other leaf is fixed-size state (a convolution's last taps).
+    GPT-2 and LFM2 describe one table, K and V of ``max_position`` rows
+    written at ``pos`` and read up to it; EvaByte two, a window written
+    at ``pos mod 2048`` and chunk summaries that gain a row every 16th
+    step. From the tables AS ALLOCATED the slot table derives whether
+    ``ops/kv_write.py`` takes the step's writes (every table's leaves
+    lie as the kernel needs them, rows third among it) and whether
+    ``ops/decode_attention.py`` takes its read (one table, read under one
+    softmax, that fits the kernel), and from the row counts
+    ``serve/step``'s ``attn_blocks`` and ``attn_blocks_table``.
 ``prefill(params, cache, ids, prompt_len) -> (h_last, cache)``
     ``ids`` (W, bucket) right-padded, ``prompt_len`` (W,): the final
     hidden row at each prompt's last real position, and ``cache`` (W
     rows, as ``init_cache(W, ...)`` made it) holding each row's state as
-    of ITS length, whatever the padding holds.
+    of ITS length, whatever the padding holds: what the step at position
+    ``prompt_len`` reads, every table's rows where its ``write_row``
+    and ``read_rows`` say that step finds them.
 ``decode_step(params, cache, tok, pos, in_place=False, read=None) -> (h, cache)``
     one token a slot, slot ``b`` at position ``pos[b]``. ``in_place``
-    is the table's word that ``ops/kv_write.py`` applies to its K/V
-    leaves. ``read`` is None, or (slots,) int32 where the table also
-    takes ``ops/decode_attention.py``: the positions slot ``b``'s
-    attention reads, ``pos[b] + 1`` for a live slot and 0 for a free
-    one, whose row then comes back as junk nobody reads. A model with
-    routed experts (``experts_per_token`` > 0) also takes ``live=``
-    (slots,) bool: its routed layers leave the dead
-    slots out, and it returns, third, the mean over those layers of how
-    many experts the live slots chose.
+    is the table's word that ``ops/kv_write.py`` applies to the leaves
+    of its row tables. ``read`` is None, or (slots,) int32 where the
+    table also takes ``ops/decode_attention.py`` (a model of ONE row
+    table): the rows slot ``b``'s attention reads, ``read_rows(pos[b])``
+    for a live slot and 0 for a free one, whose row then comes back as
+    junk nobody reads. A model with routed experts
+    (``experts_per_token`` > 0) also takes ``live=`` (slots,) bool: its
+    routed layers leave the dead slots out, and it returns, third, the
+    mean over those layers of how many experts the live slots chose.
 ``logits(params, h)``
     (…, hidden) rows -> (…, vocab).
 ``serving_features``
@@ -46,11 +64,21 @@ contract in prose):
     0 / None for a model without routed experts; else the assignments a
     token makes in each routed layer, and the name of the grouped
     product they run as (stamped on ``serve/step``).
+``step_counts(pos)``, ``prefill_counts(prompt_len)``
+    optional, both or neither: host arithmetic (numpy in, a dict of
+    ints out, the same keys whatever the input) on the positions the
+    live slots' next step writes, and on the lengths of the prompts an
+    admission prefills. The table stamps the dicts on ``serve/step``
+    and ``serve/prefill`` and keeps their running sums in its
+    ``stats``; nothing is read back from the device for them.
 ``check_servable()``
     optional: raise where this instance cannot be served at all.
 """
 
 from __future__ import annotations
+
+import dataclasses
+from typing import Callable
 
 # the engine's optional features, by the constructor argument (or the
 # family of arguments) that switches each on
@@ -58,7 +86,38 @@ FEATURES = ("paged", "spec_tokens", "lora", "int8_weights", "int8_kv",
             "tp", "kv_snapshot")
 
 _REQUIRED = ("vocab_size", "max_position", "serving_dtype", "init_cache",
-             "prefill", "decode_step", "logits", "serving_features")
+             "cache_tables", "prefill", "decode_step", "logits",
+             "serving_features")
+
+
+@dataclasses.dataclass(frozen=True)
+class RowTable:
+    """One group of cache leaves that a stream fills row by row.
+
+    ``leaves`` are their names in a layer's dict, ``rows`` the rows a
+    slot has. ``write_row(pos)`` is the row that the step at position
+    ``pos`` writes (-1: none this step) and ``read_rows(pos)`` how many
+    rows, from row 0, that step's attention reads once it has written;
+    both are plain arithmetic that takes numpy on the host and traced
+    arrays inside the step alike. ``row_axis`` is where the rows lie in a
+    leaf: 2 is ``(slots, heads, rows, head_dim)``, the shape the two
+    kernels know; a model that keeps another (1: ``(slots, rows, heads,
+    head_dim)``, a row whole tiles of a head of 128) keeps the plain
+    write and the masked read.
+    """
+
+    leaves: tuple
+    rows: int
+    write_row: Callable
+    read_rows: Callable
+    row_axis: int = 2
+
+
+def positions_table(rows):
+    """The table of a model that keeps ``"k"`` and ``"v"`` of every
+    position: row ``pos`` written at position ``pos``, rows ``0 .. pos``
+    read."""
+    return RowTable(("k", "v"), rows, lambda pos: pos, lambda pos: pos + 1)
 
 
 def check_model(model):

@@ -7,10 +7,14 @@ slot table delivers that on the PR 3 KV-cache primitives:
 
 - the cache is whatever the model's ``init_cache`` makes (the model
   protocol, ``serving/protocol.py``): one dict a layer, every leaf with
-  the slot axis first (S = ``max_slots``) — K and V of (S, H,
-  max_position, D), or fixed-size state such as a convolution's last
-  taps — allocated ONCE at construction; a request borrows one slot row
-  of every leaf for its lifetime;
+  the slot axis first (S = ``max_slots``), allocated ONCE at
+  construction; a request borrows one slot row of every leaf for its
+  lifetime. The table looks for no leaf by name: the model's
+  ``cache_tables()`` says which leaves a stream fills row by row, how
+  many rows a slot has, which row a step writes and how many it reads
+  (K and V of every position; or a window that starts over beside chunk
+  summaries that gain a row every 16th step); every other leaf is
+  fixed-size state such as a convolution's last taps;
 - :meth:`admit` prefills up to ``window`` waiting prompts in ONE batched
   causal forward and scatters their rows of every cache leaf + their
   next-token logits into the table (padding rows of a short admission
@@ -22,8 +26,9 @@ slot table delivers that on the PR 3 KV-cache primitives:
   vector ``cur_len``), greedy/sampled selection is a per-slot
   ``jnp.where`` on the temperature, and inactive rows compute masked
   junk the host ignores;
-- :meth:`retire` frees the slot row — no device work, the next admission
-  overwrites it.
+- :meth:`retire` frees the slot row — no device work: the next admission
+  overwrites the WHOLE row of every leaf, so a step's read lengths never
+  have to hide a former occupant's rows, however many tables there are.
 
 No shape ever depends on which slots are live, so the step function
 compiles exactly once and the engine dispatches O(1) per token
@@ -125,6 +130,9 @@ class SlotManager:
     # ``serve/prefill`` and ``serve/step`` span (docs/observability.md);
     # empty for a model without
     experts = None
+    # what the model's own ``step_counts`` / ``prefill_counts`` add to
+    # them (host arithmetic on positions: a model whose step reads
+    # several row tables says how many rows of each); empty without
     prefill_attrs = {}
     step_attrs = {}
     _stat_keys = ("prefill_traces", "step_traces")
@@ -174,6 +182,17 @@ class SlotManager:
         self.max_position = model.max_position
         self.stats = DecodeCounters(*self._stat_keys,
                                     obs_name=self._obs_name)
+        # the leaves a stream fills row by row, as the model describes
+        # them: which kernels apply and ``attn_blocks`` come from these
+        self._tables = tuple(model.cache_tables())
+        # a model that counts its own rows on the host: the running sums
+        # of what it stamps on the spans, under the same names
+        self._counted = hasattr(model, "step_counts")
+        if self._counted:
+            no_pos = np.zeros(0, np.int32)
+            for name in (*model.step_counts(no_pos),
+                         *model.prefill_counts(no_pos)):
+                self.stats[name] = 0
         if model.experts_per_token:
             self.experts = model.expert_product
             # running sums beside the compile gates: assignments made by
@@ -205,12 +224,20 @@ class SlotManager:
         ``out_shardings`` prefix."""
         if self.layout is None:
             return None
-        # a layout is a model's that carries ``tp``: GPT-2's heads
-        attn = self.model.gpt.layers[0].attn
-        shape = (self.max_slots, attn.n_heads, self.max_position,
-                 attn.head_dim)
+        # a layout is a model's that carries ``tp``: its one row table's
+        # leaves, heads second
+        shapes = jax.eval_shape(
+            lambda: self.model.init_cache(self.max_slots, self._dtype))
+        shape = self._table_leaf(shapes, self._tables[0]).shape
         return self.layout.sharding(self.layout.spec.kv_cache(), shape,
                                     allow_replicate=False)
+
+    @staticmethod
+    def _table_leaf(cache, table):
+        """One leaf of ``table`` (a ``protocol.RowTable``) in ``cache``
+        (or in its shapes): the first layer's that has it."""
+        name = table.leaves[0]
+        return next(c[name] for c in cache if name in c)
 
     def _alloc(self):
         model, dtype = self.model, self._dtype
@@ -295,13 +322,19 @@ class SlotManager:
         pmax = self.max_position
         wrap = self._wrap_fn()
         cache_dtype = self._dtype
-        # a K table as it was allocated says whether the write kernel
-        # applies (a TPU, no mesh, positions minor on the device)
-        table = next(c["k"] for c in self._cache if "k" in c)
-        in_place = in_place_applies(table, self.layout)
+        # the row tables as they were allocated say whether the write
+        # kernel takes the step's writes (each on a TPU, no mesh, rows
+        # minor on the device) ...
+        tables = self._tables
+        allocated = [self._table_leaf(self._cache, t) for t in tables]
+        in_place = bool(tables) and all(
+            t.row_axis == 2 and in_place_applies(a, self.layout)
+            for t, a in zip(tables, allocated))
         self.kv_write = "kernel" if in_place else "scatter"
-        # ... and whether the length-bounded attention reads it
-        bounded = decode_attention.applies(table, self.layout)
+        # ... and whether the length-bounded attention takes its read:
+        # the kernel scores ONE table a slot under one softmax
+        bounded = len(tables) == 1 and tables[0].row_axis == 2 \
+            and decode_attention.applies(allocated[0], self.layout)
         self.attn_read = "kernel" if bounded else "masked"
         # routed experts: the step also counts the experts its live slots
         # hit, one number a step beside the tokens
@@ -342,9 +375,10 @@ class SlotManager:
                 # junk the host discards; the clamp keeps its cache writes
                 # and position lookups in bounds near max_position
                 pos = jnp.minimum(lengths, pmax - 1)
-                # a live slot's attention reads up to the position it
-                # writes, a free slot's nothing
-                read = jnp.where(active, pos + 1, 0) if bounded else None
+                # a live slot's attention reads the rows its table says
+                # (K/V: up to the position it writes), a free slot's none
+                read = jnp.where(active, tables[0].read_rows(pos), 0) \
+                    if bounded else None
                 if routed:
                     h, cache, hit = model.decode_step(
                         params, cache, tok, pos, in_place=in_place,
@@ -572,10 +606,15 @@ class SlotManager:
             self.poisoned = True
             raise
         self.stats.dispatched()
+        attrs = {}
         if self.experts is not None:
             asked = self.model.experts_per_token * sum(a.size for a in arrs)
             self.stats.add("moe_assignments", asked)
-            self.prefill_attrs = {"assignments": asked}
+            attrs["assignments"] = asked
+        if self._counted:
+            attrs.update(self._summed(
+                self.model.prefill_counts(lens[:len(arrs)])))
+        self.prefill_attrs = attrs
         for i, s in enumerate(assigned):
             self.lengths[s] = lens[i]
             self.active[s] = True
@@ -585,6 +624,12 @@ class SlotManager:
             self.adapter_slots[s] = arows[i]
         return assigned
 
+    def _summed(self, counts):
+        """The model's own counts, added to their running sums."""
+        for name, n in counts.items():
+            self.stats.add(name, n)
+        return counts
+
     def step(self):
         """One block of ``steps_per_sync`` decode steps across every slot
         in a single dispatch. Returns host tokens of shape
@@ -593,6 +638,12 @@ class SlotManager:
         (steps_per_sync * spec_tokens, max_slots) and ``last_counts``
         holds each slot's committed count — callers read column ``s``
         up to ``last_counts[s]``."""
+        attrs = {}
+        if self._counted:
+            # from the positions this block's steps write, before they move
+            pos = self.lengths[self.active][:, None] \
+                + np.arange(self.steps_per_sync)
+            attrs = self._summed(self.model.step_counts(pos.ravel()))
         try:
             # argument hand-over and the call, until the executable's call
             # returns
@@ -624,9 +675,9 @@ class SlotManager:
             asked = int(self.active.sum()) * self.model.experts_per_token
             self.stats.add("moe_experts_hit", float(hits.sum()))
             self.stats.add("moe_assignments", self.steps_per_sync * asked)
-            self.step_attrs = {"experts": self.experts,
-                               "assignments": asked,
-                               "experts_hit": float(hits.mean())}
+            attrs.update(experts=self.experts, assignments=asked,
+                         experts_hit=float(hits.mean()))
+        self.step_attrs = attrs
         self.lengths[self.active] = np.minimum(
             self.lengths[self.active] + self.steps_per_sync,
             self.max_position)
@@ -655,15 +706,19 @@ class SlotManager:
         return toks
 
     def attn_blocks(self):
-        """``(read, table)``: the blocks of 128 positions that the next
-        decode step's attention reads a layer, and those of the whole
-        table; from the host's own ``lengths`` and ``active``. The masked
-        read reads the table."""
-        table = self.max_slots * -(-self.max_position
-                                   // decode_attention.BLOCK)
+        """``(read, table)``: the blocks of 128 rows that the next decode
+        step's attention reads a layer over the model's row tables, and
+        those the tables hold; from the host's own ``lengths`` and
+        ``active``. The masked read reads the tables."""
+        def blocks(rows):
+            return -(-rows // decode_attention.BLOCK)
+
+        table = self.max_slots * sum(blocks(t.rows) for t in self._tables)
         if self.attn_read != "kernel":
             return table, table
-        return decode_attention.blocks_read(self.lengths, self.active), table
+        pos = self.lengths[self.active]
+        return int(sum(blocks(t.read_rows(pos)).sum()
+                       for t in self._tables)), table
 
     def sampled(self):
         """Live slots whose next token is drawn (``temps`` > 0), from the
@@ -672,8 +727,9 @@ class SlotManager:
         return int(np.count_nonzero(self.active & (self.temps > 0.0)))
 
     def retire(self, slot):
-        """Free a slot row (host bookkeeping only — the stale K/V is
-        masked by length until the next admission overwrites it)."""
+        """Free a slot row (host bookkeeping only — a free slot's stale
+        rows are read by nobody, and the next admission overwrites the
+        whole row of every leaf)."""
         if not self.active[slot]:
             raise ValueError(f"slot {slot} is not active")
         self.active[slot] = False
