@@ -480,12 +480,14 @@ class EvaByteForCausalLM(Module):
         return self.out_norm.call(params["out_norm"], h_last), cache
 
     def decode_step(self, params, cache, tok, pos, in_place=False,
-                    read=None):
+                    read=None, live=None):
         """One byte a row at position ``pos`` (B,): ``(h, cache)`` with
         ``h`` (B, hidden) the final-norm rows. The slot table's two words
         stay unset: its kernels know tables of ``(slots, heads, rows,
         head_dim)`` read one a slot, and these keep the rows before the
-        heads and are read two under one softmax (``cache_tables``)."""
+        heads and are read two under one softmax (``cache_tables``). Its
+        mask ``live`` is for those kernels and changes nothing here: the
+        plain row writes take every row."""
         assert not in_place and read is None, (in_place, read)
         h = self._embed(params, tok)
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tok.shape)

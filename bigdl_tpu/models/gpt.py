@@ -72,13 +72,13 @@ class TransformerDecoderBlock(Module):
         return x + self._mlp(params, x), cache
 
     def decode_step(self, params, cache, x, index, in_place=False,
-                    read=None):
+                    read=None, live=None):
         """One incremental token (x: (B, 1, H)) through the block; the
-        attention K/V for slot ``index`` land in ``cache`` (``in_place``
-        and ``read``: see ``_MHA.decode_step``)."""
+        attention K/V for slot ``index`` land in ``cache`` (``in_place``,
+        ``read`` and ``live``: see ``_MHA.decode_step``)."""
         h, cache = self.attn.decode_step(
             params["attn"], self.ln1.call(params["ln1"], x), cache, index,
-            in_place=in_place, read=read)
+            in_place=in_place, read=read, live=live)
         x = x + h
         return x + self._mlp(params, x), cache
 
@@ -206,14 +206,15 @@ class GPT(Module):
                 new_cache)
 
     def decode_step(self, params, cache, tok, pos, in_place=False,
-                    read=None):
+                    read=None, live=None):
         """One incremental token: embed ``tok`` (B,) at position ``pos``
         (traced scalar, or a (B,) vector when every row sits at its own
         length — the serving engine's slot batch), run every block in
         cache mode, and return the (B, H) final-norm hidden state plus
         the updated cache. ``in_place`` is the slot table's word that
-        its buffers take the write kernel, ``read`` its per-row counts
-        for the length-bounded attention (``_MHA.decode_step``)."""
+        its buffers take the write kernel, ``live`` its (B,) mask of the
+        rows that kernel writes, ``read`` its per-row counts for the
+        length-bounded attention (``_MHA.decode_step``)."""
         h = jnp.take(params["tok_emb"], tok.astype(jnp.int32), axis=0)
         h = h + jnp.take(params["pos_emb"], jnp.asarray(pos, jnp.int32),
                          axis=0)
@@ -221,7 +222,8 @@ class GPT(Module):
         new_cache = []
         for i, layer in enumerate(self.layers):
             h, c = layer.decode_step(params["layers"][i], cache[i], h, pos,
-                                     in_place=in_place, read=read)
+                                     in_place=in_place, read=read,
+                                     live=live)
             new_cache.append(c)
         h = self.ln_f.call(params["ln_f"], h)
         return h[:, 0], new_cache
@@ -480,9 +482,9 @@ class GPTForCausalLM(Module):
         return self.gpt.prefill(params["gpt"], cache, ids, prompt_len)
 
     def decode_step(self, params, cache, tok, pos, in_place=False,
-                    read=None):
+                    read=None, live=None):
         return self.gpt.decode_step(params["gpt"], cache, tok, pos,
-                                    in_place=in_place, read=read)
+                                    in_place=in_place, read=read, live=live)
 
     def partition_specs(self, params, spec=None):
         """Canonical GSPMD PartitionSpec pytree for ``params`` — the

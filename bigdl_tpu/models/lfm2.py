@@ -139,19 +139,23 @@ class GroupedQueryAttention(Module):
         return self._attend(params, q, k, v,
                             jnp.tril(jnp.ones((t, t), bool))), cache
 
-    def decode_step(self, params, x, cache, pos, in_place=False, read=None):
+    def decode_step(self, params, x, cache, pos, in_place=False, read=None,
+                    live=None):
         """One position a row: ``x`` (B, hidden), ``pos`` (B,) the
-        position each row writes and attends up to. ``in_place`` and
-        ``read`` as in ``parallel.sequence``'s attention: the table's
-        owner says that ``ops/kv_write.py`` takes the write, and hands
+        position each row writes and attends up to. ``in_place``,
+        ``read`` and ``live`` as in ``parallel.sequence``'s attention:
+        the table's owner says that ``ops/kv_write.py`` takes the write,
+        of the rows that ``live`` marks (None: of every row), and hands
         over the per-row counts that ``ops/decode_attention.py`` reads
         (None: every position of every row, masked)."""
         from bigdl_tpu.ops.kv_write import kv_write, plain_write
         pos = jnp.asarray(pos, jnp.int32)
         q, k, v = self._qkv(params, x[:, None], pos[:, None])
-        write = kv_write if in_place else plain_write
-        kc, vc = write(cache["k"], cache["v"], k.astype(cache["k"].dtype),
-                       v.astype(cache["v"].dtype), pos)
+        k, v = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
+        if in_place:
+            kc, vc = kv_write(cache["k"], cache["v"], k, v, pos, live)
+        else:
+            kc, vc = plain_write(cache["k"], cache["v"], k, v, pos)
         if read is None:
             seen = jnp.arange(kc.shape[2])[None, :] <= pos[:, None]  # (B, S)
             out = self._attend(params, q, kc, vc,
@@ -247,7 +251,8 @@ class LFM2Block(Module):
             y, state = self.op.decode_step(p, u, cache["conv"])
             cache = {"conv": state}
         else:
-            y, cache = self.op.decode_step(p, u, cache, pos, in_place, read)
+            y, cache = self.op.decode_step(p, u, cache, pos, in_place, read,
+                                           live)
         x, hit = self._ffn(params, x + y, live)
         return x, cache, hit
 
@@ -363,10 +368,11 @@ class LFM2ForCausalLM(Module):
         """One token a row at position ``pos`` (B,): ``(h, cache)`` with
         ``h`` (B, hidden) the final-norm rows. Given ``live`` (B,) bool
         the routed layers leave the dead rows out (their ``h`` is junk
-        that nobody reads), and it also returns, third, the mean over
-        the routed layers of how many experts the live rows chose
-        (float32 scalar). ``in_place`` and ``read`` are the slot table's
-        words to the attention layers
+        that nobody reads), and a model that has routed layers
+        (``experts_per_token`` > 0) also returns, third, the mean over
+        them of how many experts the live rows chose (float32 scalar).
+        ``in_place`` and ``read`` are the slot table's words to the
+        attention layers, which also write the live rows' K/V only
         (``GroupedQueryAttention.decode_step``)."""
         h = self._embed(params, tok)
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tok.shape)
@@ -377,6 +383,6 @@ class LFM2ForCausalLM(Module):
             if hit is not None:
                 hits.append(hit)
         h = self.out_norm.call(params["out_norm"], h)
-        if live is None:
+        if live is None or not hits:
             return h, new_cache
         return h, new_cache, jnp.mean(jnp.stack(hits).astype(jnp.float32))

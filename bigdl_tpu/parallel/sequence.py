@@ -492,7 +492,7 @@ class MultiHeadAttention:
                 return qmatmul(out, params["wo"]), cache
 
             def decode_step(self, params, x, cache, index,
-                            in_place=False, read=None):
+                            in_place=False, read=None, live=None):
                 """Incremental mode: attend ONE query token (x: (B, 1, H))
                 against the cache, after writing its own K/V at slot
                 ``index``. The buffers keep their shapes, so the step is
@@ -512,8 +512,12 @@ class MultiHeadAttention:
                   B trips of four small launches for K and again for V.
                 - the same vector with ``in_place=True``: one Pallas
                   call for K and V (``ops/kv_write.py``) that rewrites
-                  only the tiles holding the B positions. The owner of
-                  the table says so: ``SlotManager`` asks
+                  only the tiles holding the positions of the rows that
+                  ``live`` (B,) bool marks (None: every row) and leaves
+                  every other row of the table as it lies: a free
+                  slot's junk K/V, which nothing reads, is not written
+                  at all. The owner of the table says so and hands over
+                  its mask: ``SlotManager`` asks
                   ``kv_write.in_place_applies`` of the table it
                   allocated (on a TPU, float32 or bfloat16, not laid out
                   over a mesh, positions minor on the device) when it
@@ -543,8 +547,12 @@ class MultiHeadAttention:
                                                   (0, 0, idx, 0))
                 else:
                     from bigdl_tpu.ops.kv_write import kv_write, plain_write
-                    write = kv_write if in_place else plain_write
-                    kc, vc = write(cache["k"], cache["v"], k, v, idx)
+                    if in_place:
+                        kc, vc = kv_write(cache["k"], cache["v"], k, v, idx,
+                                          live)
+                    else:
+                        kc, vc = plain_write(cache["k"], cache["v"], k, v,
+                                             idx)
                 if read is None:
                     out = cached_attention(q, kc, vc, idx + 1)
                 else:

@@ -43,17 +43,21 @@ prose):
     of ITS length, whatever the padding holds: what the step at position
     ``prompt_len`` reads, every table's rows where its ``write_row``
     and ``read_rows`` say that step finds them.
-``decode_step(params, cache, tok, pos, in_place=False, read=None) -> (h, cache)``
+``decode_step(params, cache, tok, pos, in_place=False, read=None, live=None) -> (h, cache)``
     one token a slot, slot ``b`` at position ``pos[b]``. ``in_place``
     is the table's word that ``ops/kv_write.py`` applies to the leaves
-    of its row tables. ``read`` is None, or (slots,) int32 where the
+    of its row tables, and ``live`` (slots,) bool the table's own mask
+    of the slots that hold a request: the kernel writes those and moves
+    no block of another (a model that keeps the plain write takes the
+    mask and leaves it unused: a free slot's row is junk nobody reads
+    either way). ``read`` is None, or (slots,) int32 where the
     table also takes ``ops/decode_attention.py`` (a model of ONE row
     table): the rows slot ``b``'s attention reads, ``read_rows(pos[b])``
     for a live slot and 0 for a free one, whose row then comes back as
     junk nobody reads. A model with routed experts
-    (``experts_per_token`` > 0) also takes ``live=`` (slots,) bool: its
-    routed layers leave the dead slots out, and it returns, third, the
-    mean over those layers of how many experts the live slots chose.
+    (``experts_per_token`` > 0) also leaves the dead slots out of its
+    routed layers and, given ``live``, returns, third, the mean over
+    those layers of how many experts the live slots chose.
 ``logits(params, h)``
     (…, hidden) rows -> (…, vocab).
 ``serving_features``

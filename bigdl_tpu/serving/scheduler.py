@@ -1099,6 +1099,7 @@ class Scheduler:
                 with obs.span("serve/step", iter=it,
                               live=len(self._inflight),
                               kv_write=slots.kv_write,
+                              kv_write_slots=slots.kv_write_slots(),
                               attn_read=slots.attn_read,
                               attn_blocks=attn_blocks,
                               attn_blocks_table=attn_blocks_table,

@@ -379,15 +379,12 @@ class SlotManager:
                 # (K/V: up to the position it writes), a free slot's none
                 read = jnp.where(active, tables[0].read_rows(pos), 0) \
                     if bounded else None
+                # ... and the write kernel moves a live slot's blocks only
+                h, cache, *hit = model.decode_step(
+                    params, cache, tok, pos, in_place=in_place, live=active,
+                    read=read)
                 if routed:
-                    h, cache, hit = model.decode_step(
-                        params, cache, tok, pos, in_place=in_place,
-                        live=active, read=read)
-                    tok = (tok, hit)
-                else:
-                    h, cache = model.decode_step(params, cache, tok, pos,
-                                                 in_place=in_place,
-                                                 read=read)
+                    tok = (tok, *hit)
                 logits = model.logits(params, h).astype(logits.dtype)
                 lengths = lengths + active.astype(lengths.dtype)
                 return (cache, logits, lengths, key), tok
@@ -704,6 +701,15 @@ class SlotManager:
         self.spec_accepted += int(tele[1])
         self.spec_rollbacks += int(tele[2])
         return toks
+
+    def kv_write_slots(self):
+        """The slots whose blocks the next decode step's write moves a
+        layer, from the host's own ``active``: the live ones where the
+        kernel takes the write, every slot of the table where the
+        scatter does."""
+        if self.kv_write == "kernel":
+            return int(np.count_nonzero(self.active))
+        return self.max_slots
 
     def attn_blocks(self):
         """``(read, table)``: the blocks of 128 rows that the next decode

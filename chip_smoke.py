@@ -438,7 +438,9 @@ def kv_write_leg(slots=48, heads=16, seq=1024, head_dim=64,
     serving step at the GPT-2 medium cell's table, ``f32[48,16,1024,64]``
     and its bfloat16 twin, every slot at another position: the two tables
     must come back the same bit for bit, so a Mosaic that accepts the
-    kernel and writes the wrong lane is caught outside the benchmark.
+    kernel and writes the wrong lane is caught outside the benchmark;
+    and again with 6 of the slots live, where the others must come back
+    as they went in.
     Also that the table as allocated selects the kernel here
     (``in_place_applies``: a TPU, positions minor on the device)."""
     import jax
@@ -459,6 +461,8 @@ def kv_write_leg(slots=48, heads=16, seq=1024, head_dim=64,
     edges = [0, seq - 1, seq // 2 - 1, seq // 2, 7, 8][:slots]
     pos[:len(edges)] = edges
     pos = jnp.asarray(pos, jnp.int32)
+    few = np.zeros(slots, bool)
+    few[np.random.default_rng(1).permutation(slots)[:6]] = True
     kernel = jax.jit(functools.partial(kv_write, interpret=interpret),
                      donate_argnums=(0, 1))
     selected = {}
@@ -476,6 +480,17 @@ def kv_write_leg(slots=48, heads=16, seq=1024, head_dim=64,
                  f"kv_write {name}: the table as allocated does not select "
                  f"the kernel (layout {k_table.format.layout})")
         want = jax.jit(plain_write)(k_table, v_table, *rest)
+        # a live slot the plain write's, a free one the table's own
+        held = jnp.asarray(few)[:, None, None, None]
+        masked = [jnp.where(held, w, t)
+                  for w, t in zip(want, (k_table, v_table))]
+        got = kernel(jnp.array(k_table), jnp.array(v_table), *rest,
+                     jnp.asarray(few))
+        wrong = int(differing(got[0], masked[0])
+                    + differing(got[1], masked[1]))
+        _require(wrong == 0, f"kv_write {name}: {wrong} elements differ "
+                             f"with 6 of {slots} slots live")
+        del masked
         got = kernel(k_table, v_table, *rest)        # consumes the tables
         wrong = int(differing(got[0], want[0]) + differing(got[1], want[1]))
         _require(wrong == 0, f"kv_write {name}: {wrong} elements differ "

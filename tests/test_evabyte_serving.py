@@ -297,7 +297,9 @@ def _pair_before_the_description(sm, in_place, bounded):
     """``SlotManager._build_fns`` as it stood before a model described
     its cache: the write and the read spelled for the one table of K and
     V, ``read = where(active, pos + 1, 0)`` (the dense, unlayouted,
-    pool-less pair)."""
+    pool-less pair). GPT-2's step is handed the table's mask too, as
+    LFM2's always was: the write kernel takes it as an operand since
+    PR 33."""
     model, stats = sm.model, sm.stats
     top_k, top_p, sampler, pmax, n_steps = (
         sm.top_k, sm.top_p, sm.sampler, sm.max_position, sm.steps_per_sync)
@@ -331,7 +333,8 @@ def _pair_before_the_description(sm, in_place, bounded):
                 tok = (tok, hit)
             else:
                 h, cache = model.decode_step(params, cache, tok, pos,
-                                             in_place=in_place, read=read)
+                                             in_place=in_place, live=active,
+                                             read=read)
             logits = model.logits(params, h).astype(logits.dtype)
             lengths = lengths + active.astype(lengths.dtype)
             return (cache, logits, lengths, key), tok

@@ -184,6 +184,7 @@ def test_remote_filesystem_hook():
     opt.optim_method = SGD(learningrate=0.1)
     opt._opt_state = opt.optim_method.init_state(model.params)
     opt._checkpoint(7)
+    opt._join_checkpoint()    # the write runs behind, on a worker thread
     assert "mem://bucket/ckpt/model.7" in blobs
     assert "mem://bucket/ckpt/optimMethod.7" in blobs
 
