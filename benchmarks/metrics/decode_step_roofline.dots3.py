@@ -1,0 +1,34 @@
+"""Least time the chip could take for one decode step of a dots3-note style model over the time it took.
+
+The least time is the larger of operations over peak FLOP/s and bytes over
+peak bytes/s for what the algorithm needs (``counts_dots3.decode_step_need``):
+every non-expert weight once, the weights of the experts the live streams HIT
+(the mean ``experts_hit`` of the traced ``serve/step`` spans) and not of the 32
+held, in each full layer the index keys of the positions SCORED
+(``dsa_context_rows`` x 256 B) and the latents of the positions READ
+(``dsa_selected_rows`` x 1152 B, not the context's), in each window layer
+``swa_rows`` x 2176 B, the rows written, one float32 row of logits a live
+stream. None where the program stamps no ``dsa_*`` rows.
+"""
+from benchmarks.harness import counts, counts_dots3, trace_reduce
+
+ROWS = ("live", "dsa_context_rows", "dsa_selected_rows", "swa_rows", "experts_hit")
+
+
+def read(ctx):
+    if ctx.trace is None or ctx.traced is None:
+        return None
+    step_ms = trace_reduce.executable_mean_ms(ctx, "step")
+    steps = [a for n, _, _, a in ctx.spans if n == "serve/step" and all(isinstance(a.get(k), (int, float)) for k in ROWS)]
+    if step_ms is None or not steps:
+        return None
+    live, scored, chosen, near, hit = (sum(a[k] for a in steps) / len(steps) for k in ROWS)
+    flops, nbytes = counts_dots3.decode_step_need(
+        counts_dots3.shape(ctx.config), live, scored, chosen, near, hit,
+        counts.dtype_bytes(ctx.config["dtype"]), counts.dtype_bytes(ctx.config["cache_dtype"]))
+    t_flops = flops / (ctx.peaks["flops_per_s"] * ctx.chips)
+    t_bytes = nbytes / (ctx.peaks["bytes_per_s"] * ctx.chips)
+    ctx.notes["decode_roofline_bound"] = "bytes" if t_bytes >= t_flops else "flops"
+    ctx.notes["decode_least_ms"] = 1e3 * max(t_flops, t_bytes)
+    ctx.notes["decode_need_gbytes"] = nbytes / 1e9
+    return 100.0 * max(t_flops, t_bytes) / (step_ms * 1e-3)

@@ -82,5 +82,8 @@ from bigdl_tpu.nn.misc import (  # noqa: F401
     Highway, ResizeBilinear)
 from bigdl_tpu.nn.conv import (  # noqa: F401
     SpatialSeperableConvolution)
-from bigdl_tpu.nn.moe import MoE, RoutedExperts  # noqa: F401
+from bigdl_tpu.nn.moe import (  # noqa: F401
+    MoE, RoutedExperts, SharedAndRoutedExperts)
+from bigdl_tpu.nn.latent import (  # noqa: F401
+    LatentAttention, SelectedLatentAttention, WindowLatentAttention)
 from bigdl_tpu.nn.gated import GatedMLP, GatedShortConv  # noqa: F401

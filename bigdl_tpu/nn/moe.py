@@ -240,6 +240,13 @@ class RoutedExperts(Module):
         gets zeros. ``hit`` is how many of the experts held got at least
         one assignment from a live row: the groups that are not empty,
         whose weights the product has to read."""
+        y, sizes = self.routed_sizes(params, x, live)
+        return y, jnp.sum(sizes > 0, dtype=jnp.int32)
+
+    def routed_sizes(self, params, x, live=None):
+        """As :meth:`routed` with the groups' sizes (count,) int32 in
+        place of their number: how many of the live rows' assignments
+        fell on each expert held."""
         n, k, c = x.shape[0], self.k, self.count
         chosen, w = self.route(params, x)
         local = chosen.reshape(-1) - self.first               # (N * k,)
@@ -266,9 +273,47 @@ class RoutedExperts(Module):
         ys = jnp.take(grouped(h, params["w2"]), back, axis=0)
         ys = jnp.where(mine[:, None], ys, 0.0).reshape(n, k, -1)
         y = jnp.sum(ys * w[:, :, None], axis=1)
-        return y, jnp.sum(sizes > 0, dtype=jnp.int32)
+        return y, sizes
 
     def call(self, params, x):
         shape = x.shape
         y, _ = self.routed(params, x.reshape(-1, shape[-1]))
+        return y.reshape(shape).astype(x.dtype)
+
+
+class SharedAndRoutedExperts(Module):
+    """:class:`RoutedExperts` beside ``n_shared`` experts that every
+    token passes through (the DeepSeek line's shared experts): ``y =
+    routed(x) + shared(x)``, the shared ones one ``GatedMLP`` of
+    ``n_shared x ffn_size``. Under expert parallelism every holder
+    computes the shared part alike, so it counts ONCE when the holders'
+    shares are added up. Arguments after ``n_shared`` are
+    :class:`RoutedExperts`'s."""
+
+    product = RoutedExperts.product
+
+    def __init__(self, hidden_size, ffn_size, num_experts, k, n_shared=1,
+                 **routed_kw):
+        super().__init__()
+        from bigdl_tpu.nn.gated import GatedMLP
+        self.experts = RoutedExperts(hidden_size, ffn_size, num_experts, k,
+                                     **routed_kw)
+        self.shared = GatedMLP(hidden_size, n_shared * ffn_size)
+
+    def make_params(self, rng, input_spec):
+        k1, k2 = jax.random.split(rng)
+        return {"routed": self.experts.make_params(k1, None),
+                "shared": self.shared.make_params(k2, None)}
+
+    def routed(self, params, x, live=None):
+        """As ``RoutedExperts.routed`` with the shared experts added,
+        and, third, how many of the live rows' assignments fell on the
+        experts held."""
+        y, sizes = self.experts.routed_sizes(params["routed"], x, live)
+        return (y + self.shared.call(params["shared"], x),
+                jnp.sum(sizes > 0, dtype=jnp.int32), jnp.sum(sizes))
+
+    def call(self, params, x):
+        shape = x.shape
+        y, _, _ = self.routed(params, x.reshape(-1, shape[-1]))
         return y.reshape(shape).astype(x.dtype)

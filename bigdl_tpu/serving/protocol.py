@@ -2,9 +2,9 @@
 
 A model is served through these names and through nothing else of it
 (``models/gpt.py``'s ``GPTForCausalLM``, ``models/lfm2.py``'s
-``LFM2ForCausalLM`` and ``models/evabyte.py``'s ``EvaByteForCausalLM``
-are the three implementations; docs/serving.md has the contract in
-prose):
+``LFM2ForCausalLM``, ``models/evabyte.py``'s ``EvaByteForCausalLM`` and
+``models/dots3.py``'s ``Dots3ForCausalLM`` are the four implementations;
+docs/serving.md has the contract in prose):
 
 ``vocab_size``, ``max_position``
     ints: the width of a row of logits, and the positions a slot holds
@@ -30,12 +30,20 @@ prose):
     GPT-2 and LFM2 describe one table, K and V of ``max_position`` rows
     written at ``pos`` and read up to it; EvaByte two, a window written
     at ``pos mod 2048`` and chunk summaries that gain a row every 16th
-    step. From the tables AS ALLOCATED the slot table derives whether
+    step; Dots3 three, the index keys of every position (all read), the
+    latents of every position READ BY SELECTION (``selected``: every
+    row up to ``pos`` is scored, the 2048 best count, and which they are
+    is decided on the device) and a ring of 513 latents, the last two
+    read by the model's own kernel (``own_read``). From the
+    tables AS ALLOCATED the slot table derives whether
     ``ops/kv_write.py`` takes the step's writes (every table's leaves
     lie as the kernel needs them, rows third among it) and whether
-    ``ops/decode_attention.py`` takes its read (one table, read under one
-    softmax, that fits the kernel), and from the row counts
-    ``serve/step``'s ``attn_blocks`` and ``attn_blocks_table``.
+    ``ops/decode_attention.py`` takes its read (one table, read from row
+    0 under one softmax, that fits the kernel; never a table read by
+    selection), and from the row counts ``serve/step``'s ``attn_blocks``
+    (what the step's reads fetch: ``read_rows`` under the table's kernel,
+    ``own_read``'s count under the model's, else every row held) and
+    ``attn_blocks_table``.
 ``prefill(params, cache, ids, prompt_len) -> (h_last, cache)``
     ``ids`` (W, bucket) right-padded, ``prompt_len`` (W,): the final
     hidden row at each prompt's last real position, and ``cache`` (W
@@ -82,7 +90,7 @@ prose):
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable
+from typing import Callable, Optional
 
 # the engine's optional features, by the constructor argument (or the
 # family of arguments) that switches each on
@@ -106,8 +114,27 @@ class RowTable:
     arrays inside the step alike. ``row_axis`` is where the rows lie in a
     leaf: 2 is ``(slots, heads, rows, head_dim)``, the shape the two
     kernels know; a model that keeps another (1: ``(slots, rows, heads,
-    head_dim)``, a row whole tiles of a head of 128) keeps the plain
-    write and the masked read.
+    head_dim)``, a row whole tiles of a head of 128; or ``(slots, rows,
+    width)``, a latent with no head axis) keeps the plain write and the
+    masked read.
+
+    ``selected`` marks a table READ BY SELECTION: of the rows up to
+    ``pos`` the step reads ``read_rows(pos)``, CHOSEN on the device (by
+    scores against another table's leaves, the model's business), not
+    the first so many. Neither kernel takes such a table whatever its
+    shape (:attr:`kernel_shaped`): both move or read a slot's leading
+    rows.
+
+    ``own_read`` is None, or the model's word on a kernel of its OWN for
+    this table's read: ``own_read(leaf)``, asked once with the table's
+    first leaf as allocated, gives None where that kernel does not take
+    it (the model's step then reads every row of every slot under a
+    mask, as the slot table's masked read does) or ``fetched(pos)``, the
+    rows the kernel moves of a live slot whose next step is at ``pos``
+    (arithmetic on ``pos`` like the others; of a free slot it moves
+    none). The model's step decides by the same test, so the slot table
+    knows what is read without choosing it: ``attn_read`` says
+    ``"model"`` and ``attn_blocks`` counts what is fetched.
     """
 
     leaves: tuple
@@ -115,6 +142,15 @@ class RowTable:
     write_row: Callable
     read_rows: Callable
     row_axis: int = 2
+    selected: bool = False
+    own_read: Optional[Callable] = None
+
+    @property
+    def kernel_shaped(self):
+        """Whether ``ops/kv_write.py`` and ``ops/decode_attention.py``
+        may be asked about this table's leaves at all: rows third, and
+        read from row 0 on."""
+        return self.row_axis == 2 and not self.selected
 
 
 def positions_table(rows):

@@ -118,7 +118,8 @@ class SlotManager:
     # stamped beside it: "kernel" is ``ops/decode_attention.py``, each
     # live slot's own blocks of 128 positions and nothing of a free slot;
     # "masked" scores the whole table and masks (the paged and the
-    # speculative steps too)
+    # speculative steps too); "model" is a kernel of the model's own for
+    # a table it describes so (``RowTable.own_read``)
     attn_read = "masked"
     # how the sampled branch of token selection finds its top-k and
     # nucleus cuts, fixed with them from the logits table as allocated
@@ -185,6 +186,7 @@ class SlotManager:
         # the leaves a stream fills row by row, as the model describes
         # them: which kernels apply and ``attn_blocks`` come from these
         self._tables = tuple(model.cache_tables())
+        self._own_reads = (None,) * len(self._tables)
         # a model that counts its own rows on the host: the running sums
         # of what it stamps on the spans, under the same names
         self._counted = hasattr(model, "step_counts")
@@ -328,14 +330,21 @@ class SlotManager:
         tables = self._tables
         allocated = [self._table_leaf(self._cache, t) for t in tables]
         in_place = bool(tables) and all(
-            t.row_axis == 2 and in_place_applies(a, self.layout)
+            t.kernel_shaped and in_place_applies(a, self.layout)
             for t, a in zip(tables, allocated))
         self.kv_write = "kernel" if in_place else "scatter"
         # ... and whether the length-bounded attention takes its read:
-        # the kernel scores ONE table a slot under one softmax
-        bounded = len(tables) == 1 and tables[0].row_axis == 2 \
+        # the kernel scores ONE table a slot under one softmax, from row
+        # 0 on: never a table whose rows read are chosen on the device
+        bounded = len(tables) == 1 and tables[0].kernel_shaped \
             and decode_attention.applies(allocated[0], self.layout)
-        self.attn_read = "kernel" if bounded else "masked"
+        # a model may read a table through a kernel of its own: the
+        # table's description says whether it takes the table as allocated
+        # and what it then fetches (``RowTable.own_read``)
+        self._own_reads = [t.own_read and t.own_read(a)
+                           for t, a in zip(tables, allocated)]
+        self.attn_read = "kernel" if bounded else \
+            "model" if any(self._own_reads) else "masked"
         # routed experts: the step also counts the experts its live slots
         # hit, one number a step beside the tokens
         routed = bool(model.experts_per_token)
@@ -393,7 +402,7 @@ class SlotManager:
             (cache, logits_buf, _, key), toks = lax.scan(
                 one, (cache, logits_buf, lengths, key), None,
                 length=n_steps)
-            # toks (n_steps, S); routed: (toks, hits (n_steps,))
+            # toks (n_steps, S); routed: (toks, hits (n_steps,)[, held])
             return cache, logits_buf, key, toks
 
         # ``jax.jit`` names an executable ``jit_`` + its function's name,
@@ -668,12 +677,18 @@ class SlotManager:
         with obs.leaf_span("serve/step.readback", iter=self.iter):
             toks = jax.device_get(toks)
         if self.experts is not None:
-            toks, hits = toks
+            toks, hits, *held = toks
             asked = int(self.active.sum()) * self.model.experts_per_token
             self.stats.add("moe_experts_hit", float(hits.sum()))
             self.stats.add("moe_assignments", self.steps_per_sync * asked)
             attrs.update(experts=self.experts, assignments=asked,
                          experts_hit=float(hits.mean()))
+            if held:
+                # a holder of a SHARE of the experts: how many of those
+                # assignments fell on the experts it holds
+                self.stats.setdefault("moe_assignments_held", 0.0)
+                self.stats.add("moe_assignments_held", float(held[0].sum()))
+                attrs["assignments_held"] = float(held[0].mean())
         self.step_attrs = attrs
         self.lengths[self.active] = np.minimum(
             self.lengths[self.active] + self.steps_per_sync,
@@ -715,16 +730,24 @@ class SlotManager:
         """``(read, table)``: the blocks of 128 rows that the next decode
         step's attention reads a layer over the model's row tables, and
         those the tables hold; from the host's own ``lengths`` and
-        ``active``. The masked read reads the tables."""
+        ``active``. The table's kernel reads a live slot's
+        ``read_rows``, a model's own kernel what it says it fetches
+        (``RowTable.own_read``), and a masked read every row held."""
         def blocks(rows):
             return -(-rows // decode_attention.BLOCK)
 
-        table = self.max_slots * sum(blocks(t.rows) for t in self._tables)
-        if self.attn_read != "kernel":
-            return table, table
         pos = self.lengths[self.active]
-        return int(sum(blocks(t.read_rows(pos)).sum()
-                       for t in self._tables)), table
+        read = table = 0
+        for t, own in zip(self._tables, self._own_reads):
+            held = self.max_slots * blocks(t.rows)
+            table += held
+            if own:
+                read += int(blocks(own(pos)).sum())
+            elif self.attn_read == "kernel":
+                read += int(blocks(t.read_rows(pos)).sum())
+            else:
+                read += held
+        return read, table
 
     def sampled(self):
         """Live slots whose next token is drawn (``temps`` > 0), from the

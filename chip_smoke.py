@@ -609,6 +609,102 @@ def decode_attention_leg(tables=(((48, 16, 1024, 64), 1, "float32"),
             "tolerance": tol, "errors": errors, "selected": selected}
 
 
+# how far the logits of the two-layer latent model, served in bfloat16,
+# may lie from the float32 reference's: the root of their mean square, and
+# the widest single one. A bfloat16 rounding turns a selection at its
+# 2048th place, as it turns an expert choice, and a turned selection moves
+# a few logits by a good part of their spread (1.44): the widest read 1.28
+# on the chip (my chip run, PR 34), so it is held loosely; a wrong row, a
+# wrong selection or a slipped ring moves EVERY logit of a position by
+# that spread, which is what the mean square holds
+LATENT_TOL = {"rms": 0.25, "widest": 3.0}
+
+
+def latent_read_leg(model_kw=None, first=4096, steps=64, dtype="bfloat16",
+                    tol=LATENT_TOL, weights_spec=None):
+    """One full layer (latent attention over the positions an indexer
+    picks) and one window layer of ``models/dots3.py`` at the published
+    widths, a dense feed-forward each: two streams are prefilled just
+    past position ``first`` and decoded for ``steps`` steps through the
+    slot table, and after the prefill and after every step each stream's
+    logits are held to ``benchmarks/reference/dots3.py``'s rows of the
+    whole sequence (true float32, K and V expanded, a plain top-k). The
+    contexts are twice the selection and eight times the window, so a
+    step that reads a wrong row, selects wrongly or lets the ring slip is
+    caught here, without a benchmark run: the check a later kernel for
+    the selected read can use. ``tol`` holds the root mean square and the
+    widest of the logit differences; both readings are reported."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.harness import weights
+    from benchmarks.reference import dots3 as reference_mod
+    from bigdl_tpu.models.dots3 import Dots3ForCausalLM
+    from bigdl_tpu.serving.slots import SlotManager
+
+    kw = dict(vocab_size=2048, layer_types=["full_attention",
+                                            "sliding_attention"],
+              first_k_dense_replace=2, max_position=first + 2048,
+              rope_theta=8e7, swa_rope_theta=5e4)
+    kw.update(model_kw or {})
+    t_start = time.perf_counter()
+    model = Dots3ForCausalLM(**kw)
+    # the reference reads every size from the keyword arguments
+    import inspect
+    defaults = {k: v.default for k, v in inspect.signature(
+        Dots3ForCausalLM.__init__).parameters.items()
+        if v.default is not inspect.Parameter.empty}
+    ref_kw = dict(defaults, **kw)
+    shapes = jax.eval_shape(lambda k: model.setup(k, None)[0],
+                            jax.random.key(0))
+    params = weights.make_params(
+        shapes, 34, weights_spec or {"std": 0.02, "gain_std": 0.1,
+                                     "bias_std": 0.1}, dtype=dtype)
+    reference, _ = reference_mod.make({"constructor_kwargs": ref_kw})
+    pmax = kw["max_position"]
+    rng = np.random.default_rng(34)
+    lengths = (first + 1, first + 37)
+    seqs = [rng.integers(0, kw["vocab_size"], pmax).astype(np.int32)
+            for _ in lengths]
+    sm = SlotManager(model, params, max_slots=2, window=1)
+    slots = [sm.admit([s[:n]])[0] for s, n in zip(seqs, lengths)]
+    want = [np.asarray(reference(
+        params, s, np.arange(n - 1, n + steps, dtype=np.int32)))
+        for s, n in zip(seqs, lengths)]
+    worst, squares, spread = 0.0, [], float(np.std(want[0]))
+    with _compile_log() as compiles:
+        for step in range(steps + 1):
+            if step == 2:
+                steady_from = time.perf_counter()
+            got = np.asarray(sm._logits, np.float32)
+            for slot, w in zip(slots, want):
+                gap = np.abs(got[slot] - w[step])
+                worst = max(worst, float(gap.max()))
+                squares.append(float(np.square(gap, dtype=np.float64).mean()))
+            if step == steps:
+                break
+            # feed the sequence's own next token: plant it as the only
+            # finite logit of the slot's row
+            forced = np.full(got.shape, -np.inf, np.float32)
+            for slot, s, n in zip(slots, seqs, lengths):
+                forced[slot, s[n + step]] = 0.0
+            sm._logits = jnp.asarray(forced, sm._logits.dtype)
+            sm.step()
+    late = [name for t, name in compiles if steps >= 2 and t >= steady_from]
+    _require(not late, f"latent_read: compiled inside the steady steps: "
+                       f"{late}")
+    rms = float(np.sqrt(np.mean(squares)))
+    _require(rms <= tol["rms"] and worst <= tol["widest"],
+             f"latent_read: the logits lie {rms} (root mean square; widest "
+             f"{worst}) from the reference's, over {tol} (spread {spread})")
+    return {"ok": True, "setup_s": round(time.perf_counter() - t_start, 2),
+            "tolerance": tol, "logit_gap": worst, "logit_gap_rms": rms,
+            "logit_spread": spread,
+            "positions": [first, first + 37 + steps],
+            "selected_of": [kw.get("index_topk", 2048), first],
+            "kv_write": sm.kv_write, "attn_read": sm.attn_read}
+
+
 # ------------------------------------------------------------------ train --
 def train_leg(model, x_shape, n_class, steps, compute_dtype, seed=0):
     """A few optimizer steps on one repeated seeded batch through the
@@ -800,6 +896,7 @@ def main():
         ("kv_write", kv_write_leg),
         ("decode_attention", decode_attention_leg),
         ("sampling", sampling_leg),
+        ("latent_read", latent_read_leg),
         ("train", train_resnet50),
     ]
     if device["count"] >= 4:

@@ -79,6 +79,29 @@ def test_sampling_leg():
     assert rec["selected"] == {"12x1000": False, "4x300": False}
 
 
+def test_latent_read_leg():
+    """A full layer and a window layer at toy widths in float32: prefill
+    past the selection of 8 and the window of 5, then steps, held to the
+    plain reference's rows."""
+    rec = chip_smoke.latent_read_leg(
+        model_kw=dict(
+            vocab_size=50, hidden_size=64, intermediate_size=96,
+            num_attention_heads=4, q_lora_rank=16, kv_lora_rank=16,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            index_n_heads=3, index_head_dim=16, index_topk=8,
+            swa_num_attention_heads=2, swa_q_lora_rank=16,
+            swa_kv_lora_rank=24, swa_qk_nope_head_dim=24,
+            swa_qk_rope_head_dim=8, swa_v_head_dim=16,
+            sliding_window_size=5, max_position=64, prefill_block=8),
+        first=16, steps=10, dtype="float32",
+        tol={"rms": 5e-5, "widest": 5e-5},
+        weights_spec={"std": 0.2, "gain_std": 0.1, "bias_std": 0.5})
+    assert rec["ok"] and rec["logit_gap"] <= 5e-5 < rec["logit_spread"]
+    assert rec["logit_gap_rms"] <= rec["logit_gap"]
+    # not on a TPU, and never for a table read by selection
+    assert (rec["kv_write"], rec["attn_read"]) == ("scatter", "masked")
+
+
 def test_train_leg(multi_device_cpu):
     from bigdl_tpu.models.resnet import ResNet
     rec = chip_smoke.train_leg(

@@ -334,3 +334,28 @@ def test_sampled_branch_compiled_at_the_cell_size_sorts_nothing(
     assert "tpu_custom_call" in text and "sample_cutoffs" in text
     assert not re.search(r"\bsort\(|top[_-]?k", text, re.I)
     assert sampling._vmem_bytes(shape[1]) <= 16 * 2 ** 20
+
+
+@pytest.mark.parametrize("shape,heads,v_width", [
+    ((36, 32768, 640), 128, 512),
+    ((36, 640, 1152), 64, 1024),
+], ids=["dots3_full_layer", "dots3_window_ring"])
+def test_latent_read_compiled_at_the_cell_size(one_chip, shape, heads,
+                                               v_width):
+    """``ops/latent_attention.py`` (the read of a latent cache, PR 34) at
+    ``dots3-longctx-generate``'s tables, their rows of 576 and 1088
+    numbers kept in whole lanes (640 and 1152). Kept here, beside the
+    other step kernels' compiles, so that one worker loads the TPU's
+    compiler."""
+    from bigdl_tpu.ops import latent_attention as lat
+    b, rows, width = shape
+    at = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    read = jax.jit(lambda *a: lat._latent_attention(*a, v_width, False))
+    compiled = read.lower(
+        at((b, heads, width), jnp.bfloat16), at(shape, jnp.bfloat16),
+        at((b, rows), jnp.float32), at((b,), jnp.int32),
+        at((b,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_attention" in text
+    # the table goes in as it lies: no copy of it around the call
+    assert not re.search(rf"copy\(\w*\[{b},{rows},{width}\]", text)
