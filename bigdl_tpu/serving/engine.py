@@ -568,6 +568,11 @@ class ServingEngine:
             "prefill_traces": st["prefill_traces"],
             "step_traces": st["step_traces"],
             "dispatches": st["dispatches"],
+            # the loop's dispatch-ahead: blocks dispatched with another
+            # still in flight; slot-blocks computed for a stream the host
+            # then found finished (EOS, cancel, deadline)
+            "steps_ahead": st["steps_ahead"],
+            "junk_slot_blocks": st["junk_slot_blocks"],
             "tp_degree": self.tp,
             "mesh_devices": (1 if self.layout is None
                              else self.layout.num_devices),

@@ -57,7 +57,7 @@ import numpy as np
 from jax import lax
 
 from bigdl_tpu.resilience.faults import FaultError, fault_point
-from bigdl_tpu.serving.slots import SlotManager, select_tokens
+from bigdl_tpu.serving.slots import DecodeBlock, SlotManager, select_tokens
 
 logger = logging.getLogger("bigdl_tpu.serving")
 
@@ -283,6 +283,11 @@ class PagedSlotManager(SlotManager):
     """
 
     paged = True
+    # the pages a block writes are reserved from the lengths its
+    # predecessor left (``reserve_block``), a chunked prefill turns
+    # ``active`` on between blocks, and an exhausted pool preempts a
+    # stream: the owner reads each block back before it dispatches the next
+    runs_ahead = False
     _stat_keys = ("prefill_traces", "step_traces", "copy_traces")
     _obs_name = "serving_paged"
     _load_fn = None
@@ -1238,18 +1243,16 @@ class PagedSlotManager(SlotManager):
             raise
         self.stats.dispatched()
 
-    def step(self):
-        """One block of ``steps_per_sync`` decode steps across every
-        slot in a single dispatch (call :meth:`reserve_block` first).
-        Same contract as the dense step: (steps_per_sync, max_slots)
-        host tokens, inactive rows junk — or the speculative
-        variable-commit block with ``last_counts`` when
-        ``spec_tokens`` > 1."""
+    def dispatch_step(self):
+        """Dispatch one block of ``steps_per_sync`` decode steps across
+        every slot (call :meth:`reserve_block` first); the dense
+        manager's contract, except that ``lengths`` advance at the
+        readback."""
         extra = self._adapter_args(self.adapter_slots)
         try:
             if self.spec_tokens > 1:
-                (self._pools, self._logits, self._key, self._table, toks,
-                 counts, tele) = self._step_fn(
+                (self._pools, self._logits, self._key, self._table,
+                 *toks) = self._step_fn(
                     self.params, self._pools, self._logits,
                     self.page_table, self.lengths, self.active,
                     self.temps, self._key, self._table, self._last_tok,
@@ -1263,10 +1266,17 @@ class PagedSlotManager(SlotManager):
             self.poisoned = True
             raise
         self.stats.dispatched()
+        return DecodeBlock(toks, {})
+
+    def read_step(self, block):
+        """Same contract as the dense readback: (steps_per_sync,
+        max_slots) host tokens, inactive rows junk — or the speculative
+        variable-commit block with ``last_counts`` when
+        ``spec_tokens`` > 1."""
         if self.spec_tokens > 1:
-            toks = self._finish_spec_block(toks, counts, tele)
+            toks = self._finish_spec_block(*block.toks)
         else:
-            toks = jax.device_get(toks)        # ONE readback per block
+            toks = jax.device_get(block.toks)  # ONE readback per block
             self.lengths[self.active] = np.minimum(
                 self.lengths[self.active] + self.steps_per_sync,
                 self.max_position)

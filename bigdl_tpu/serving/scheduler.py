@@ -14,6 +14,14 @@ with :class:`QueueFullError` instead of buffering unboundedly, and each
 request's token stream is a bounded queue sized by its own
 ``max_new_tokens``.
 
+Dispatch-ahead (docs/serving.md): where the slot manager's next block
+needs nothing of the last block's tokens (``SlotManager.runs_ahead``), the
+loop keeps ONE block in flight: an iteration admits, dispatches block N+1
+and only then reads back and delivers block N, so the host's round trip
+lies behind the device's work. A stream that ends by count is taken out
+of the table at the dispatch of its last block; an EOS, a cancel or a
+deadline is seen a block late, and that slot's one junk block is dropped.
+
 Failure model (docs/resilience.md): the decode loop never dies holding
 requests. A step/admit exception triggers in-place recovery — the slot
 table is rebuilt and every in-flight request re-prefilled from its full
@@ -81,6 +89,23 @@ class _Halt(BaseException):
 
 
 _DONE = object()
+
+
+class _Flight:
+    """A decode block between its dispatch and its delivery: the slot
+    manager's ``block``, and ``streams``, the ``(slot, request, length
+    before the block)`` of every stream it decodes for. A stream that
+    ends by count with this block leaves ``Scheduler._inflight`` at the
+    dispatch and is held here alone until its tokens are delivered;
+    ``takes`` is what each request gets of the block by count (the next
+    dispatch reckons with it before the tokens are read)."""
+
+    __slots__ = ("block", "streams", "takes")
+
+    def __init__(self, block, streams, takes):
+        self.block = block
+        self.streams = streams
+        self.takes = takes
 
 
 class Request:
@@ -271,6 +296,10 @@ class Scheduler:
                                       8, int)
         self.max_recoveries = int(max_recoveries)
         self._inflight = {}            # slot -> Request (loop thread only)
+        # blocks dispatched and not yet delivered, oldest first: at most
+        # one between iterations (two inside ``_step``), none for a
+        # manager that cannot run ahead
+        self._flight = collections.deque()
         # requests the loop holds OUTSIDE _waiting/_inflight (a popped
         # admission batch, a recovery set): abandon()/_give_up() must see
         # them or a mid-admission crash would strand them
@@ -699,7 +728,7 @@ class Scheduler:
             self._abandoned = True
             self._accepting = False
             pool = list(self._waiting) + self._limbo \
-                + list(self._inflight.values())
+                + list(self._inflight.values()) + self._in_flight_only()
             self._waiting.clear()
             self._obs["queue_depth"].set(0)
             self._cond.notify()
@@ -894,6 +923,7 @@ class Scheduler:
         if not (force or snap.due()):
             return
         try:
+            self._settle()
             streams = []
             for s, r in list(self._inflight.items()):
                 if self.slots.active[s]:
@@ -977,7 +1007,7 @@ class Scheduler:
             batch = []
             with self._cond:
                 if (self._accepting and not self._waiting
-                        and not self._inflight):
+                        and not self._inflight and not self._flight):
                     with obs.leaf_span("serve/idle", iter=it):
                         while (self._accepting and not self._waiting
                                and not self._inflight):
@@ -990,8 +1020,13 @@ class Scheduler:
                         w = self._waiting.popleft()
                         w._finish(err)
                         self._journal_retire(w)
-                    for s, r in list(self._inflight.items()):
+                    # a block in flight is dropped with the streams it
+                    # alone still held
+                    ending = self._in_flight_only()
+                    self._flight.clear()
+                    for s in self._inflight:
                         slots.retire(s)
+                    for r in [*self._inflight.values(), *ending]:
                         r._finish(err)
                         self._journal_retire(r)
                     self._inflight.clear()
@@ -1006,7 +1041,7 @@ class Scheduler:
                 # pick are three disjoint leaves: a request whose deadline
                 # ran out in the queue fails once the hold is over
                 if (self.admit_wait_s > 0 and self._accepting
-                        and not self._inflight
+                        and not self._inflight and not self._flight
                         and 0 < len(self._waiting) < slots.window):
                     with obs.leaf_span("serve/idle", iter=it):
                         deadline = time.perf_counter() + self.admit_wait_s
@@ -1019,7 +1054,8 @@ class Scheduler:
                     self._sweep_waiting_locked()
                     queued = len(self._waiting)
                     pick.set(queued=queued, n=0)
-                    if not queued and not self._inflight:
+                    if (not queued and not self._inflight
+                            and not self._flight):
                         if not self._accepting:
                             return
                         continue
@@ -1049,6 +1085,8 @@ class Scheduler:
                 self._sweep_inflight()
                 if paged and getattr(slots, "host_tier", None) is not None:
                     self._prefetch_host_tier()
+            # admission BEFORE the block's dispatch: an admitted request's
+            # first token comes out of the very next block
             if batch:
                 if paged:
                     self._admit_paged(batch)
@@ -1073,9 +1111,9 @@ class Scheduler:
                     continue
                 self._beat()
                 self._update_paged_gauges()
-            if not self._inflight:
+            if not self._inflight and not self._flight:
                 continue
-            if paged:
+            if paged and self._inflight:
                 if not any(slots.active[s] for s in self._inflight):
                     continue       # everything in flight is still prefilling
                 try:
@@ -1090,25 +1128,13 @@ class Scheduler:
                     self._obs["failures"].inc()
                     self._recover(list(self._inflight.values()), e)
                     continue
-            pre_lengths = slots.lengths.copy()
-            attn_blocks, attn_blocks_table = slots.attn_blocks()
             try:
-                fault_point("serving.step",
-                            requests=tuple(r.id
-                                           for r in self._inflight.values()))
-                with obs.span("serve/step", iter=it,
-                              live=len(self._inflight),
-                              kv_write=slots.kv_write,
-                              kv_write_slots=slots.kv_write_slots(),
-                              attn_read=slots.attn_read,
-                              attn_blocks=attn_blocks,
-                              attn_blocks_table=attn_blocks_table,
-                              sampler=slots.sampler,
-                              sampled=slots.sampled()) as step_span:
-                    toks = slots.step()    # (steps_per_sync, max_slots)
-                    # a model with routed experts: ``experts``,
-                    # ``assignments``, ``experts_hit``
-                    step_span.set(**slots.step_attrs)
+                if self._inflight:
+                    fault_point("serving.step",
+                                requests=tuple(
+                                    r.id for r in self._inflight.values()))
+                with obs.span("serve/step", iter=it) as step_span:
+                    flight, toks = self._step(step_span)
             except _Halt:
                 raise
             except BaseException as e:
@@ -1124,9 +1150,11 @@ class Scheduler:
             dt = step_span.duration
             self.step_seconds += dt
             self._obs["step_seconds"].inc(dt)
-            with obs.leaf_span("serve/deliver", iter=it) as deliver:
-                tokens, retired = self._deliver_block(toks, pre_lengths)
-                deliver.set(tokens=tokens, retired=retired)
+            if flight is not None:
+                with obs.leaf_span("serve/deliver", iter=it) as deliver:
+                    tokens, retired = self._deliver_block(toks,
+                                                          flight.streams)
+                    deliver.set(tokens=tokens, retired=retired)
             with obs.leaf_span("serve/after", iter=it):
                 self._maybe_snapshot()
                 self._update_spec_gauges()
@@ -1143,6 +1171,112 @@ class Scheduler:
                         note["spec_accepted"] = slots.spec_accepted
                     reqtrace.default_flight().note_iteration(
                         self.obs_label, **note)
+
+    # ---------------------------------------------------- the decode block --
+    def _in_flight_only(self):
+        """The unfinished requests that only a block in flight still
+        holds: their last block was dispatched, so they left
+        ``_inflight``, and its tokens are not delivered yet."""
+        held = {id(r) for r in self._inflight.values()}
+        only = []
+        for flight in list(self._flight):
+            for _, r, _ in flight.streams:
+                if id(r) not in held and not r.done.is_set():
+                    held.add(id(r))
+                    only.append(r)
+        return only
+
+    def _step(self, span):
+        """One iteration's decode work, inside its ``serve/step`` span:
+        dispatch the next block for the live streams, THEN read back the
+        block that was in flight, so the chip runs the one while the
+        host waits for, delivers and sweeps after the other. Returns
+        ``(flight, host tokens)`` of the block read back, ``(None,
+        None)`` when none was (the first block of a busy stretch: it is
+        read in the next iteration). A manager that cannot run ahead
+        reads back the block it has just dispatched, which is the order
+        of a loop without this. With no live stream left the iteration
+        only drains the block in flight."""
+        slots = self.slots
+        old = self._flight[0] if self._flight else None
+        if self._inflight:
+            attn_blocks, attn_blocks_table = slots.attn_blocks()
+            # of the block dispatched here ...
+            span.set(live=len(self._inflight), ahead=int(old is not None),
+                     kv_write=slots.kv_write,
+                     kv_write_slots=slots.kv_write_slots(),
+                     attn_read=slots.attn_read, attn_blocks=attn_blocks,
+                     attn_blocks_table=attn_blocks_table,
+                     sampler=slots.sampler, sampled=slots.sampled())
+            new = self._dispatch_block(old)
+            # (a model's own counts; with routed experts ``experts`` and
+            # ``assignments``)
+            span.set(**new.block.dispatched)
+            if old is not None:
+                slots.stats.add("steps_ahead", 1)
+            elif not slots.runs_ahead:
+                old = new
+        else:
+            span.set(ahead=0)
+        if old is None:
+            return None, None
+        toks = self._read_oldest()
+        # ... and of the block read back here, one older when ahead
+        # (``experts_hit``, ``assignments_held``)
+        span.set(**old.block.read)
+        return old, toks
+
+    def _read_oldest(self):
+        """Read back the oldest block in flight and take it off the
+        list; its host tokens, (steps_per_sync, max_slots)."""
+        toks = self.slots.read_step(self._flight[0].block)
+        with self._cond:
+            self._flight.popleft()
+        return toks
+
+    def _settle(self):
+        """Read back and deliver whatever block is in flight, so that
+        what looks at ``lengths``, ``_inflight`` or the cache as a whole
+        (a page snapshot, a preemption) sees the table as of the tokens
+        the callers have. The next iteration starts a stretch again."""
+        while self._flight:
+            streams = self._flight[0].streams
+            self._deliver_block(self._read_oldest(), streams)
+
+    def _dispatch_block(self, old=None):
+        """Dispatch one decode block and record whom it decodes for.
+        Where the manager runs ahead, a stream that ends by count with
+        this block (``max_new_tokens`` or the table's room: arithmetic
+        on what the host has, less what ``old``, the block still in
+        flight, will deliver) leaves the table HERE, so the block after
+        its last token computes nothing for it and its slot takes the
+        next admission; its tokens reach it at the delivery."""
+        slots = self.slots
+        ahead = slots.runs_ahead
+        n_steps = slots.steps_per_sync
+        owed = old.takes if old is not None else {}
+        streams, takes, ending = [], {}, []
+        for s, r in self._inflight.items():
+            if not slots.active[s]:
+                continue           # paged: still prefilling in chunks
+            pre = int(slots.lengths[s])
+            streams.append((s, r, pre))
+            if ahead:
+                left = r.remaining() - owed.get(r.id, 0)
+                room = max(0, int(slots.max_position) - pre)
+                takes[r.id] = take = min(n_steps, left, room)
+                if take >= left or take >= room:
+                    ending.append(s)
+        flight = _Flight(slots.dispatch_step(), streams, takes)
+        with self._cond:
+            self._flight.append(flight)
+            for s in ending:
+                del self._inflight[s]
+        for s in ending:
+            slots.retire(s)
+        if ending:
+            self._obs["slot_occupancy"].set(slots.occupancy())
+        return flight
 
     # ------------------------------------------------------- admission ----
     def _admit(self, batch):
@@ -1326,6 +1460,7 @@ class Scheduler:
         streams keep decoding. A lone stream that cannot reserve its
         next positions can never finish: it fails typed instead."""
         slots = self.slots
+        self._settle()
         if len(self._inflight) <= 1:
             for s, r in list(self._inflight.items()):
                 with self._cond:
@@ -1433,24 +1568,30 @@ class Scheduler:
                 sl.spec_accepted / sl.spec_proposed)
 
     # -------------------------------------------------------- delivery ----
-    def _deliver_block(self, toks, pre_lengths=None):
-        """Fan one step block's token columns out to the in-flight
-        requests, retiring EOS/max-token completions. ``pre_lengths``
-        (the slot lengths BEFORE the block's dispatch) bounds each
-        column to the positions the slot table can actually hold: a
-        request whose ``prompt_len + generated`` reaches
-        ``max_position`` is force-retired (``Request.truncated``)
-        instead of being fed clamped-position junk. Returns
+    def _deliver_block(self, toks, streams):
+        """Fan one step block's token columns out to the requests it was
+        dispatched for (``streams`` of its ``_Flight``: slot, request,
+        the slot's length BEFORE the dispatch), retiring EOS/max-token
+        completions. The length bounds each column to the positions the
+        slot table can actually hold: a request whose ``prompt_len +
+        generated`` reaches ``max_position`` is force-retired
+        (``Request.truncated``) instead of being fed clamped-position
+        junk. A request that finished while the block was in flight (an
+        EOS in the block before, a cancel, a deadline) gets nothing of
+        it: its column is junk, counted in ``junk_slot_blocks``. Returns
         ``(tokens delivered, requests retired)``."""
         done = []
+        junk = 0
         tokens_before = self.generated_tokens
         # speculative managers commit a VARIABLE count per slot each
         # block (1..block_span); last_counts bounds each column to the
         # tokens actually committed
         counts = getattr(self.slots, "last_counts", None)
-        for s, r in self._inflight.items():
-            if not self.slots.active[s]:
-                continue           # paged: still prefilling in chunks
+        pmax = int(self.slots.max_position)
+        for s, r, pre in streams:
+            if r.done.is_set():
+                junk += 1
+                continue
             # vectorized per-slot delivery: the block's token column,
             # truncated at max_new_tokens / first EOS (the tail past
             # either is junk the model kept decoding)
@@ -1458,12 +1599,10 @@ class Scheduler:
             col = col[:r.remaining()]
             finished = col.size == r.remaining()
             capped = False
-            if pre_lengths is not None:
-                room = max(0, int(self.slots.max_position)
-                           - int(pre_lengths[s]))
-                if col.size >= room:
-                    col = col[:room]
-                    capped = True
+            room = max(0, pmax - pre)
+            if col.size >= room:
+                col = col[:room]
+                capped = True
             if r.eos_token is not None:
                 hits = np.nonzero(col == r.eos_token)[0]
                 if hits.size:
@@ -1493,11 +1632,16 @@ class Scheduler:
                                n=int(col.size))
             self.generated_tokens += col.size
             if finished:
-                done.append(s)
-        for s in done:
-            with self._cond:
-                r = self._inflight.pop(s)
-            self.slots.retire(s)
+                done.append((s, r))
+        if junk:
+            self.slots.stats.add("junk_slot_blocks", junk)
+        for s, r in done:
+            # a stream that ended by count left the table when its last
+            # block was dispatched; one that ends by EOS leaves it here
+            if self._inflight.get(s) is r:
+                with self._cond:
+                    del self._inflight[s]
+                self.slots.retire(s)
             self.retired += 1
             self._stall_admissions = False   # pages/slots freed
             ttft = ((r.first_token_at - r.submitted_at)
@@ -1631,6 +1775,7 @@ class Scheduler:
         slots.reset()
         with self._cond:
             self._inflight.clear()
+            self._flight.clear()
         self._stall_admissions = False
         reqs = [r for r in reqs if not r.done.is_set()]
         # recovered adapter requests normally still hold their pool rows
@@ -1668,12 +1813,12 @@ class Scheduler:
             fault_point("serving.step",
                         requests=tuple(r.id
                                        for r in self._inflight.values()))
-            pre_lengths = slots.lengths.copy()
-            toks = slots.step()
+            flight = self._dispatch_block()
+            toks = self._read_oldest()
             if self._abandoned:
                 raise _Halt
             self._beat()
-            self._deliver_block(toks, pre_lengths)
+            self._deliver_block(toks, flight.streams)
             self._update_spec_gauges()
         self._obs["slot_occupancy"].set(slots.occupancy())
         self._update_paged_gauges()
@@ -1691,6 +1836,12 @@ class Scheduler:
             logger.error("recovery budget exhausted (%d > %d); halting",
                          self.recoveries, self.max_recoveries)
             self._give_up(error)
+        # a block in flight goes with the table: its tokens are dropped
+        # (never delivered, so never re-streamed) and the streams it alone
+        # still held are re-placed with the rest, as one recovery
+        held = {id(r) for r in affected}
+        affected = affected + [r for r in self._in_flight_only()
+                               if id(r) not in held]
         affected = [r for r in affected if not r.done.is_set()]
         logger.warning("recovering decode loop after %r: %d request(s) "
                        "to re-place (recovery %d/%d)", error,
@@ -1698,6 +1849,7 @@ class Scheduler:
         self._limbo = list(affected)
         with self._cond:
             self._inflight.clear()
+            self._flight.clear()
         healthy = []
         groups = [affected] if affected else []
         probes = 0
@@ -1740,9 +1892,10 @@ class Scheduler:
             self._accepting = False
             self.failed = error
             pool = list(self._waiting) + self._limbo \
-                + list(self._inflight.values())
+                + list(self._inflight.values()) + self._in_flight_only()
             self._waiting.clear()
             self._inflight.clear()
+            self._flight.clear()
             # decide the handoff atomically with the drain: the monitor
             # may see ``failed`` and call abandon() the moment the lock
             # drops — it will collect nothing (the pool is already

@@ -81,6 +81,22 @@ def select_tokens(logits, temps, key, top_k, top_p, sampler="sort"):
     return tok.astype(jnp.int32), key
 
 
+class DecodeBlock:
+    """One decode block between its dispatch and its readback:
+    ``toks`` what the step executable returned, still on the device;
+    ``dispatched`` what describes the block as it was dispatched (the
+    ``serve/step`` attributes known from the host's own table);
+    ``read`` what comes back with its tokens (``experts_hit``,
+    ``assignments_held``), filled by :meth:`SlotManager.read_step`."""
+
+    __slots__ = ("toks", "dispatched", "read")
+
+    def __init__(self, toks, dispatched):
+        self.toks = toks
+        self.dispatched = dispatched
+        self.read = {}
+
+
 class SlotManager:
     """Slot-table over one preallocated cache (see module docstring).
 
@@ -103,7 +119,7 @@ class SlotManager:
     single-device path, bit-identical to a build without the layout.
 
     Thread model: NOT thread-safe — exactly one thread (the scheduler
-    loop) may call ``admit``/``step``/``retire``.
+    loop) may call ``admit``/``dispatch_step``/``read_step``/``retire``.
     """
 
     # the scheduler branches on this: the paged manager
@@ -133,7 +149,8 @@ class SlotManager:
     experts = None
     # what the model's own ``step_counts`` / ``prefill_counts`` add to
     # them (host arithmetic on positions: a model whose step reads
-    # several row tables says how many rows of each); empty without
+    # several row tables says how many rows of each); empty without.
+    # ``step_attrs`` is the latest block's that :meth:`step` read back
     prefill_attrs = {}
     step_attrs = {}
     _stat_keys = ("prefill_traces", "step_traces")
@@ -183,6 +200,11 @@ class SlotManager:
         self.max_position = model.max_position
         self.stats = DecodeCounters(*self._stat_keys,
                                     obs_name=self._obs_name)
+        # the owner's counts of its dispatch-ahead (``Scheduler._serve``):
+        # blocks dispatched with another still in flight, and slot-blocks
+        # computed for a stream the host then found finished
+        self.stats["steps_ahead"] = 0
+        self.stats["junk_slot_blocks"] = 0
         # the leaves a stream fills row by row, as the model describes
         # them: which kernels apply and ``attn_blocks`` come from these
         self._tables = tuple(model.cache_tables())
@@ -636,20 +658,38 @@ class SlotManager:
             self.stats.add(name, n)
         return counts
 
-    def step(self):
-        """One block of ``steps_per_sync`` decode steps across every slot
-        in a single dispatch. Returns host tokens of shape
-        (steps_per_sync, max_slots); rows of inactive slots are junk the
-        caller must ignore. With ``spec_tokens`` > 1 the block is
-        (steps_per_sync * spec_tokens, max_slots) and ``last_counts``
-        holds each slot's committed count — callers read column ``s``
-        up to ``last_counts[s]``."""
+    @property
+    def runs_ahead(self):
+        """Whether the next block's inputs are known before the last
+        block's tokens are: the step draws its token on the device from
+        the logits table it carries, and the host passes ``lengths``,
+        ``active`` and ``temps``, which advance by arithmetic at
+        dispatch. Then the owner may dispatch block N+1 before it reads
+        block N back (``Scheduler._serve``). Not under speculation: the
+        commit counts and the last committed token come back with the
+        block."""
+        return self.spec_tokens == 1
+
+    def dispatch_step(self):
+        """Dispatch one block of ``steps_per_sync`` decode steps across
+        every slot and return it as a :class:`DecodeBlock`, unread: the
+        call returns when the executable is on the device's queue. The
+        host's ``lengths`` advance HERE, so the next block can be
+        dispatched before this one is read (:attr:`runs_ahead`; under
+        speculation they advance at the readback, by the counts it
+        brings). The arguments are copies: the owner may retire a slot
+        while the block is still queued."""
         attrs = {}
+        live = self.active.copy()
         if self._counted:
             # from the positions this block's steps write, before they move
-            pos = self.lengths[self.active][:, None] \
+            pos = self.lengths[live][:, None] \
                 + np.arange(self.steps_per_sync)
             attrs = self._summed(self.model.step_counts(pos.ravel()))
+        if self.experts is not None:
+            asked = int(live.sum()) * self.model.experts_per_token
+            self.stats.add("moe_assignments", self.steps_per_sync * asked)
+            attrs.update(experts=self.experts, assignments=asked)
         try:
             # argument hand-over and the call, until the executable's call
             # returns
@@ -657,7 +697,7 @@ class SlotManager:
                 extra = self._adapter_args(self.adapter_slots)
                 if self.spec_tokens > 1:
                     (self._cache, self._logits, self._key, self._table,
-                     toks, counts, tele) = self._step_fn(
+                     *toks) = self._step_fn(
                         self.params, self._cache, self._logits,
                         self.lengths, self.active, self.temps, self._key,
                         self._table, self._last_tok, *extra)
@@ -665,34 +705,52 @@ class SlotManager:
                     self._cache, self._logits, self._key, toks = \
                         self._step_fn(
                             self.params, self._cache, self._logits,
-                            self.lengths, self.active, self.temps,
+                            self.lengths.copy(), live, self.temps.copy(),
                             self._key, *extra)
+                    # the transfer queued behind the block: the readback
+                    # finds the tokens on the host, or on their way
+                    for leaf in jax.tree_util.tree_leaves(toks):
+                        leaf.copy_to_host_async()
         except BaseException:
             self.poisoned = True
             raise
         self.stats.dispatched()
+        if self.spec_tokens == 1:
+            self.lengths[live] = np.minimum(
+                self.lengths[live] + self.steps_per_sync, self.max_position)
+        return DecodeBlock(toks, attrs)
+
+    def read_step(self, block):
+        """The host's half of a block: ONE readback, the host blocked on
+        the device until the block's tokens are there. Returns host
+        tokens of shape (steps_per_sync, max_slots); rows of slots that
+        were inactive at the dispatch are junk the caller must ignore.
+        With ``spec_tokens`` > 1 the block is (steps_per_sync *
+        spec_tokens, max_slots) and ``last_counts`` holds each slot's
+        committed count — callers read column ``s`` up to
+        ``last_counts[s]``."""
         if self.spec_tokens > 1:
-            return self._finish_spec_block(toks, counts, tele)
-        # ONE readback per block: the host blocked on the device
+            return self._finish_spec_block(*block.toks)
         with obs.leaf_span("serve/step.readback", iter=self.iter):
-            toks = jax.device_get(toks)
+            toks = jax.device_get(block.toks)
         if self.experts is not None:
             toks, hits, *held = toks
-            asked = int(self.active.sum()) * self.model.experts_per_token
             self.stats.add("moe_experts_hit", float(hits.sum()))
-            self.stats.add("moe_assignments", self.steps_per_sync * asked)
-            attrs.update(experts=self.experts, assignments=asked,
-                         experts_hit=float(hits.mean()))
+            block.read["experts_hit"] = float(hits.mean())
             if held:
                 # a holder of a SHARE of the experts: how many of those
                 # assignments fell on the experts it holds
                 self.stats.setdefault("moe_assignments_held", 0.0)
                 self.stats.add("moe_assignments_held", float(held[0].sum()))
-                attrs["assignments_held"] = float(held[0].mean())
-        self.step_attrs = attrs
-        self.lengths[self.active] = np.minimum(
-            self.lengths[self.active] + self.steps_per_sync,
-            self.max_position)
+                block.read["assignments_held"] = float(held[0].mean())
+        return toks
+
+    def step(self):
+        """One block dispatched and read back at once (:meth:`read_step`
+        has the shapes); ``step_attrs`` then holds what describes it."""
+        block = self.dispatch_step()
+        toks = self.read_step(block)
+        self.step_attrs = {**block.dispatched, **block.read}
         return toks
 
     def _finish_spec_block(self, toks, counts, tele):

@@ -308,8 +308,9 @@ def test_serving_step_says_which_write_it_built():
                       timeout=120)
     finally:
         engine.shutdown()
+    # (the span that only drains the last block dispatches nothing)
     steps = [s for s in obs.default_tracer().spans()
-             if s.name == "serve/step"]
+             if s.name == "serve/step" and "live" in s.attrs]
     assert steps and all(s.attrs["kv_write"] == "scatter" for s in steps)
 
 
@@ -345,7 +346,8 @@ def _served(model, params, prompts, lengths, slots):
         engine.shutdown()
     steps = [(s.attrs["kv_write"], s.attrs["kv_write_slots"],
               s.attrs["live"])
-             for s in obs.default_tracer().spans() if s.name == "serve/step"]
+             for s in obs.default_tracer().spans()
+             if s.name == "serve/step" and "live" in s.attrs]
     return tokens, steps
 
 

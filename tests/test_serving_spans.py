@@ -30,6 +30,14 @@ PROMPTS = [[5, 9, 2, 17, 3], [1, 1, 4, 60, 8], [7, 3, 3],
            list(range(30, 47)), [4, 4, 4, 4, 4, 4, 4, 4]]
 
 
+def _dispatched(spans, loop=None):
+    """The attributes of the ``serve/step`` spans that dispatched a block
+    (``live`` and what else describes it); the span that only drains the
+    last block of a busy stretch carries none of them."""
+    return [s.attrs for s in spans if s.name == "serve/step"
+            and "live" in s.attrs and loop in (None, s.thread_id)]
+
+
 def _engine():
     model = GPTForCausalLM(vocab_size=61, hidden_size=32, n_layers=2,
                            n_heads=4, max_position=64)
@@ -87,13 +95,26 @@ def test_an_iteration_opens_at_most_twelve_spans(served):
     per_iter = collections.Counter(s.attrs["iter"] for s in spans
                                    if "iter" in s.attrs)
     assert per_iter and max(per_iter.values()) <= 12
-    # and a decoding iteration opens each of its phases once
-    for it in {s.attrs["iter"] for s in spans if s.name == "serve/step"}:
+    # and a decoding iteration opens each of its phases once: it
+    # dispatches a block where a stream is live and reads back and
+    # delivers the block that was in flight, which the first iteration of
+    # a busy stretch has none of and the last has alone
+    steps = [s for s in spans if s.name == "serve/step"]
+    kinds = collections.Counter()
+    for step in steps:
+        it = step.attrs["iter"]
         names = [s.name for s in spans if s.attrs.get("iter") == it]
         assert len(names) == len(set(names)), names
         assert {"serve/pick", "serve/sweep", "serve/step",
-                "serve/step.dispatch", "serve/step.readback",
-                "serve/deliver", "serve/after"} <= set(names)
+                "serve/after"} <= set(names)
+        dispatched = "live" in step.attrs
+        read = dispatched and step.attrs["ahead"] == 1 or not dispatched
+        assert ("serve/step.dispatch" in names) == dispatched
+        assert ("serve/step.readback" in names) == read
+        assert ("serve/deliver" in names) == read
+        kinds[dispatched, read] += 1
+    assert kinds[True, False] >= 1 and kinds[False, True] >= 1
+    assert kinds[True, True] > kinds[True, False] + kinds[False, True]
 
 
 def test_each_request_waits_once_and_meets_its_first_token(served):
@@ -145,11 +166,11 @@ def test_a_step_says_how_its_attention_read_and_how_much(served):
     """Off the chip the read is masked, which is the whole table: four
     slots of one block of 128 positions each (64 positions, rounded up)."""
     _, spans = served
-    steps = [s for s in spans if s.name == "serve/step"]
+    steps = _dispatched(spans)
     assert steps
-    for s in steps:
-        assert s.attrs["attn_read"] == "masked"
-        assert s.attrs["attn_blocks"] == s.attrs["attn_blocks_table"] == 4
+    for a in steps:
+        assert a["attn_read"] == "masked"
+        assert a["attn_blocks"] == a["attn_blocks_table"] == 4
 
 
 def test_a_step_says_how_it_samples_and_how_many_streams_it_draws_for(
@@ -161,9 +182,9 @@ def test_a_step_says_how_it_samples_and_how_many_streams_it_draws_for(
     temperature, falls as they retire, and the admissions count theirs."""
     from bigdl_tpu.ops import sampling
     _, spans = served
-    steps = [s for s in spans if s.name == "serve/step"]
-    assert {s.attrs["sampler"] for s in steps} == {"sort"}
-    assert {s.attrs["sampled"] for s in steps} == {0}
+    steps = _dispatched(spans)
+    assert {a["sampler"] for a in steps} == {"sort"}
+    assert {a["sampled"] for a in steps} == {0}
     assert {s.attrs["sampled"] for s in spans
             if s.name == "serve/prefill"} == {0}
     monkeypatch.setattr(sampling, "applies", lambda *a: True)
@@ -182,7 +203,7 @@ def test_a_step_says_how_it_samples_and_how_many_streams_it_draws_for(
         for h in handles:
             h.result(timeout=300)
     spans = [s for s in tracer.spans() if s.thread_id == loop]
-    steps = [s.attrs for s in spans if s.name == "serve/step"]
+    steps = _dispatched(spans)
     assert {a["sampler"] for a in steps} == {"kernel"}
     assert all(0 <= a["sampled"] <= min(2, a["live"]) for a in steps)
     assert {a["sampled"] for a in steps} >= {0, 1}
@@ -213,15 +234,13 @@ def test_attn_blocks_follow_admissions_and_retirements(monkeypatch):
         assert engine.slots.attn_read == "kernel"
         long = np.arange(126, dtype=np.int32) % 61
         engine.submit(long, 5).result(timeout=300)
-        alone = [s.attrs for s in tracer.spans()
-                 if s.thread_id == loop and s.name == "serve/step"]
+        alone = _dispatched(tracer.spans(), loop)
         tracer.clear()
         handles = [engine.submit(PROMPTS[i], n)
                    for i, n in enumerate((2, 4, 6))]
         for h in handles:
             h.result(timeout=300)
-    three = [s.attrs for s in tracer.spans()
-             if s.thread_id == loop and s.name == "serve/step"]
+    three = _dispatched(tracer.spans(), loop)
     for a in alone + three:
         assert a["attn_read"] == "kernel"
         assert a["attn_blocks_table"] == 3 * 2
