@@ -261,15 +261,16 @@ class HistogramChild:
         self._count = 0
         self._exemplars = {}          # bucket idx -> (value, trace, wall)
 
-    def observe(self, v, exemplar=None):
+    def observe(self, v, exemplar=None, n=1):
+        """Count ``v`` ``n`` times in one call."""
         if not _enabled:
             return
         v = float(v)
         i = bisect.bisect_left(self.bounds, v)
         with self._lock:
-            self._counts[i] += 1
-            self._sum += v
-            self._count += 1
+            self._counts[i] += n
+            self._sum += v * n
+            self._count += n
             if exemplar is not None:
                 old = self._exemplars.get(i)
                 now = time.time()
@@ -365,8 +366,8 @@ class Histogram(_Family):
     def _make_child(self):
         return HistogramChild(self.bounds)
 
-    def observe(self, v, exemplar=None):
-        self._solo().observe(v, exemplar=exemplar)
+    def observe(self, v, exemplar=None, n=1):
+        self._solo().observe(v, exemplar=exemplar, n=n)
 
     def quantile(self, q):
         return self._solo().quantile(q)

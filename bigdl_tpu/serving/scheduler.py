@@ -58,6 +58,11 @@ logger = logging.getLogger("bigdl_tpu.serving")
 # models prefill in well under a millisecond on a warm executable.
 TTFT_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0)
+# the gap between two deliveries to one stream: a decode block (2.5 ms
+# for a small model on the chip) up to a stall of seconds
+TOKEN_GAP_BUCKETS = (0.0005, 0.001, 0.002, 0.003, 0.005, 0.0075, 0.01,
+                     0.015, 0.02, 0.03, 0.05, 0.075, 0.1, 0.15, 0.25, 0.5,
+                     1.0, 2.5, 5.0, 10.0)
 
 
 class QueueFullError(RuntimeError):
@@ -98,14 +103,22 @@ class _Flight:
     ends by count with this block leaves ``Scheduler._inflight`` at the
     dispatch and is held here alone until its tokens are delivered;
     ``takes`` is what each request gets of the block by count (the next
-    dispatch reckons with it before the tokens are read)."""
+    dispatch reckons with it before the tokens are read). ``prefills``
+    and ``prefill_positions`` count the prefill executables launched
+    since the block before this one was dispatched, and the positions
+    they computed: what the device has queued BEFORE this block, and so
+    what lengthens the token gap that this block's delivery ends."""
 
-    __slots__ = ("block", "streams", "takes")
+    __slots__ = ("block", "streams", "takes", "prefills",
+                 "prefill_positions")
 
-    def __init__(self, block, streams, takes):
+    def __init__(self, block, streams, takes, prefills=0,
+                 prefill_positions=0):
         self.block = block
         self.streams = streams
         self.takes = takes
+        self.prefills = prefills
+        self.prefill_positions = prefill_positions
 
 
 class Request:
@@ -174,6 +187,9 @@ class Request:
         self.prefill_bucket = None
         self.first_token_at = None
         self.finished_at = None
+        # the stamp of the delivery that last gave this request a token
+        # (``Scheduler._deliver_block``); None again at every admission
+        self.delivered_at = None
         # True when the slot table ran out of positions before
         # max_new_tokens: the request finished successfully but short
         # (force-retire instead of clamped-position junk)
@@ -300,6 +316,15 @@ class Scheduler:
         # one between iterations (two inside ``_step``), none for a
         # manager that cannot run ahead
         self._flight = collections.deque()
+        # the token gap (docs/observability.md): the stamp of the last
+        # delivery, and the prefill executables launched, with the
+        # positions they computed, since the last decode block was
+        # dispatched (``_dispatch_block`` moves them onto its ``_Flight``)
+        self._delivered_at = None
+        self._prefills = 0
+        self._prefill_positions = 0
+        for k in ("token_gaps", "token_gaps_after_prefill"):
+            slots.stats.setdefault(k, 0)
         # requests the loop holds OUTSIDE _waiting/_inflight (a popped
         # admission batch, a recovery set): abandon()/_give_up() must see
         # them or a mid-admission crash would strand them
@@ -368,6 +393,11 @@ class Scheduler:
                 "bigdl_serving_ttft_seconds",
                 "submit-to-first-token latency", lbl,
                 buckets=TTFT_BUCKETS).labels(e),
+            "token_gap": reg.histogram(
+                "bigdl_serving_token_gap_seconds",
+                "gap between two consecutive deliveries of tokens to one "
+                "stream (the inter-token latency at the loop)", lbl,
+                buckets=TOKEN_GAP_BUCKETS).labels(e),
             "failures": reg.counter(
                 "bigdl_serving_failures_total",
                 "decode-loop step/admit exceptions caught", lbl).labels(e),
@@ -972,7 +1002,11 @@ class Scheduler:
         re-prefilled. ``delivered`` > 0 marks a re-placement (recovery,
         preemption resume, migration), not a first admission.
         ``queue_wait_s`` is the ``serve/queue_wait`` span's length, from
-        the same clock read (``admitted_at``)."""
+        the same clock read (``admitted_at``). Every admission passes
+        here, so here the request forgets the delivery that last gave it
+        a token: a stream placed again (a preemption, a recovery) has no
+        token gap across that."""
+        r.delivered_at = None
         reqtrace.event(
             r.trace, "admit", request=r.id, engine=self.obs_label,
             delivered=len(r.tokens),
@@ -1102,6 +1136,8 @@ class Scheduler:
                     with obs.span("serve/prefill_chunk",
                                   pending=slots.pending_prefills()):
                         slots.prefill_tick()
+                    self._prefill_launched(slots.window,
+                                           slots.prefill_chunk)
                 except _Halt:
                     raise
                 except BaseException as e:
@@ -1152,9 +1188,8 @@ class Scheduler:
             self._obs["step_seconds"].inc(dt)
             if flight is not None:
                 with obs.leaf_span("serve/deliver", iter=it) as deliver:
-                    tokens, retired = self._deliver_block(toks,
-                                                          flight.streams)
-                    deliver.set(tokens=tokens, retired=retired)
+                    deliver.set(**self._deliver_block(toks, flight,
+                                                      at=deliver.start))
             with obs.leaf_span("serve/after", iter=it):
                 self._maybe_snapshot()
                 self._update_spec_gauges()
@@ -1240,8 +1275,8 @@ class Scheduler:
         (a page snapshot, a preemption) sees the table as of the tokens
         the callers have. The next iteration starts a stretch again."""
         while self._flight:
-            streams = self._flight[0].streams
-            self._deliver_block(self._read_oldest(), streams)
+            flight = self._flight[0]
+            self._deliver_block(self._read_oldest(), flight)
 
     def _dispatch_block(self, old=None):
         """Dispatch one decode block and record whom it decodes for.
@@ -1250,7 +1285,10 @@ class Scheduler:
         on what the host has, less what ``old``, the block still in
         flight, will deliver) leaves the table HERE, so the block after
         its last token computes nothing for it and its slot takes the
-        next admission; its tokens reach it at the delivery."""
+        next admission; its tokens reach it at the delivery. The block
+        takes the count of the prefills launched since the block before
+        it with it: on the device they run before it, whichever
+        iteration of the loop delivers it."""
         slots = self.slots
         ahead = slots.runs_ahead
         n_steps = slots.steps_per_sync
@@ -1267,7 +1305,9 @@ class Scheduler:
                 takes[r.id] = take = min(n_steps, left, room)
                 if take >= left or take >= room:
                     ending.append(s)
-        flight = _Flight(slots.dispatch_step(), streams, takes)
+        flight = _Flight(slots.dispatch_step(), streams, takes,
+                         self._prefills, self._prefill_positions)
+        self._prefills = self._prefill_positions = 0
         with self._cond:
             self._flight.append(flight)
             for s in ending:
@@ -1279,6 +1319,13 @@ class Scheduler:
         return flight
 
     # ------------------------------------------------------- admission ----
+    def _prefill_launched(self, rows, bucket):
+        """A prefill executable was launched over ``rows`` x ``bucket``
+        positions: it stands on the device before the next decode block
+        to be dispatched (``_dispatch_block`` takes the counts)."""
+        self._prefills += 1
+        self._prefill_positions += rows * bucket
+
     def _admit(self, batch):
         """One batched prefill dispatch; on failure, fall back to
         one-at-a-time admission so only the poisoned request fails."""
@@ -1299,6 +1346,7 @@ class Scheduler:
                 # what the dispatch was padded to, against what was asked
                 # for: rows x bucket positions computed for ``tokens``
                 rows, bucket = slots.last_prefill_shape
+                self._prefill_launched(rows, bucket)
                 prefill.set(rows=rows, bucket=bucket,
                             tokens=sum(r.prompt.size + len(r.tokens)
                                        for r in batch),
@@ -1335,6 +1383,7 @@ class Scheduler:
                         return
                     self._quarantine(r, e2)
                 else:
+                    self._prefill_launched(*slots.last_prefill_shape)
                     with self._cond:
                         self._inflight[s] = r
                     self.admitted += 1
@@ -1568,27 +1617,46 @@ class Scheduler:
                 sl.spec_accepted / sl.spec_proposed)
 
     # -------------------------------------------------------- delivery ----
-    def _deliver_block(self, toks, streams):
+    def _deliver_block(self, toks, flight, at=None):
         """Fan one step block's token columns out to the requests it was
-        dispatched for (``streams`` of its ``_Flight``: slot, request,
-        the slot's length BEFORE the dispatch), retiring EOS/max-token
+        dispatched for (``flight.streams``: slot, request, the slot's
+        length BEFORE the dispatch), retiring EOS/max-token
         completions. The length bounds each column to the positions the
         slot table can actually hold: a request whose ``prompt_len +
         generated`` reaches ``max_position`` is force-retired
         (``Request.truncated``) instead of being fed clamped-position
         junk. A request that finished while the block was in flight (an
         EOS in the block before, a cancel, a deadline) gets nothing of
-        it: its column is junk, counted in ``junk_slot_blocks``. Returns
-        ``(tokens delivered, requests retired)``."""
+        it: its column is junk, counted in ``junk_slot_blocks``.
+
+        The token gap is measured here (docs/observability.md). ``at``
+        stamps the delivery: its ``serve/deliver`` leaf's start, or one
+        clock read where there is no leaf. A request keeps the stamp of
+        the delivery that last gave it a token, so ``gap_streams``, the
+        requests that get a token now AND got one in the delivery before
+        (a first token has no gap, nor has a stream placed again in
+        between), all waited the same ``gap_ms``: this stamp less the
+        last. With ``steps_per_sync`` k a delivery hands a stream k
+        tokens and the gap stays the delivery's. ``prefills`` and
+        ``prefill_positions`` are the block's own (``_Flight``). Returns
+        the ``serve/deliver`` span's attributes: ``tokens`` delivered,
+        requests ``retired``, and the four above (``gap_ms`` and
+        ``gap_streams`` only where a stream waited); with telemetry off,
+        the first two alone."""
         done = []
         junk = 0
         tokens_before = self.generated_tokens
+        timed = obs.enabled()
+        if timed and at is None:
+            at = time.perf_counter() - obs.default_tracer().epoch_perf
+        last = self._delivered_at
+        gap_streams = 0
         # speculative managers commit a VARIABLE count per slot each
         # block (1..block_span); last_counts bounds each column to the
         # tokens actually committed
         counts = getattr(self.slots, "last_counts", None)
         pmax = int(self.slots.max_position)
-        for s, r, pre in streams:
+        for s, r, pre in flight.streams:
             if r.done.is_set():
                 junk += 1
                 continue
@@ -1623,6 +1691,10 @@ class Scheduler:
                     bucket=r.prefill_bucket)
             self._journal_delivered(r, col.size)
             if col.size:
+                if timed:
+                    if last is not None and r.delivered_at == last:
+                        gap_streams += 1
+                    r.delivered_at = at
                 # stream offsets, not counts: the failover-continuity
                 # test asserts a migrated stream's offsets tile
                 # 0..total exactly once across BOTH replicas' events
@@ -1664,7 +1736,20 @@ class Scheduler:
                 self.generated_tokens / self.step_seconds)
         if done:
             self._obs["slot_occupancy"].set(self.slots.occupancy())
-        return int(delivered), len(done)
+        attrs = {"tokens": int(delivered), "retired": len(done)}
+        self._delivered_at = at if timed else None
+        if timed:
+            attrs.update(prefills=flight.prefills,
+                         prefill_positions=flight.prefill_positions)
+            if gap_streams:
+                gap_s = at - last
+                attrs.update(gap_ms=1e3 * gap_s, gap_streams=gap_streams)
+                self._obs["token_gap"].observe(gap_s, n=gap_streams)
+                stats = self.slots.stats
+                stats.add("token_gaps", gap_streams)
+                if flight.prefills:
+                    stats.add("token_gaps_after_prefill", gap_streams)
+        return attrs
 
     # -------------------------------------------- cancel/deadline sweeps --
     def _swept(self, r, err):
@@ -1776,6 +1861,9 @@ class Scheduler:
         with self._cond:
             self._inflight.clear()
             self._flight.clear()
+        # no stream's token gap spans a re-placement (``_trace_admitted``),
+        # so the prefills before and during it are no delivery's
+        self._prefills = self._prefill_positions = 0
         self._stall_admissions = False
         reqs = [r for r in reqs if not r.done.is_set()]
         # recovered adapter requests normally still hold their pool rows
@@ -1818,7 +1906,7 @@ class Scheduler:
             if self._abandoned:
                 raise _Halt
             self._beat()
-            self._deliver_block(toks, flight.streams)
+            self._deliver_block(toks, flight)
             self._update_spec_gauges()
         self._obs["slot_occupancy"].set(slots.occupancy())
         self._update_paged_gauges()

@@ -6,18 +6,26 @@ request gets exactly one ``serve/queue_wait`` and one ``serve/first_token``
 that meet at its admission, a ``serve/prefill`` counts no more prompt
 tokens than positions it computed, an iteration opens at most 12 spans,
 and under a real profiler session the leaves (and no parent) lie on the
-``/host:CPU`` plane.
+``/host:CPU`` plane. And the token gap: every ``serve/deliver`` says how
+long the streams it serves waited since the delivery before
+(``gap_ms``, ``gap_streams``) and how many prefills the device had queued
+before its block (``prefills``, ``prefill_positions``), the same numbers
+reach ``bigdl_serving_token_gap_seconds`` and ``engine.stats``, and the
+kill switch stops all three.
 """
 
 import collections
 import glob
 import os
+import time
 
 import jax
 import pytest
 
 from bigdl_tpu import obs
 from bigdl_tpu.models.gpt import GPTForCausalLM
+from bigdl_tpu.obs.metrics import HistogramChild
+from bigdl_tpu.resilience import faults
 from bigdl_tpu.serving import ServingEngine
 
 PARENTS = {"serve/step", "serve/prefill"}
@@ -299,3 +307,218 @@ def test_the_leaves_reach_the_profilers_host_plane(tmp_path):
                if any(e.name == "serve/step.dispatch" for e in l.events)]
     event = next(e for e in line.events if e.name == "serve/step.dispatch")
     assert dict(event.stats)["iter"] >= 1
+
+
+# the token gap -------------------------------------------------------------
+def _delivers(spans):
+    return [s for s in spans if s.name == "serve/deliver"]
+
+
+def _gap_histogram(engine):
+    return engine.scheduler._obs["token_gap"]
+
+
+def test_a_delivery_says_how_long_its_streams_waited(served):
+    """Every ``serve/deliver`` carries its block's ``prefills`` and
+    ``prefill_positions``; every one that serves a stream the delivery
+    before also served carries ``gap_ms``, the distance between the two
+    deliveries' starts, and ``gap_streams``; the one without (the first of
+    a busy stretch) hands out first tokens only."""
+    _, spans = served
+    delivers = _delivers(spans)
+    assert len(delivers) >= 8
+    assert "gap_ms" not in delivers[0].attrs
+    waited = 0
+    for before, d in zip([None] + delivers, delivers):
+        a = d.attrs
+        assert a["prefills"] >= 0
+        assert a["prefill_positions"] >= 16 * a["prefills"]
+        assert ("gap_ms" in a) == ("gap_streams" in a)
+        if "gap_ms" in a:
+            waited += 1
+            assert 1 <= a["gap_streams"] <= a["tokens"]
+            assert a["gap_ms"] == pytest.approx(
+                1e3 * (d.start - before.start), abs=1e-6)
+        else:
+            firsts = [s for s in spans if s.name == "serve/first_token"
+                      and d.start <= s.end <= d.end]
+            assert len(firsts) == a["tokens"]
+    assert waited >= len(delivers) - 2
+    # the prefills all stood before some block that was delivered
+    assert sum(d.attrs["prefills"] for d in delivers) == len(
+        [s for s in spans if s.name == "serve/prefill"])
+    assert sum(d.attrs["prefill_positions"] for d in delivers) == sum(
+        s.attrs["rows"] * s.attrs["bucket"] for s in spans
+        if s.name == "serve/prefill")
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_a_prefill_lengthens_the_gap_of_the_block_dispatched_after_it(paged):
+    """A request admitted while another decodes. The dense loop has block
+    N in flight when it launches the prefill, dispatches block N+1 behind
+    it and delivers block N in that same iteration: ``prefills`` 0 there,
+    and 1 on the next iteration's delivery, which ends the gap the
+    prefill lengthened (counted by the host's order, between two
+    deliveries, it would be the other way round). The paged loop reads
+    each block back at once: the prefill stands before its own
+    iteration's block."""
+    model = GPTForCausalLM(vocab_size=61, hidden_size=32, n_layers=2,
+                           n_heads=4, max_position=64)
+    params, _ = model.setup(jax.random.PRNGKey(3), None)
+    kw = dict(paged=True, page_size=8, prefill_chunk=8) if paged else {}
+    tracer = obs.default_tracer()
+    faults.configure(None)
+    try:
+        with ServingEngine(model, params, max_slots=4, **kw) as engine:
+            assert engine.slots.runs_ahead == (not paged)
+            loop = engine.scheduler._thread.ident
+            engine.submit(PROMPTS[2], 2).result(timeout=300)   # compiled
+            tracer.clear()
+            faults.configure("serving.step:delay=0.02")
+            first = engine.submit(PROMPTS[0], 40)
+            deadline = time.monotonic() + 120
+            while len(first.tokens) < 3 and time.monotonic() < deadline:
+                time.sleep(0.002)
+            late = engine.submit(PROMPTS[4], 4)
+            late.result(timeout=300)
+            faults.configure(None)
+            first.result(timeout=300)
+    finally:
+        faults.configure(None)
+    spans = [s for s in tracer.spans() if s.thread_id == loop]
+    (pick,) = [s for s in spans if s.name == "serve/pick"
+               and s.attrs["n"] == 1 and s.attrs["iter"] > 1
+               and s.start > min(d.start for d in _delivers(spans))]
+    it = pick.attrs["iter"]
+    by_iter = {d.attrs["iter"]: d.attrs for d in _delivers(spans)}
+    own, after = by_iter[it], by_iter[it + 1]
+    positions = 4 * 8 if paged else 4 * 16      # rows x chunk or bucket
+    if paged:
+        assert (own["prefills"], own["prefill_positions"]) == (1, positions)
+        assert after["prefills"] == 0
+        assert own["tokens"] == 2               # the late one's first
+    else:
+        (step,) = [s for s in spans if s.name == "serve/step"
+                   and s.attrs["iter"] == it]
+        assert step.attrs["ahead"] == 1
+        assert own["prefills"] == own["prefill_positions"] == 0
+        assert (after["prefills"], after["prefill_positions"]) == (
+            1, positions)
+        assert own["tokens"] == 1 and after["tokens"] == 2
+    # the first stream waited through both; the late one's first token
+    # has no gap
+    assert own["gap_streams"] == 1
+    assert after["gap_streams"] == (2 if paged else 1)
+    assert sum(d["prefills"] for d in by_iter.values()) == 2
+
+
+def test_a_first_token_and_a_stream_placed_again_have_no_gap():
+    """One request alone: every token after its first is one gap. Then a
+    step fails under two streams: the recovery places both again from
+    their contexts and delivers a block itself, which is no gap of
+    theirs (and the block that was in flight is dropped), so each stream
+    placed again with tokens delivered counts one gap less."""
+    tracer = obs.default_tracer()
+    recorder = obs.default_recorder()
+    faults.configure(None)
+    try:
+        with _engine() as engine:
+            alone = engine.submit(PROMPTS[0], 7)
+            alone.result(timeout=300)
+            assert engine.stats["token_gaps"] == 6
+            assert _gap_histogram(engine).count == 6
+            tracer.clear()
+            faults.configure("serving.step:error:after=4:times=1")
+            with engine.scheduler._cond:
+                pair = [engine.submit(PROMPTS[1], 9),
+                        engine.submit(PROMPTS[3], 12)]
+            for h in pair:
+                h.result(timeout=300)
+            assert engine.scheduler.recoveries == 1
+            placed_again = sum(
+                1 for h in pair
+                for e in recorder.timeline(h.trace)["events"]
+                if e["event"] == "admit" and e["delivered"] > 0)
+            assert placed_again == 2
+            gaps = sum(len(h.tokens) - 1 for h in pair) - placed_again
+            assert engine.stats["token_gaps"] == 6 + gaps
+            assert _gap_histogram(engine).count == 6 + gaps
+            loop = engine.scheduler._thread.ident
+    finally:
+        faults.configure(None)
+    # the recovery's own delivery has no leaf and counts no gap: the
+    # spans and the sums agree
+    delivers = _delivers(s for s in tracer.spans() if s.thread_id == loop)
+    assert sum(d.attrs.get("gap_streams", 0) for d in delivers) == gaps
+    assert all(h.delivered_at is not None for h in pair)
+
+
+def test_the_three_sinks_count_the_same_gaps():
+    """The histogram's count, ``engine.stats["token_gaps"]`` and the sum
+    of ``gap_streams`` over the spans are all the tokens delivered less
+    the first tokens; the histogram's sum is the gaps' weighted sum; the
+    gaps after a prefill are among them."""
+    tracer = obs.default_tracer()
+    tracer.clear()
+    with _engine() as engine:
+        loop = engine.scheduler._thread.ident
+        handles = [engine.submit(p, 2 + i % 6)
+                   for i, p in enumerate(PROMPTS)]
+        for h in handles:
+            h.result(timeout=300)
+        stats = dict(engine.stats)
+        hist = _gap_histogram(engine)
+        count, total = hist.count, hist.sum
+        scrape = obs.default_registry().prometheus_text()
+    delivers = _delivers(s for s in tracer.spans() if s.thread_id == loop)
+    gaps = sum(len(h.tokens) - 1 for h in handles)
+    assert gaps == sum(d.attrs.get("gap_streams", 0) for d in delivers)
+    assert stats["token_gaps"] == count == gaps
+    assert total == pytest.approx(sum(
+        1e-3 * d.attrs["gap_ms"] * d.attrs["gap_streams"]
+        for d in delivers if "gap_ms" in d.attrs))
+    after = sum(d.attrs["gap_streams"] for d in delivers
+                if d.attrs["prefills"] and "gap_ms" in d.attrs)
+    assert 0 < stats["token_gaps_after_prefill"] == after < gaps
+    label = engine.scheduler.obs_label
+    assert (f'bigdl_serving_token_gap_seconds_count{{engine="{label}"}} '
+            f'{gaps}') in scrape
+
+
+def test_one_observation_counts_for_n():
+    bounds = (0.001, 0.01, 0.1)
+    once, thrice = HistogramChild(bounds), HistogramChild(bounds)
+    once.observe(0.004, n=3)
+    once.observe(0.5)
+    for _ in range(3):
+        thrice.observe(0.004)
+    thrice.observe(0.5, n=1)
+    assert once.snapshot() == thrice.snapshot()
+    assert once.count == 4 and once.sum == pytest.approx(0.512)
+    assert once.quantile(0.5) == thrice.quantile(0.5)
+
+
+def test_the_kill_switch_stops_the_gap_and_serves_the_same_tokens():
+    jobs = list(enumerate(PROMPTS[:6]))
+    with _engine() as engine:
+        on = [engine.submit(p, 3 + i % 4) for i, p in jobs]
+        tokens_on = [list(h.result(timeout=300)) for h in on]
+        assert engine.stats["token_gaps"] > 0
+    tracer = obs.default_tracer()
+    tracer.clear()
+    was = obs.set_enabled(False)
+    try:
+        with _engine() as engine:
+            off = [engine.submit(p, 3 + i % 4) for i, p in jobs]
+            tokens_off = [list(h.result(timeout=300)) for h in off]
+            assert engine.stats["token_gaps"] == 0
+            assert engine.stats["token_gaps_after_prefill"] == 0
+            assert _gap_histogram(engine).count == 0
+            assert engine.scheduler._delivered_at is None
+            assert engine.scheduler.generated_tokens == sum(
+                3 + i % 4 for i, _ in jobs)
+    finally:
+        obs.set_enabled(was)
+    assert tokens_off == tokens_on
+    assert all(h.delivered_at is None for h in off)
+    assert not [s for s in tracer.spans() if s.name == "serve/deliver"]
