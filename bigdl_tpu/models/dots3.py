@@ -211,10 +211,16 @@ class Dots3ForCausalLM(Module):
                        for i, kind in enumerate(layer_types)]
         self.out_norm = nn.RMSNorm(hidden_size, rms_norm_eps)
         # what the slot table stamps on its spans: the assignments a
-        # token makes in a routed layer, and the product they run as
+        # token makes in a routed layer
         routed = any(l.routed for l in self.layers)
         self.experts_per_token = num_experts_per_tok if routed else 0
-        self.expert_product = nn.RoutedExperts.product if routed else None
+
+    def expert_rows(self, width, length):
+        """The assignments a routed layer's call takes in a pass over
+        ``width`` rows of ``length`` positions: a prompt goes through a
+        layer a block of ``prefill_block`` positions at a time."""
+        return width * min(self.prefill_block, length) \
+            * self.experts_per_token
 
     def setup(self, rng, input_spec):
         ks = jax.random.split(rng, len(self.layers) + 2)

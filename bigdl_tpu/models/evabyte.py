@@ -312,7 +312,6 @@ class EvaByteForCausalLM(Module):
     serving_features = frozenset()
     logits_dtype = jnp.float32
     experts_per_token = 0
-    expert_product = None
 
     def __init__(self, vocab_size=320, hidden_size=4096,
                  intermediate_size=11008, num_hidden_layers=32,

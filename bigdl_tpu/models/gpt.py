@@ -454,7 +454,6 @@ class GPTForCausalLM(Module):
                                   "kv_snapshot"))
     logits_dtype = None
     experts_per_token = 0
-    expert_product = None
     logits = _lm_logits
 
     @property

@@ -15,7 +15,8 @@ and the combine einsum scatters outputs back weighted by the gate.
 :class:`RoutedExperts`, further down, is the layer that serves: it drops
 no token (``MoE`` does, beyond ``capacity``), scores with a sigmoid and
 a selection bias, and computes its experts as one grouped product over
-the assignments sorted by expert.
+the assignments sorted by expert, the product chosen by their number
+(:func:`grouped_product`).
 """
 
 from __future__ import annotations
@@ -25,6 +26,27 @@ import jax.numpy as jnp
 from jax import lax
 
 from bigdl_tpu.nn.module import Module
+from bigdl_tpu.ops.grouped_matmul import grouped_matmul
+
+# the assignments a routed layer's call takes from which its grouped
+# product is the Pallas grouped matmul and not ``ragged_dot``: every
+# prompt pass's, 512 and more, and not a decode step's, 288 (dots3) and
+# 384 (LFM2). Measured (my chip sweeps, PERF.md section 6, PR 38), the
+# grouped matmul takes 0.53-0.79 ms a call at LFM2's 512-8192 prompt rows
+# where ``ragged_dot`` takes 1.16-1.51, and it also leads at the steps'
+# rows (0.63-0.64 against 0.82-0.84 ms at 384, 0.48-0.61 against
+# 0.51-0.66 at 288): there is no crossover above 288. The steps stay on
+# ``ragged_dot`` because both ``moe_expert_roofline`` metrics read its
+# operations in a step (PERF.md section 7.2)
+GROUPED_MATMUL_ROWS = 512
+
+
+def grouped_product(rows):
+    """The grouped product a routed layer runs ``rows`` assignments as:
+    ``"gmm"`` (``ops/grouped_matmul.py``) from :data:`GROUPED_MATMUL_ROWS`
+    on, else ``"ragged_dot"``. The layer and the slot table's spans both
+    ask this, so they cannot disagree."""
+    return "gmm" if rows >= GROUPED_MATMUL_ROWS else "ragged_dot"
 
 
 def _topk_dispatch(probs, k, capacity):
@@ -172,18 +194,20 @@ class RoutedExperts(Module):
     The layer is told which experts it HOLDS: ``count`` of them from
     ``first`` on (default: all). It routes over all ``num_experts``,
     keeps the assignments to its own, sorts them by expert and computes
-    them as one grouped product a matrix (``jax.lax.ragged_dot``, which
-    the TPU compiler turns into its own grouped-matmul kernel: the
-    operations named ``ragged-dot`` in a device trace). What the absent
-    experts would have added is left out: the shares of every holder add
-    up to the whole layer. No assignment is dropped, whatever the
-    routing: the product's groups are as long as the routing makes them.
+    them as one grouped product a matrix. Which product is a function of
+    the rows alone, seen when the layer is traced
+    (:func:`grouped_product`): under :data:`GROUPED_MATMUL_ROWS`
+    assignments, a decode step's, ``jax.lax.ragged_dot`` (the TPU
+    compiler's own grouped matmul, ``ragged-dot`` in a device trace);
+    from there on, a prompt pass's, the Pallas grouped matmul of
+    ``ops/grouped_matmul.py``. What the absent experts would have added
+    is left out: the shares of every holder add up to the whole layer.
+    No assignment is dropped, whatever the routing: the product's groups
+    are as long as the routing makes them.
 
     Scores, choice and weights are float32 with true-float32 products;
     the expert products take the weights' dtype and sum in float32.
     """
-
-    product = "ragged_dot"
 
     def __init__(self, hidden_size, ffn_size, num_experts, k, first=0,
                  count=None, use_bias=True, norm_topk_prob=True,
@@ -265,6 +289,8 @@ class RoutedExperts(Module):
         xs = jnp.take(x.astype(dt), order // k, axis=0)       # (N * k, d)
 
         def grouped(a, b):
+            if grouped_product(n * k) == "gmm":
+                return grouped_matmul(a.astype(dt), b, sizes)
             return lax.ragged_dot(a.astype(dt), b, sizes,
                                   preferred_element_type=jnp.float32)
 
@@ -289,8 +315,6 @@ class SharedAndRoutedExperts(Module):
     computes the shared part alike, so it counts ONCE when the holders'
     shares are added up. Arguments after ``n_shared`` are
     :class:`RoutedExperts`'s."""
-
-    product = RoutedExperts.product
 
     def __init__(self, hidden_size, ffn_size, num_experts, k, n_shared=1,
                  **routed_kw):

@@ -72,10 +72,14 @@ docs/serving.md has the contract in prose):
     which of :data:`FEATURES` the model carries. The engine's
     constructor raises a ``TypeError`` naming any other that is asked
     for: there is no fallback.
-``experts_per_token``, ``expert_product``
-    0 / None for a model without routed experts; else the assignments a
-    token makes in each routed layer, and the name of the grouped
-    product they run as (stamped on ``serve/step``).
+``experts_per_token``, ``expert_rows(width, length)``
+    0 for a model without routed experts (which need not have
+    ``expert_rows``); else the assignments a token makes in each routed
+    layer, and how many of them one routed layer's call takes in a pass
+    over ``width`` rows of ``length`` positions (a decode step: length
+    1). The table asks ``nn.moe.grouped_product`` of that number, as
+    the layer does of its own, and stamps the product's name on
+    ``serve/step`` and ``serve/prefill``.
 ``step_counts(pos)``, ``prefill_counts(prompt_len)``
     optional, both or neither: host arithmetic (numpy in, a dict of
     ints out, the same keys whatever the input) on the positions the

@@ -48,6 +48,7 @@ from jax import lax
 
 from bigdl_tpu import obs
 from bigdl_tpu.models.gpt import prompt_bucket, sample_logits
+from bigdl_tpu.nn.moe import grouped_product
 from bigdl_tpu.ops import decode_attention, sampling
 from bigdl_tpu.ops.kv_write import in_place_applies
 from bigdl_tpu.resilience.faults import fault_point
@@ -144,8 +145,9 @@ class SlotManager:
     # the table (every step of every manager follows it)
     sampler = "sort"
     # what a model with routed experts adds to the latest
-    # ``serve/prefill`` and ``serve/step`` span (docs/observability.md);
-    # empty for a model without
+    # ``serve/prefill`` and ``serve/step`` span (docs/observability.md):
+    # ``experts`` is the grouped product of the step's (a prefill's is
+    # its own, by its shape); None for a model without
     experts = None
     # what the model's own ``step_counts`` / ``prefill_counts`` add to
     # them (host arithmetic on positions: a model whose step reads
@@ -218,12 +220,14 @@ class SlotManager:
                          *model.prefill_counts(no_pos)):
                 self.stats[name] = 0
         if model.experts_per_token:
-            self.experts = model.expert_product
+            self.experts = grouped_product(model.expert_rows(max_slots, 1))
             # running sums beside the compile gates: assignments made by
-            # admitted prompts and live slots (a routed layer), and the
-            # steps' ``experts_hit``
+            # admitted prompts and live slots (a routed layer), the
+            # steps' ``experts_hit``, and the prefills by their product
             self.stats["moe_assignments"] = 0
             self.stats["moe_experts_hit"] = 0.0
+            for product in ("gmm", "ragged_dot"):
+                self.stats[f"moe_prefills_{product}"] = 0
         self._seed = int(seed)
         self._resets = 0
         # a failed dispatch may have consumed its DONATED operands (the
@@ -638,7 +642,9 @@ class SlotManager:
         if self.experts is not None:
             asked = self.model.experts_per_token * sum(a.size for a in arrs)
             self.stats.add("moe_assignments", asked)
-            attrs["assignments"] = asked
+            product = grouped_product(self.model.expert_rows(w, bucket))
+            self.stats.add(f"moe_prefills_{product}", 1)
+            attrs.update(experts=product, assignments=asked)
         if self._counted:
             attrs.update(self._summed(
                 self.model.prefill_counts(lens[:len(arrs)])))

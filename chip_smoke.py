@@ -18,6 +18,9 @@ at the full width of models the repo lists, on whatever TPU JAX reports:
   bit and tokens one for one, at both serving cells' logits tables.
 - **decode_attention**: the decode step's length-bounded attention against
   the whole-table read in true float32, at the two serving cells' tables.
+- **grouped_product**: the prompt pass's grouped expert product
+  (``ops/grouped_matmul.py``) against ``lax.ragged_dot`` at the LFM2 and
+  dots3 cells' shapes, parity and the time of each (ISSUE 38's sweep).
 - **train**: ResNet-50 NHWC, bf16 compute, batch 256, a few steps through
   ``Optimizer(...).optimize()`` on one repeated seeded batch.
 - **four chips** (only when JAX reports four or more): the train leg then
@@ -609,6 +612,109 @@ def decode_attention_leg(tables=(((48, 16, 1024, 64), 1, "float32"),
             "tolerance": tol, "errors": errors, "selected": selected}
 
 
+# the grouped expert products of ISSUE 38, (name, assignments, contraction,
+# output, experts routed over, experts held, experts a token, share of the
+# tokens live): LFM2's step (96 slots x 4) and prompt passes (4 rows x
+# buckets 32-512 x 4, a fifth of the positions real as the cell's
+# ``prefill_useful_share.batch`` reads, and once every one), dots3's step
+# (36 x 8) and a block of its prompt pass (2048 x 8), 32 of 256 held
+GROUPED_PRODUCT_SHAPES = tuple(
+    (f"{name}.{mat}", rows, *(dims if mat == "w13" else dims[::-1]), *rest)
+    for name, rows, dims, rest in (
+        ("lfm2.step", 384, (2048, 1536), (64, 64, 4, 1.0)),
+        ("lfm2.prefill512", 512, (2048, 1536), (64, 64, 4, 0.2)),
+        ("lfm2.prefill1024", 1024, (2048, 1536), (64, 64, 4, 0.2)),
+        ("lfm2.prefill2048", 2048, (2048, 1536), (64, 64, 4, 0.2)),
+        ("lfm2.prefill4096", 4096, (2048, 1536), (64, 64, 4, 0.2)),
+        ("lfm2.prefill8192", 8192, (2048, 1536), (64, 64, 4, 0.2)),
+        ("lfm2.prefill8192full", 8192, (2048, 1536), (64, 64, 4, 1.0)),
+        ("dots3.step", 288, (5120, 1536), (256, 32, 8, 1.0)),
+        ("dots3.prefill", 16384, (5120, 1536), (256, 32, 8, 1.0)))
+    for mat in ("w13", "w2"))
+
+
+def grouped_product_leg(shapes=GROUPED_PRODUCT_SHAPES, tilings=None,
+                        reps=10, tol=1e-3, interpret=False):
+    """The prompt pass's grouped expert product (``ops/grouped_matmul.py``)
+    against ``lax.ragged_dot``, which it replaces there, at the shapes the
+    cells make: the rows of every group must agree (bfloat16 operands,
+    float32 sums; ``tol`` relative to the largest), the rows after the
+    groups (padding and experts held elsewhere) must come out zero. The
+    routing is drawn as the layer's: each token picks distinct experts,
+    a held one's rows go to its group, the rest trail. Both are timed on
+    the host's clock, ``reps`` calls back to back, the best of three
+    rounds: ms a call and the weights of the experts hit over it in GB/s.
+    ``tilings`` (a list of ``(tm, tk, tn)``, each fitted to the shape as
+    the product fits its own) is the sweep; None times the product's
+    own tiling only. Also which product the layer's rule
+    (``nn.moe.grouped_product``) gives each shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.nn.moe import grouped_product
+    from bigdl_tpu.ops.grouped_matmul import grouped_matmul, tiling
+    from bigdl_tpu.ops.pallas_util import fit_block
+
+    def timed(fn, *args):
+        fn(*args).block_until_ready()
+        best = float("inf")
+        for _ in range(3 if reps else 0):
+            t = time.perf_counter()
+            for _ in range(reps):
+                out = fn(*args)
+            out.block_until_ready()
+            best = min(best, (time.perf_counter() - t) / reps * 1e3)
+        return round(best, 4) if reps else None
+
+    t_start = time.perf_counter()
+    rng = np.random.default_rng(38)
+    cases = {}
+    for name, rows, k, n, experts, held, per_token, live in shapes:
+        tokens = rows // per_token
+        chosen = np.argsort(rng.random((tokens, experts)), 1)[:, :per_token]
+        chosen[int(round(live * tokens)):] = held      # dead: trailing
+        sizes = np.bincount(chosen.ravel(), minlength=held + 1)[:held]
+        keys = jax.random.split(jax.random.key(rows + k), 2)
+        lhs = jax.random.normal(keys[0], (rows, k), jnp.bfloat16)
+        rhs = (0.02 * jax.random.normal(keys[1], (held, k, n))).astype(
+            jnp.bfloat16)
+        sizes = jnp.asarray(sizes, jnp.int32)
+        held_rows = int(sizes.sum())
+        want = jax.jit(lambda a, b, s: jax.lax.ragged_dot(
+            a, b, s, preferred_element_type=jnp.float32))
+        ref = want(lhs, rhs, sizes)
+        own = tiling(rows, k, n)
+        sweep = {own, *((min(rows, tm), fit_block(k, tk), fit_block(n, tn))
+                        for tm, tk, tn in tilings or ())}
+        hit = int((sizes > 0).sum())
+        gbs = lambda ms: round(hit * k * n * 2 / ms / 1e6, 1) if ms else None
+        rec = {"rows": rows, "held_rows": held_rows, "experts_hit": hit,
+               "rule": grouped_product(rows)}
+        rec["ragged_dot_ms"] = timed(want, lhs, rhs, sizes)
+        rec["ragged_dot_gb_s"] = gbs(rec["ragged_dot_ms"])
+        rec["gmm_ms"] = {}
+        for tiles in sorted(sweep):
+            fn = jax.jit(functools.partial(grouped_matmul, tiles=tiles,
+                                           interpret=interpret))
+            got = fn(lhs, rhs, sizes)
+            err = _rel_err(got[:held_rows], ref[:held_rows])
+            trail = float(jnp.abs(got[held_rows:]).max(initial=0.0))
+            _require(err <= tol and trail == 0.0,
+                     f"grouped_product {name} {tiles}: {err} from "
+                     f"ragged_dot (over {tol}), trailing rows up to {trail}")
+            rec["gmm_ms"]["x".join(map(str, tiles))] = timed(
+                fn, lhs, rhs, sizes)
+        own_ms = rec["gmm_ms"]["x".join(map(str, own))]
+        rec.update(own_tiling=list(own), gmm_own_ms=own_ms,
+                   gmm_own_gb_s=gbs(own_ms))
+        cases[name] = rec
+        print(f"chip_smoke: grouped_product {name}: {json.dumps(rec)}",
+              flush=True)
+        del lhs, rhs, ref
+    return {"ok": True, "setup_s": round(time.perf_counter() - t_start, 2),
+            "tolerance": tol, "cases": cases}
+
+
 # how far the logits of the two-layer latent model, served in bfloat16,
 # may lie from the float32 reference's: the root of their mean square, and
 # the widest single one. A bfloat16 rounding turns a selection at its
@@ -897,6 +1003,7 @@ def main():
         ("decode_attention", decode_attention_leg),
         ("sampling", sampling_leg),
         ("latent_read", latent_read_leg),
+        ("grouped_product", grouped_product_leg),
         ("train", train_resnet50),
     ]
     if device["count"] >= 4:

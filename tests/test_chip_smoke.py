@@ -79,6 +79,21 @@ def test_sampling_leg():
     assert rec["selected"] == {"12x1000": False, "4x300": False}
 
 
+def test_grouped_product_leg():
+    """The sweep's parity at toy widths: a prompt pass's rows (a fifth
+    live), a share of the experts held, a step's rows under the rule."""
+    rec = chip_smoke.grouped_product_leg(
+        shapes=(("toy.prefill", 512, 32, 24, 8, 8, 2, 0.2),
+                ("toy.share", 512, 24, 32, 16, 4, 4, 1.0),
+                ("toy.step", 96, 32, 24, 8, 8, 2, 1.0)),
+        tilings=[(256, 128, 128)], reps=0, interpret=True)
+    cases = rec["cases"]
+    assert rec["ok"] and [c["rule"] for c in cases.values()] == [
+        "gmm", "gmm", "ragged_dot"]
+    assert cases["toy.prefill"]["held_rows"] < 512 / 4
+    assert set(cases["toy.prefill"]["gmm_ms"]) == {"128x32x24", "256x32x24"}
+
+
 def test_latent_read_leg():
     """A full layer and a window layer at toy widths in float32: prefill
     past the selection of 8 and the window of 5, then steps, held to the

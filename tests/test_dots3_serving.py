@@ -393,6 +393,7 @@ def test_step_and_prefill_spans_carry_the_rows_the_host_reckons(dots):
         return sum(min(p + 1, cap) for p in range(n))
 
     assert sm.prefill_attrs == {
+        "experts": "ragged_dot",        # 2 rows x 8 positions x 4
         "assignments": 4 * 26,
         "dsa_context_rows": summed(5, PMAX) + summed(21, PMAX),
         "dsa_selected_rows": summed(5, K) + summed(21, K),

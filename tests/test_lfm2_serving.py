@@ -307,6 +307,34 @@ def test_engine_serves_the_model_and_stamps_the_expert_counters(lfm2):
         sum(a["experts_hit"] for a in reads), rel=1e-5)
 
 
+def test_prefill_spans_name_the_product_their_rows_take(lfm2):
+    """A prompt of 40 tokens fills a prefill of 4 rows x 64 positions x 2
+    assignments = 512, the grouped matmul's; one of 5 a prefill of 4 x 16
+    x 2 = 128, ``ragged_dot``'s; a step of 4 slots x 2 stays on
+    ``ragged_dot``. The spans and ``engine.stats`` say so, and the tokens
+    served through either are the reference's."""
+    model, params, reference = lfm2
+    obs.default_tracer().clear()
+    rng = np.random.default_rng(38)
+    prompts = [rng.integers(0, 97, n).astype(np.int32) for n in (40, 5)]
+    with ServingEngine(model, params, max_slots=4, max_queue=8) as eng:
+        outs = [np.asarray(eng.submit(p, 6).result(timeout=120))
+                for p in prompts]
+        stats = dict(eng.stats)
+    for p, o in zip(prompts, outs):
+        rows = _reference_rows(reference, params, o)[len(p) - 1:len(o) - 1]
+        assert (rows.max(-1) - rows[np.arange(6), o[len(p):]]).max() < TOL
+    spans = obs.default_tracer().spans()
+    fills = [s.attrs for s in spans if s.name == "serve/prefill"]
+    assert [(a["bucket"], a["experts"]) for a in fills] == [
+        (64, "gmm"), (16, "ragged_dot")]
+    steps = [s.attrs for s in spans
+             if s.name == "serve/step" and "live" in s.attrs]
+    assert steps and {a["experts"] for a in steps} == {"ragged_dot"}
+    assert (stats["moe_prefills_gmm"], stats["moe_prefills_ragged_dot"]) \
+        == (1, 1)
+
+
 def test_a_model_without_experts_stamps_none_of_it():
     obs.default_tracer().clear()
     model = GPTForCausalLM(vocab_size=61, hidden_size=32, n_layers=2,
