@@ -36,12 +36,15 @@ the model's own). It carries none of the engine's optional features.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
 import bigdl_tpu.nn as nn
+from bigdl_tpu.models import prompt_blocks
 from bigdl_tpu.nn.gated import mm
 from bigdl_tpu.nn.module import Module
 
@@ -171,9 +174,7 @@ class Dots3ForCausalLM(Module):
         for gate in (attention_gate_type, swa_attention_gate_type):
             if gate not in ("headwise", None):
                 raise ValueError(f"unknown attention gate {gate!r}")
-        if max_position > prefill_block and max_position % prefill_block:
-            raise ValueError(f"max_position {max_position} is not whole "
-                             f"prefill blocks of {prefill_block}")
+        prompt_blocks.check_positions(max_position, prefill_block)
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.max_position = max_position
@@ -238,12 +239,6 @@ class Dots3ForCausalLM(Module):
                         axis=0).astype(jnp.float32)
 
     # ------------------------------------------------------ a prompt pass --
-    def _blocks(self, length):
-        """``(block, n)``: ``length`` positions are walked as ``n`` blocks
-        of ``block``."""
-        block = min(self.prefill_block, length)
-        return block, -(-length // block)
-
     def _carries(self, batch, dtype):
         return [l.attn.init_carry(batch, dtype) for l in self.layers]
 
@@ -264,7 +259,7 @@ class Dots3ForCausalLM(Module):
         """``x`` (B, T) tokens -> logits ``(B x T, vocab)``, walked a block
         of ``prefill_block`` positions at a time."""
         b, t = x.shape
-        block, n = self._blocks(t)
+        block, n = prompt_blocks.block_count(self.prefill_block, t)
         ids = jnp.pad(x, ((0, 0), (0, n * block - t)))
         dtype = self.serving_dtype(params)
         cache = [l.attn.init_cache(b, n * block, dtype) for l in self.layers]
@@ -362,41 +357,15 @@ class Dots3ForCausalLM(Module):
         junk that its steps overwrite before they read it), each ring
         slot holding its residue's newest real position. A block of
         ``prefill_block`` queries goes through every layer before the
-        next; the blocks past the longest prompt are not walked."""
-        b, bucket = ids.shape
-        block, n = self._blocks(bucket)
-        rows = n * block
-        ids = jnp.pad(ids, ((0, 0), (0, rows - bucket)))
-        prompt_len = jnp.broadcast_to(jnp.asarray(prompt_len, jnp.int32),
-                                      (b,))
-        last = prompt_len - 1
-        # a prompt of this bucket reads no row past it: the pass works on
-        # the leading rows of the position tables
-        whole = cache
-        cache = [{k: v[:, :rows] if layer.kind == FULL else v
-                  for k, v in c.items()}
-                 for layer, c in zip(self.layers, whole)]
+        next; the blocks past the longest prompt are not walked
+        (``models/prompt_blocks.py``)."""
         dtype = jax.tree_util.tree_leaves(cache)[0].dtype
-
-        def one(j, carry):
-            cache, carries, h_last = carry
-            first = j * block
-            ids_j = lax.dynamic_slice_in_dim(ids, first, block, axis=1)
-            h, cache, carries = self._block(params, cache, carries, ids_j,
-                                            first, prompt_len)
-            row = jnp.take_along_axis(h, (last % block)[:, None, None],
-                                      axis=1)[:, 0]
-            h_last = jnp.where((last // block == j)[:, None], row, h_last)
-            return cache, carries, h_last
-
-        walked = (jnp.max(prompt_len) + block - 1) // block
-        cache, _, h_last = lax.fori_loop(
-            0, walked, one,
-            (cache, self._carries(b, dtype),
-             jnp.zeros((b, self.hidden_size), jnp.float32)))
-        cache = [{k: lax.dynamic_update_slice(w[k], v, (0, 0, 0))
-                  if layer.kind == FULL else v for k, v in c.items()}
-                 for layer, c, w in zip(self.layers, cache, whole)]
+        h_last, cache = prompt_blocks.walk(
+            self.prefill_block, self.hidden_size, cache, ids, prompt_len,
+            functools.partial(self._block, params),
+            carries=lambda: self._carries(ids.shape[0], dtype),
+            position_axes=[1 if l.kind == FULL else None
+                           for l in self.layers])
         return self.out_norm.call(params["out_norm"], h_last), cache
 
     def decode_step(self, params, cache, tok, pos, in_place=False,

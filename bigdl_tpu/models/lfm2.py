@@ -47,13 +47,16 @@ CONV, ATTENTION = "conv", "full_attention"
 class GroupedQueryAttention(Module):
     """Causal attention with ``n_kv_heads`` K/V heads under ``n_heads``
     query heads (``n_heads / n_kv_heads`` queries share one), QK-norm and
-    rotary positions. The cache is ``{"k", "v"}`` of ``(B, n_kv_heads,
-    max_len, head_dim)``, the dense slot table's own shape, so a decode
-    step writes through ``ops/kv_write.py`` where the table's owner says
-    it applies."""
+    rotary positions (``qk_norm=False``: q and k as projected;
+    ``rotary=False``: no positional encoding at all, as the Nemotron-H
+    family's attention layers). The cache is ``{"k", "v"}`` of ``(B,
+    n_kv_heads, max_len, head_dim)``, the dense slot table's own shape, so
+    a decode step writes through ``ops/kv_write.py`` where the table's
+    owner says it applies."""
 
     def __init__(self, hidden_size, n_heads, n_kv_heads, head_dim=None,
-                 rope_theta=10000.0, norm_eps=1e-5):
+                 rope_theta=10000.0, norm_eps=1e-5, qk_norm=True,
+                 rotary=True):
         super().__init__()
         if n_heads % n_kv_heads:
             raise ValueError(f"{n_heads} query heads do not divide over "
@@ -63,6 +66,8 @@ class GroupedQueryAttention(Module):
         self.n_kv_heads = n_kv_heads
         self.head_dim = head_dim or hidden_size // n_heads
         self.rope_theta = rope_theta
+        self.qk_norm = qk_norm
+        self.rotary = rotary
         self.q_norm = nn.RMSNorm(self.head_dim, norm_eps)
         self.k_norm = nn.RMSNorm(self.head_dim, norm_eps)
 
@@ -71,29 +76,37 @@ class GroupedQueryAttention(Module):
         q, kv = self.n_heads * hd, self.n_kv_heads * hd
         ks = jax.random.split(rng, 4)
         std = d ** -0.5
-        return {"wq": jax.random.normal(ks[0], (d, q)) * std,
-                "wk": jax.random.normal(ks[1], (d, kv)) * std,
-                "wv": jax.random.normal(ks[2], (d, kv)) * std,
-                "wo": jax.random.normal(ks[3], (q, d)) * q ** -0.5,
-                "q_norm": self.q_norm.make_params(None, None),
-                "k_norm": self.k_norm.make_params(None, None)}
+        params = {"wq": jax.random.normal(ks[0], (d, q)) * std,
+                  "wk": jax.random.normal(ks[1], (d, kv)) * std,
+                  "wv": jax.random.normal(ks[2], (d, kv)) * std,
+                  "wo": jax.random.normal(ks[3], (q, d)) * q ** -0.5}
+        if self.qk_norm:
+            params.update(q_norm=self.q_norm.make_params(None, None),
+                          k_norm=self.k_norm.make_params(None, None))
+        return params
 
     def _qkv(self, params, x, positions):
         """``x`` (B, T, hidden), ``positions`` (T,) or (B, T) -> q
         ``(B, kv, rep, T, hd)``, k and v ``(B, kv, T, hd)``, float32, q
-        and k normed and turned."""
+        and k normed and turned (where the layer has either)."""
         b, t, _ = x.shape
         g, hd = self.n_kv_heads, self.head_dim
         q = mm(x, params["wq"]).reshape(b, t, g, self.n_heads // g, hd)
         k = mm(x, params["wk"]).reshape(b, t, g, hd)
         v = mm(x, params["wv"]).reshape(b, t, g, hd)
-        cos, sin = rotary_angles(positions, hd, self.rope_theta)
-        if cos.ndim == 2:
-            cos, sin = cos[None], sin[None]
-        cos, sin = cos[:, :, None], sin[:, :, None]           # (B|1,T,1,hd)
-        q = apply_rotary(self.q_norm.call(params["q_norm"], q),
-                         cos[:, :, :, None], sin[:, :, :, None])
-        k = apply_rotary(self.k_norm.call(params["k_norm"], k), cos, sin)
+        if self.rotary:
+            cos, sin = rotary_angles(positions, hd, self.rope_theta)
+            if cos.ndim == 2:
+                cos, sin = cos[None], sin[None]
+            cos, sin = cos[:, :, None], sin[:, :, None]       # (B|1,T,1,hd)
+        if self.qk_norm:
+            q = self.q_norm.call(params["q_norm"], q)
+        if self.rotary:
+            q = apply_rotary(q, cos[:, :, :, None], sin[:, :, :, None])
+        if self.qk_norm:
+            k = self.k_norm.call(params["k_norm"], k)
+        if self.rotary:
+            k = apply_rotary(k, cos, sin)
         return (q.transpose(0, 2, 3, 1, 4), k.transpose(0, 2, 1, 3),
                 v.transpose(0, 2, 1, 3))
 
@@ -138,6 +151,22 @@ class GroupedQueryAttention(Module):
                  "v": lax.dynamic_update_slice(cache["v"], v, (0, 0, 0, 0))}
         return self._attend(params, q, k, v,
                             jnp.tril(jnp.ones((t, t), bool))), cache
+
+    def block_pass(self, params, x, cache, first):
+        """One block of a prompt: ``x`` (B, L, hidden) at positions
+        ``first .. first + L - 1``, their K and V written into rows
+        ``[first, first + L)`` of ``cache``, each query attending to the
+        cache's rows up to its own position (what lies past it, the
+        padding's or nothing's, is masked off)."""
+        t = x.shape[1]
+        positions = first + jnp.arange(t)
+        q, k, v = self._qkv(params, x, positions)
+        k, v = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
+        at = (0, 0, first, 0)
+        cache = {"k": lax.dynamic_update_slice(cache["k"], k, at),
+                 "v": lax.dynamic_update_slice(cache["v"], v, at)}
+        seen = jnp.arange(cache["k"].shape[2])[None, :] <= positions[:, None]
+        return self._attend(params, q, cache["k"], cache["v"], seen), cache
 
     def decode_step(self, params, x, cache, pos, in_place=False, read=None,
                     live=None):

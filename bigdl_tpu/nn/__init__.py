@@ -87,3 +87,4 @@ from bigdl_tpu.nn.moe import (  # noqa: F401
 from bigdl_tpu.nn.latent import (  # noqa: F401
     LatentAttention, SelectedLatentAttention, WindowLatentAttention)
 from bigdl_tpu.nn.gated import GatedMLP, GatedShortConv  # noqa: F401
+from bigdl_tpu.nn.ssm import Mamba2Mixer  # noqa: F401

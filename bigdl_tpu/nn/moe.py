@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from bigdl_tpu.nn.gated import mm
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.ops.grouped_matmul import grouped_matmul
 
@@ -181,15 +182,41 @@ class MoE(Module):
         return y.reshape(shape), {"aux_loss": aux}
 
 
+class SquaredReLUMLP(Module):
+    """``w2(relu(w1 x)^2)``: the feed-forward of ``mlp_hidden_act: relu2``
+    (no gate)."""
+
+    def __init__(self, hidden_size, ffn_size):
+        super().__init__()
+        self.hidden_size = hidden_size
+        self.ffn_size = ffn_size
+
+    def make_params(self, rng, input_spec):
+        d, f = self.hidden_size, self.ffn_size
+        k1, k2 = jax.random.split(rng)
+        return {"w1": jax.random.normal(k1, (d, f)) * d ** -0.5,
+                "w2": jax.random.normal(k2, (f, d)) * f ** -0.5}
+
+    def call(self, params, x):
+        return mm(jnp.square(jax.nn.relu(mm(x, params["w1"]))), params["w2"])
+
+
 class RoutedExperts(Module):
-    """Dropless routed SwiGLU experts, as the sparse decoders after 2024
-    route (sigmoid scores, a bias that moves the CHOICE only, weights
+    """Dropless routed experts, as the sparse decoders after 2024 route
+    (sigmoid scores, a bias that moves the CHOICE only, weights
     renormalised over the chosen):
 
         s = sigmoid(x @ wg)                       ``num_experts`` scores
         chosen = the ``k`` largest of s + expert_bias
         w_e = s_e / (sum of the chosen s + 1e-6) * scaling
-        y = sum over chosen e of w_e * w2[e](silu(w1[e] x) * w3[e] x)
+        y = sum over chosen e of w_e * E_e(x)
+
+    ``act`` is the experts' form: ``"swiglu"``, ``E_e(x) = w2[e](silu(w1[e]
+    x) * w3[e] x)``, or ``"relu2"``, ``E_e(x) = w2[e](relu(w1[e] x)^2)``.
+    With ``latent_size`` the experts work at that width between two
+    matrices that every expert shares (LatentMoE): the router still
+    scores the full-width ``x``, ``l = x @ w_down``, and ``y = (sum over
+    chosen e of w_e * E_e(l)) @ w_up``.
 
     The layer is told which experts it HOLDS: ``count`` of them from
     ``first`` on (default: all). It routes over all ``num_experts``,
@@ -211,9 +238,11 @@ class RoutedExperts(Module):
 
     def __init__(self, hidden_size, ffn_size, num_experts, k, first=0,
                  count=None, use_bias=True, norm_topk_prob=True,
-                 scaling=1.0):
+                 scaling=1.0, act="swiglu", latent_size=None):
         super().__init__()
         count = num_experts - first if count is None else count
+        if act not in ("swiglu", "relu2"):
+            raise ValueError(f"unknown expert form {act!r}")
         if k < 1 or k > num_experts:
             raise ValueError(f"k={k} outside [1, {num_experts}]")
         if first < 0 or count < 1 or first + count > num_experts:
@@ -228,15 +257,23 @@ class RoutedExperts(Module):
         self.use_bias = use_bias
         self.norm_topk_prob = norm_topk_prob
         self.scaling = scaling
+        self.act = act
+        self.latent_size = latent_size
 
     def make_params(self, rng, input_spec):
         d, f, e, c = (self.hidden_size, self.ffn_size, self.num_experts,
                       self.count)
         kg, k1, k2, k3 = jax.random.split(rng, 4)
+        w = self.latent_size or d
         params = {"wg": jax.random.normal(kg, (d, e)) * d ** -0.5,
-                  "w1": jax.random.normal(k1, (c, d, f)) * d ** -0.5,
-                  "w3": jax.random.normal(k2, (c, d, f)) * d ** -0.5,
-                  "w2": jax.random.normal(k3, (c, f, d)) * f ** -0.5}
+                  "w1": jax.random.normal(k1, (c, w, f)) * w ** -0.5,
+                  "w2": jax.random.normal(k3, (c, f, w)) * f ** -0.5}
+        if self.act == "swiglu":
+            params["w3"] = jax.random.normal(k2, (c, w, f)) * w ** -0.5
+        if self.latent_size:
+            k4, k5 = jax.random.split(jax.random.fold_in(rng, 4))
+            params["w_down"] = jax.random.normal(k4, (d, w)) * d ** -0.5
+            params["w_up"] = jax.random.normal(k5, (w, d)) * w ** -0.5
         if self.use_bias:
             params["expert_bias"] = jnp.zeros((e,))
         return params
@@ -286,6 +323,8 @@ class RoutedExperts(Module):
             group[:, None] == jnp.arange(c, dtype=group.dtype)[None, :],
             axis=0, dtype=jnp.int32)
         dt = params["w1"].dtype
+        if self.latent_size:
+            x = mm(x, params["w_down"])                       # (N, latent)
         xs = jnp.take(x.astype(dt), order // k, axis=0)       # (N * k, d)
 
         def grouped(a, b):
@@ -294,11 +333,16 @@ class RoutedExperts(Module):
             return lax.ragged_dot(a.astype(dt), b, sizes,
                                   preferred_element_type=jnp.float32)
 
-        h = jax.nn.silu(grouped(xs, params["w1"])) \
-            * grouped(xs, params["w3"])
+        if self.act == "relu2":
+            h = jnp.square(jax.nn.relu(grouped(xs, params["w1"])))
+        else:
+            h = jax.nn.silu(grouped(xs, params["w1"])) \
+                * grouped(xs, params["w3"])
         ys = jnp.take(grouped(h, params["w2"]), back, axis=0)
         ys = jnp.where(mine[:, None], ys, 0.0).reshape(n, k, -1)
         y = jnp.sum(ys * w[:, :, None], axis=1)
+        if self.latent_size:
+            y = mm(y, params["w_up"])
         return y, sizes
 
     def call(self, params, x):
@@ -310,19 +354,22 @@ class RoutedExperts(Module):
 class SharedAndRoutedExperts(Module):
     """:class:`RoutedExperts` beside ``n_shared`` experts that every
     token passes through (the DeepSeek line's shared experts): ``y =
-    routed(x) + shared(x)``, the shared ones one ``GatedMLP`` of
-    ``n_shared x ffn_size``. Under expert parallelism every holder
-    computes the shared part alike, so it counts ONCE when the holders'
-    shares are added up. Arguments after ``n_shared`` are
+    routed(x) + shared(x)``, the shared ones one feed-forward of the
+    routed experts' form (``act``) at full width, ``shared_size`` wide
+    (default ``n_shared x ffn_size``). Under expert parallelism every
+    holder computes the shared part alike, so it counts ONCE when the
+    holders' shares are added up. Arguments after ``shared_size`` are
     :class:`RoutedExperts`'s."""
 
     def __init__(self, hidden_size, ffn_size, num_experts, k, n_shared=1,
-                 **routed_kw):
+                 shared_size=None, **routed_kw):
         super().__init__()
         from bigdl_tpu.nn.gated import GatedMLP
         self.experts = RoutedExperts(hidden_size, ffn_size, num_experts, k,
                                      **routed_kw)
-        self.shared = GatedMLP(hidden_size, n_shared * ffn_size)
+        width = shared_size or n_shared * ffn_size
+        self.shared = SquaredReLUMLP(hidden_size, width) \
+            if self.experts.act == "relu2" else GatedMLP(hidden_size, width)
 
     def make_params(self, rng, input_spec):
         k1, k2 = jax.random.split(rng)

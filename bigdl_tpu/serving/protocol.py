@@ -2,9 +2,10 @@
 
 A model is served through these names and through nothing else of it
 (``models/gpt.py``'s ``GPTForCausalLM``, ``models/lfm2.py``'s
-``LFM2ForCausalLM``, ``models/evabyte.py``'s ``EvaByteForCausalLM`` and
-``models/dots3.py``'s ``Dots3ForCausalLM`` are the four implementations;
-docs/serving.md has the contract in prose):
+``LFM2ForCausalLM``, ``models/evabyte.py``'s ``EvaByteForCausalLM``,
+``models/dots3.py``'s ``Dots3ForCausalLM`` and ``models/nemotron_h.py``'s
+``NemotronHForCausalLM`` are the five implementations; docs/serving.md
+has the contract in prose):
 
 ``vocab_size``, ``max_position``
     ints: the width of a row of logits, and the positions a slot holds
@@ -26,7 +27,9 @@ docs/serving.md has the contract in prose):
     by row, a tuple of :class:`RowTable` (empty for a model whose state
     is all fixed-size): which leaves, how many rows a slot has, which
     row the step at position ``pos`` writes and how many rows it reads.
-    Every other leaf is fixed-size state (a convolution's last taps).
+    Every other leaf is fixed-size state (a convolution's last taps; a
+    Mamba-2 layer's float32 S, rewritten whole at every step, of which
+    the model's own kernel moves the live slots' only).
     GPT-2 and LFM2 describe one table, K and V of ``max_position`` rows
     written at ``pos`` and read up to it; EvaByte two, a window written
     at ``pos mod 2048`` and chunk summaries that gain a row every 16th
@@ -34,7 +37,8 @@ docs/serving.md has the contract in prose):
     latents of every position READ BY SELECTION (``selected``: every
     row up to ``pos`` is scored, the 2048 best count, and which they are
     is decided on the device) and a ring of 513 latents, the last two
-    read by the model's own kernel (``own_read``). From the
+    read by the model's own kernel (``own_read``); Nemotron-H one, K and
+    V of its attention layers, beside its Mamba layers' state. From the
     tables AS ALLOCATED the slot table derives whether
     ``ops/kv_write.py`` takes the step's writes (every table's leaves
     lie as the kernel needs them, rows third among it) and whether

@@ -152,3 +152,44 @@ def test_entry_refuses_without_tpu():
     assert "runs on a TPU only" in r.stderr
     assert "JAX_PLATFORMS was 'cpu'" in r.stderr
     assert '"ok"' not in r.stdout
+
+
+def test_ssm_leg():
+    """A Mamba layer and an expert layer at toy widths in float32, the
+    family's long-memory state: prefill over two blocks, several chunks
+    and a ragged last one, then steps, held to the plain reference's
+    rows."""
+    rec = chip_smoke.ssm_leg(
+        model_kw=dict(
+            vocab_size=50, hidden_size=32, mamba_num_heads=4,
+            mamba_head_dim=8, n_groups=2, ssm_state_size=16, chunk_size=8,
+            n_routed_experts=16, num_experts_per_tok=4,
+            moe_intermediate_size=12, moe_latent_size=16,
+            moe_shared_expert_intermediate_size=24, experts_first=4,
+            experts_held=4, max_position=64, prefill_block=16),
+        lengths=(20, 29), steps=10, dtype="float32",
+        tol={"rms": 5e-5, "widest": 5e-5},
+        weights_spec={"std": 0.2, "gain_std": 0.1, "bias_std": 0.5})
+    assert rec["ok"] and rec["logit_gap"] <= 5e-5 < rec["logit_spread"]
+    assert rec["ssm_update"] == "plain" and rec["ssm_slots"] == 2 * 10
+
+
+def test_ssm_leg_builds_its_own_model_at_the_published_widths():
+    """The leg's default model is built as the chip builds it (shapes
+    only): its positions are whole prompt blocks, hold the longer prompt
+    and its steps, and each prompt crosses a block."""
+    import inspect
+
+    import jax
+
+    from bigdl_tpu.models.nemotron_h import NemotronHForCausalLM
+    model = NemotronHForCausalLM(**chip_smoke.SSM_LEG_KW)
+    shapes = jax.eval_shape(lambda k: model.setup(k, None)[0],
+                            jax.random.key(0))
+    assert shapes["layers"][0]["mixer"]["A_log"].shape == (128,)
+    jax.eval_shape(lambda: model.init_cache(2))
+    leg = inspect.signature(chip_smoke.ssm_leg).parameters
+    lengths, steps = leg["lengths"].default, leg["steps"].default
+    assert model.max_position % model.prefill_block == 0
+    assert max(lengths) + steps <= model.max_position
+    assert min(lengths) > model.prefill_block

@@ -359,3 +359,28 @@ def test_latent_read_compiled_at_the_cell_size(one_chip, shape, heads,
     assert "tpu_custom_call" in text and "latent_attention" in text
     # the table goes in as it lies: no copy of it around the call
     assert not re.search(rf"copy\(\w*\[{b},{rows},{width}\]", text)
+
+
+def test_state_update_compiled_at_the_cell_size_moves_no_state(one_chip):
+    """``ops/ssm_step.py`` (a Mamba-2 layer's decode-step state update)
+    at ``nemotron3-reason-closed``'s table, 128 slots of 128 x 64 x 128
+    float32, donated as the serving step donates it: the kernel takes the
+    state where it lies and hands it back aliased, with no copy of it
+    around the call and nothing of its size beside it. Kept here, beside
+    the other step kernels' compiles, so that one worker loads the TPU's
+    compiler."""
+    from bigdl_tpu.ops import ssm_step
+    shape = (128, 128, 64, 128)
+    at = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    update = jax.jit(lambda *a: ssm_step.ssm_update(*a, interpret=False),
+                     donate_argnums=0)
+    compiled = update.lower(
+        at(shape, jnp.float32), at(shape[:2], jnp.float32),
+        at(shape[:3], jnp.float32), at((128, 128, 128), jnp.float32),
+        at((128, 128, 128), jnp.float32), at((128,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm_step" in text
+    assert not re.search(r"copy\(\w*\[128,128,64,128\]", text)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 4 * 128 * 128 * 64 * 128
+    assert memory.temp_size_in_bytes < 2 ** 20

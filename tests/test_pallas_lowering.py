@@ -107,3 +107,15 @@ def test_kv_write(dtype, heads):
     new = S((SLOTS, heads, 1, HEAD_DIM), dtype)
     lower_for_tpu(lambda *a: kv_write(*a, interpret=False),
                   table, table, new, new, S((SLOTS,), jnp.int32))
+
+
+@pytest.mark.parametrize("heads", [128, 16], ids=["h128", "h16"])
+def test_ssm_step(heads):
+    from bigdl_tpu.ops.ssm_step import ssm_update
+    lower_for_tpu(lambda *a: ssm_update(*a, interpret=False),
+                  S((SLOTS, heads, 64, 128), jnp.float32),
+                  S((SLOTS, heads), jnp.float32),
+                  S((SLOTS, heads, 64), jnp.float32),
+                  S((SLOTS, heads, 128), jnp.float32),
+                  S((SLOTS, heads, 128), jnp.float32),
+                  S((SLOTS,), jnp.bool_))
