@@ -21,6 +21,12 @@ Traffic keys:
                    greedy)
 ``lead_in_s``      seconds of the same traffic before the window opens, so
                    the window starts in steady state (counted as set-up)
+``drain_s``        seconds the requests still open when the window closes
+                   may take to finish before they count as failed (default
+                   60; the serving runner's)
+``trace_seconds``  seconds that ``--trace 1`` profiles in the middle of the
+                   window (default ``profiler.TRACE_SECONDS``, at most half
+                   the window)
 """
 
 from __future__ import annotations

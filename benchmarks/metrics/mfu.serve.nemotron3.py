@@ -14,4 +14,6 @@ def read(ctx):
             if a <= t < b:
                 # token 1 comes out of the prompt pass; token k+1 out of feeding token k back at position n0 + k - 1
                 flops += counts.prefill_flops(s, n0) if k == 0 else counts.decode_flops(s, n0 + k - 1)
+    if not flops:       # no token reached a client in the traced part
+        return None
     return 100.0 * flops / ((b - a) * ctx.chips * ctx.peaks["flops_per_s"])

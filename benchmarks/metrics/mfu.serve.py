@@ -16,4 +16,6 @@ def read(ctx):
                 # token 1 comes out of the prompt pass; token k+1 out of feeding token k back
                 flops += counts.gpt_prefill_flops(n0, v, h, n) if k == 0 \
                     else counts.gpt_decode_flops(n0 + k, v, h, n)
+    if not flops:       # no token reached a client in the traced part
+        return None
     return 100.0 * flops / ((b - a) * ctx.chips * ctx.peaks["flops_per_s"])

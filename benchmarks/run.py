@@ -233,7 +233,8 @@ def run(args):
     runner_mod = importlib.import_module("benchmarks.runners."
                                          + config["runner"])
     runner = runner_mod.Runner(ctx)
-    profiler = prof.Profiler() if args.trace else None
+    profiler = prof.Profiler(float(traffic.get(
+        "trace_seconds", prof.TRACE_SECONDS))) if args.trace else None
 
     with prof.compile_log() as compiled:
         runner.setup()

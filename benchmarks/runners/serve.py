@@ -25,6 +25,8 @@ import numpy as np
 
 from benchmarks.harness import loadgen, resolve, sleep_until, weights, window
 
+DRAIN_S = 60.0   # seconds the last requests sent may take; ``drain_s`` overrides
+
 
 class Runner:
     def __init__(self, ctx):
@@ -88,7 +90,8 @@ class Runner:
 
     def run_window(self, profiler=None):
         """Lead-in, then the window; returns ``(start, end)`` once every
-        request sent has finished or a minute has passed."""
+        request sent has finished or the traffic's ``drain_s`` (default 60)
+        has passed since the window closed."""
         ctx = self.ctx
         lead = float(ctx.traffic.get("lead_in_s", 0.0))
         self.gen = loadgen.LoadGenerator(ctx.traffic, self.planned,
@@ -109,7 +112,8 @@ class Runner:
         self.gen.stop_sending()
         ctx.counters.update({k: int(v) for k, v in self.engine.stats.items()
                              if isinstance(v, (int, np.integer))})
-        self.unfinished = self.gen.drain(60.0)
+        self.unfinished = self.gen.drain(
+            float(ctx.traffic.get("drain_s", DRAIN_S)))
         ctx.records = self.gen.records
         late = window.percentile(
             window.send_lateness(ctx.records, ctx.window), 95)
@@ -148,41 +152,12 @@ class Runner:
         return len(recs), failed
 
     def sample_for_check(self):
-        """``(greedy, sampled)``: requests of the window that finished.
-        Greedy: the longest, then one of every prefill class the window
-        finished (a power-of-two prompt length, as the warm-up classes
-        them), then others drawn from the seed until ``check.min_tokens``
-        served tokens are in the sample (at most ``check.max_requests``
-        requests). Sampled: ``check.sampled_requests`` of those decoded at
-        a temperature, drawn from the seed."""
+        """``(greedy, sampled)``: requests of the window that finished,
+        chosen by :func:`choose_for_check`."""
         ctx = self.ctx
         done = [r for r in window.due_in(ctx.records, ctx.window)
                 if r.error is None and len(r.tokens) == r.planned.max_new]
-        check = ctx.config["check"]
-        rng = np.random.default_rng([int(ctx.seed), 0xc4ec])
-        warm = [r for r in done if r.planned.temperature > 0.0]
-        sampled = [warm[i] for i in rng.permutation(len(warm))[
-            :int(check.get("sampled_requests", 0))]]
-        cold = [r for r in done if r.planned.temperature == 0.0]
-        if not cold:
-            return [], sampled
-        cold = [cold[i] for i in rng.permutation(len(cold))]
-        first = [max(cold, key=lambda r: len(r.planned.prompt)
-                     + len(r.tokens))]
-        classes = {_prefill_class(len(first[0].planned.prompt))}
-        for r in cold:
-            if _prefill_class(len(r.planned.prompt)) not in classes:
-                classes.add(_prefill_class(len(r.planned.prompt)))
-                first.append(r)
-        out, n_tok = [], 0
-        for r in first + [r for r in cold if all(r is not f for f in first)]:
-            if len(out) >= len(first) and (
-                    n_tok >= int(check["min_tokens"])
-                    or len(out) >= int(check["max_requests"])):
-                break
-            out.append(r)
-            n_tok += len(r.tokens)
-        return out, sampled
+        return choose_for_check(done, ctx.seed, ctx.config["check"])
 
     def verify(self, with_control=False):
         """``(attempted, failed, checks, controls)``. ``checks`` is the
@@ -272,6 +247,39 @@ class Runner:
         return attempted, failed, checks, {
             name: _numbers(gaps[name], below_set[name], lim)
             for name in controls}
+
+
+def choose_for_check(done, seed, check):
+    """``(greedy, sampled)`` of the finished records ``done``. Greedy: the
+    longest, then one of every prefill class they hold (a power-of-two
+    prompt length, as the warm-up classes them), then others drawn from the
+    seed until ``check["min_tokens"]`` served tokens are in the sample (at
+    most ``check["max_requests"]`` requests, and never fewer than those
+    chosen first). Sampled: ``check["sampled_requests"]`` of those decoded
+    at a temperature, drawn from the seed."""
+    rng = np.random.default_rng([int(seed), 0xc4ec])
+    warm = [r for r in done if r.planned.temperature > 0.0]
+    sampled = [warm[i] for i in rng.permutation(len(warm))[
+        :int(check.get("sampled_requests", 0))]]
+    cold = [r for r in done if r.planned.temperature == 0.0]
+    if not cold:
+        return [], sampled
+    cold = [cold[i] for i in rng.permutation(len(cold))]
+    first = [max(cold, key=lambda r: len(r.planned.prompt) + len(r.tokens))]
+    classes = {_prefill_class(len(first[0].planned.prompt))}
+    for r in cold:
+        if _prefill_class(len(r.planned.prompt)) not in classes:
+            classes.add(_prefill_class(len(r.planned.prompt)))
+            first.append(r)
+    out, n_tok = [], 0
+    for r in first + [r for r in cold if all(r is not f for f in first)]:
+        if len(out) >= len(first) and (
+                n_tok >= int(check["min_tokens"])
+                or len(out) >= int(check["max_requests"])):
+            break
+        out.append(r)
+        n_tok += len(r.tokens)
+    return out, sampled
 
 
 def _prefill_class(n):

@@ -181,14 +181,15 @@ def test_the_expert_roofline_is_none_not_zero_without_the_operation(run, config)
 def test_every_new_metric_is_an_entry_a_file_and_reported_in_the_cell(run):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    assert [m["name"] for m in manifest["per_layer"]][-len(NEW):] == NEW
+    assert [m["name"] for m in manifest["per_layer"] if m["name"] in NEW] == NEW   # in their order, wherever later entries are appended
     cell = next(c for c in manifest["workloads"] if c["name"] == CELL)
     reported = {m["name"] for m in run.metrics_for(manifest, cell, "per_layer")}
     assert set(NEW) <= reported and not {"mfu.serve", "decode_step_roofline"} & reported
     assert {m["name"] for m in run.metrics_for(manifest, cell, "end_to_end")} == {"tpot_p95_ms", "tokens_per_s", "setup_s"}
     for m in manifest["per_layer"]:
         if m["name"] in NEW:
-            assert m["workloads"] == [CELL] and m["moves"] == "tpot_p95_ms" and callable(run.load_reader(BENCH, m["name"]))
+            # this cell first; other cells may join (dots3 and Nemotron list moe_experts_hit_mean)
+            assert m["workloads"][0] == CELL and m["moves"] == "tpot_p95_ms" and callable(run.load_reader(BENCH, m["name"]))
 
 
 # --------------------------------------------------------------- reference --
@@ -203,5 +204,6 @@ def test_rehearsal_with_a_trace_fills_the_counter_and_no_device_metric(capsys):
     assert run.main(["--workload", CELL, "--seed", str(2**31 + 78), "--seconds", "2", "--trace", "1", "--rehearse"]) == 0
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
-    assert set(out["metrics"]) == {"window_compiles"} and out["metrics"]["window_compiles"]["value"] == 0
+    counters = {m["name"] for m in run.metrics_for(*run.load_cell(CELL)[:2], "per_layer") if m["source"] == "program_counter"}
+    assert set(out["metrics"]) <= counters and out["metrics"]["window_compiles"]["value"] == 0
     assert out["notes"]["checked"]["greedy_tokens"] >= 40

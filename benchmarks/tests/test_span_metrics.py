@@ -4,7 +4,7 @@ import os
 
 import pytest
 
-from benchmarks.harness import span_account, trace_reduce
+from benchmarks.harness import loadgen, peaks, span_account, trace_reduce
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
@@ -127,7 +127,7 @@ def test_every_new_metric_is_an_entry_and_a_file(run):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     entries = {m["name"]: m for m in manifest["per_layer"]}
-    assert [m["name"] for m in manifest["per_layer"]][-len(NEW):] == NEW       # appended, in the issue's order
+    assert [m["name"] for m in manifest["per_layer"] if m["name"] in NEW] == NEW   # in their order, wherever later entries are appended
     reports = {c["name"]: {m["name"] for m in run.metrics_for(manifest, c, "per_layer")} for c in manifest["workloads"]}
     for name in NEW:
         assert callable(run.load_reader(BENCH, name))
@@ -168,6 +168,37 @@ def test_idle_named_share_and_its_notes(run, trace):
     assert ctx.notes["idle_s_between_leaves"] == pytest.approx(0.010 - 0.0089)
     assert ctx.notes["idle_s_under_no_leaf"][0][:2] == ["bench-client-3", "np.asarray(jax.Array)"]
     json.dumps(ctx.notes)                                                               # the result line prints them
+
+
+def test_idle_named_share_lists_the_one_cell_whose_slice_holds_gaps(run):
+    """The other serving cells' traced slices hold no idle gap of 50 us, so the reader finds nothing to name there."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entry = next(m for m in manifest["per_layer"] if m["name"] == "idle_named_share.serve")
+    assert entry["workloads"] == ["gpt2m-chat-steady"]
+
+
+class _Served:
+    """A request whose two tokens reached its client at ``first`` and 50 ms later."""
+
+    def __init__(self, first):
+        self.planned = loadgen.Planned(0, 0.0, [1] * 100, 2, 0.0)
+        self.token_at = [first, first + 0.05]
+
+
+@pytest.mark.parametrize("name,cell", [("mfu.serve", "gpt2m-chat-steady"), ("mfu.serve.lfm2moe", "lfm2moe-generate-closed"),
+                                       ("mfu.serve.evabyte", "evabyte-longdoc-generate"),
+                                       ("mfu.serve.dots3", "dots3-longctx-generate"),
+                                       ("mfu.serve.nemotron3", "nemotron3-reason-closed")])
+def test_a_share_of_the_peak_with_no_token_in_the_slice_is_none_not_0(run, trace, name, cell):
+    """A slice that falls inside one long prompt pass sees no token reach a client: nothing to read, so no reading."""
+    config = run.load_cell(cell)[2]
+    read = run.load_reader(BENCH, name)
+    ctx = run.Ctx(config=config, trace=trace, traced=(9.9, 10.3), chips=1, peaks=peaks.peaks_for("TPU v5 lite"))
+    ctx.records = [_Served(11.0)]
+    assert read(ctx) is None
+    ctx.records = [_Served(10.0)]
+    assert 0.0 < read(ctx) < 100.0
 
 
 @pytest.mark.parametrize("name", NEW)

@@ -182,7 +182,7 @@ def test_every_new_metric_is_an_entry_and_a_file(run):
         assert (m["moves"], m["better"], m["layer"]) == ("tpot_p95_ms", "lower", layers[name])
         assert m["unit"] == ("%" if name.endswith("_share") else "ms")
         assert m["source"] == ("program_counter" if name.endswith("_share") else "program_span")
-        assert m["workloads"] == (SERVING[:3] if name == "token_gap_prefill_ms" else SERVING)
+        assert set(m["workloads"]) >= set(SERVING[:3] if name == "token_gap_prefill_ms" else SERVING)  # later cells join
     cells = {c["name"]: c for c in manifest["workloads"]}
     for cell in SERVING:
         reported = {m["name"] for m in run.metrics_for(manifest, cells[cell], "per_layer")}
