@@ -39,7 +39,9 @@ reads the rows of closed windows. The ROWS come before the heads: with a
 head of 128 the device keeps a table as it is shaped, so a row is whole
 tiles of ``heads x head_dim`` and a step writes it where it lies (kept
 ``(slots, heads, rows, head_dim)`` the compiler turned every table over
-for the one-row write and back, 16 ms of a 43 ms step on the chip). It
+for the one-row write and back, 16 ms of a 43 ms step on the chip). On
+the chip a step reads both tables through ``ops/eva_attention.py``, each
+live slot's own rows in blocks of 128, not every row of every slot. It
 carries none of the engine's optional features.
 """
 
@@ -54,6 +56,7 @@ import bigdl_tpu.nn as nn
 from bigdl_tpu.nn.gated import mm
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.nn.rotary import apply_rotary, rotary_angles
+from bigdl_tpu.ops import eva_attention
 
 # queries scored at a time inside a window: one block's scores against the
 # window's keys and every summary are alive, not the window's
@@ -203,13 +206,16 @@ class EvaAttention(Module):
         return {"win_k": jnp.zeros(near, dtype), "win_v": jnp.zeros(near, dtype),
                 "sum_k": jnp.zeros(far, dtype), "sum_v": jnp.zeros(far, dtype)}
 
-    def decode_step(self, params, x, cache, pos):
+    def decode_step(self, params, x, cache, pos, live=None):
         """One position a row: ``x`` (B, hidden), ``pos`` (B,). Writes
         ``k``, ``v`` to window row ``pos mod window``, reads window rows
         ``0 .. pos mod window`` and the summaries of closed windows, and,
         where ``pos`` ends a chunk, forms that chunk's summary from the
         ``chunk`` window rows that end there and writes it to summary row
-        ``pos div chunk``."""
+        ``pos div chunk``. The read goes through ``ops/eva_attention.py``
+        where it takes both tables as allocated (each slot in ``live``,
+        every slot where that is None, reads its own blocks and a free
+        slot none), else every row of every slot is scored and masked."""
         w, c = self.window, self.chunk
         pos = jnp.asarray(pos, jnp.int32)
         q, k, v = self._qkv(params, x[:, None], pos[:, None])
@@ -238,12 +244,18 @@ class EvaAttention(Module):
         sv = write_rows(sv, jnp.where(
             closes, new_v.astype(sv.dtype),
             jnp.take_along_axis(sv, at_row, axis=1)), pos // c)
-        local = jnp.arange(w)[None, :] <= row[:, None]              # (B, W)
-        seen = jnp.arange(sk.shape[1])[None, :] \
-            < ((pos // w) * (w // c))[:, None]                      # (B, S)
-        # one query a row: scores of (B, heads, columns), no query axis
-        o = self._attend(q[:, 0], wk, wv, sk, sv, local[:, None, :],
-                         seen[:, None, :])
+        if eva_attention.applies(wk) and eva_attention.applies(sk):
+            if live is None:
+                live = jnp.ones(pos.shape, bool)
+            o = eva_attention.eva_attention(q[:, 0], wk, wv, sk, sv, pos,
+                                            live, window=w, chunk=c)
+        else:
+            local = jnp.arange(w)[None, :] <= row[:, None]          # (B, W)
+            seen = jnp.arange(sk.shape[1])[None, :] \
+                < ((pos // w) * (w // c))[:, None]                  # (B, S)
+            # one query a row: scores of (B, heads, columns), no query axis
+            o = self._attend(q[:, 0], wk, wv, sk, sv, local[:, None, :],
+                             seen[:, None, :])
         return self._project(params, o[:, None])[:, 0], {
             "win_k": wk, "win_v": wv, "sum_k": sk, "sum_v": sv}
 
@@ -296,9 +308,9 @@ class EvaByteBlock(Module):
         y, kv, sums = self.attn.window_pass(params["attn"], u, first, sums)
         return self._mlp(params, x + y), kv, sums
 
-    def decode_step(self, params, cache, x, pos):
+    def decode_step(self, params, cache, x, pos, live=None):
         u = self.attn_norm.call(params["attn_norm"], x)
-        y, cache = self.attn.decode_step(params["attn"], u, cache, pos)
+        y, cache = self.attn.decode_step(params["attn"], u, cache, pos, live)
         return self._mlp(params, x + y), cache
 
 
@@ -410,15 +422,36 @@ class EvaByteForCausalLM(Module):
     def cache_tables(self):
         """The window, written at ``pos mod window`` and read up to it,
         and the summaries: row ``pos div chunk`` written where ``pos``
-        ends a chunk, the rows of closed windows read."""
+        ends a chunk, the rows of closed windows read. Both are read
+        through ``ops/eva_attention.py`` where it takes the two tables as
+        allocated (asked of a leaf of either, the other's shape follows),
+        each live slot's rows in whole blocks."""
         from bigdl_tpu.serving.protocol import RowTable
         w, c = self.window, self.chunk
-        return (RowTable(("win_k", "win_v"), w,
-                         lambda pos: pos % w, lambda pos: pos % w + 1,
-                         row_axis=1),
-                RowTable(("sum_k", "sum_v"), self.max_position // c,
+        near, far = w, self.max_position // c
+
+        def own_read(read_rows):
+            def fetched(leaf):
+                b, _, heads, head_dim = leaf.shape
+                if not all(eva_attention.applies(jax.ShapeDtypeStruct(
+                        (b, rows, heads, head_dim), leaf.dtype))
+                        for rows in (near, far)):
+                    return None
+                return lambda pos: eva_attention.fetched_rows(
+                    read_rows(pos))
+            return fetched
+
+        def near_read(pos):
+            return pos % w + 1
+
+        def far_read(pos):
+            return (pos // w) * (w // c)
+
+        return (RowTable(("win_k", "win_v"), near, lambda pos: pos % w,
+                         near_read, row_axis=1, own_read=own_read(near_read)),
+                RowTable(("sum_k", "sum_v"), far,
                          lambda pos: (pos % c == c - 1) * (pos // c + 1) - 1,
-                         lambda pos: (pos // w) * (w // c), row_axis=1))
+                         far_read, row_axis=1, own_read=own_read(far_read)))
 
     def step_counts(self, pos):
         """What the step at the live slots' positions ``pos`` (numpy)
@@ -484,14 +517,15 @@ class EvaByteForCausalLM(Module):
         ``h`` (B, hidden) the final-norm rows. The slot table's two words
         stay unset: its kernels know tables of ``(slots, heads, rows,
         head_dim)`` read one a slot, and these keep the rows before the
-        heads and are read two under one softmax (``cache_tables``). Its
-        mask ``live`` is for those kernels and changes nothing here: the
+        heads and are read two under one softmax, by the model's own
+        kernel where it takes them (``cache_tables``). Its mask ``live``
+        goes to that kernel, which reads nothing of a free slot; the
         plain row writes take every row."""
         assert not in_place and read is None, (in_place, read)
         h = self._embed(params, tok)
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tok.shape)
         new_cache = []
         for layer, p, c in zip(self.layers, params["layers"], cache):
-            h, c = layer.decode_step(p, c, h, pos)
+            h, c = layer.decode_step(p, c, h, pos, live)
             new_cache.append(c)
         return self.out_norm.call(params["out_norm"], h), new_cache

@@ -811,6 +811,179 @@ def latent_read_leg(model_kw=None, first=4096, steps=64, dtype="bfloat16",
             "kv_write": sm.kv_write, "attn_read": sm.attn_read}
 
 
+# the EvaByte cell's tables, a layer: 20 slots, a window of 2048 rows and
+# 1024 summaries (16384 positions in chunks of 16), 32 heads of 128; the
+# cell's model has 8 layers
+EVA_TABLES = (20, 2048, 1024, 32, 128)
+EVA_CHUNK, EVA_LAYERS = 16, 8
+# what float32 sums in another order may add to the kernel's read, beside
+# the rounding of the softmax's weights to the tables' dtype (which the
+# masked read rounds too): the leg holds each output to ``u x sum_r p_r
+# |v_r|`` from the true read, ``u`` the dtype's unit roundoff, plus this.
+# Two roundings of the same weights differ by up to 2 u of a weight, so
+# the kernel and the masked read may lie 2 u x that mass apart (0.0061
+# in the mix of edge positions on a v5e, where few rows share the
+# weights); a wrong row or block moves an output by a whole weight's
+# share, many times the bound
+EVA_TOL = 1e-5
+
+
+def eva_read_leg(tables=EVA_TABLES, chunk=EVA_CHUNK, layers=EVA_LAYERS,
+                 dtype="bfloat16", tol=EVA_TOL, reps=10, interpret=False):
+    """``ops/eva_attention.py`` against the read it replaces
+    (``EvaAttention._attend``, every row of both tables scored under the
+    decode step's masks) and the true float32 read, at the EvaByte cell's
+    tables, ``layers`` layers' worth of them, at several mixes of
+    positions: the cell's (a window
+    or more deep), each window's first and last row, a chunk's closing
+    position and the table's end, the first window (no summary seen),
+    every slot at the deepest position, and a third of the slots free
+    (their rows NaN: a free slot must read nothing and give zeros). Each
+    output must lie within the rounding of the softmax's weights to the
+    tables' dtype (and ``tol``) of the true read's (``EVA_TOL``); the
+    masked read's share of that bound is reported beside, and the widest
+    difference between the two reads as ``error``. Both
+    reads are timed on the host's clock, all layers' calls back to back,
+    ``reps`` times, the best of three rounds: ms a layer, and the kernel's
+    bytes fetched over its time in GB/s. Also that the tables as
+    allocated select the kernel here (``applies``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.models.evabyte import EvaAttention
+    from bigdl_tpu.ops import eva_attention as kernel
+
+    slots, window, far_rows, heads, head_dim = tables
+    pmax = far_rows * chunk
+    att = EvaAttention(heads * head_dim, heads, window, chunk)
+    t_start = time.perf_counter()
+    draw = jax.jit(lambda k, shape: jax.random.normal(
+        k, shape, jnp.float32).astype(dtype), static_argnums=1)
+    keys = iter(jax.random.split(jax.random.key(45), 5 * layers))
+    near = (slots, window, heads, head_dim)
+    far = (slots, far_rows, heads, head_dim)
+    qs = [jax.random.normal(next(keys), (slots, heads, head_dim))
+          for _ in range(layers)]
+    stack = [[draw(next(keys), shape) for shape in (near, near, far, far)]
+             for _ in range(layers)]
+    selected = all(kernel.applies(t) for t in stack[0])
+    _require(selected or interpret,
+             f"eva_read: the tables as allocated do not select the kernel "
+             f"({dtype}, {list(near)} and {list(far)})")
+
+    def twin(qs, stack, pos, live):
+        local = jnp.arange(window)[None, :] <= (pos % window)[:, None]
+        seen = jnp.arange(far_rows)[None, :] \
+            < ((pos // window) * (window // chunk))[:, None]
+        return jnp.stack([jnp.where(live[:, None, None], att._attend(
+            q, *tabs, local[:, None, :], seen[:, None, :]), 0.0)
+            for q, tabs in zip(qs, stack)])
+
+    def own(qs, stack, pos, live):
+        return jnp.stack([kernel.eva_attention(
+            q, *tabs, pos, live, window=window, chunk=chunk,
+            interpret=interpret) for q, tabs in zip(qs, stack)])
+
+    def true_read(q, tabs, pos, live):
+        """One layer read in true float32, the weights unrounded: the
+        outputs, and ``sum_r p_r |v_r|`` (what rounding the weights to
+        the tables' dtype may move them by, in units of its roundoff)."""
+        f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+        wk, wv, sk, sv = tabs
+        q = q.astype(wk.dtype)
+        local = jnp.arange(window)[None, :] <= (pos % window)[:, None]
+        seen = jnp.arange(far_rows)[None, :] \
+            < ((pos // window) * (window // chunk))[:, None]
+        s = jnp.concatenate([
+            jnp.where(local[:, None], jnp.einsum(
+                "bhd,bkhd->bhk", q, wk, precision=hi,
+                preferred_element_type=f32), -jnp.inf),
+            jnp.where(seen[:, None], jnp.einsum(
+                "bhd,bchd->bhc", q, sk, precision=hi,
+                preferred_element_type=f32), -jnp.inf)], -1)
+        p = jax.nn.softmax(s * head_dim ** -0.5, -1)
+
+        def mix(v_near, v_far):
+            return (jnp.einsum("bhk,bkhd->bhd", p[..., :window],
+                               v_near.astype(f32), precision=hi)
+                    + jnp.einsum("bhc,bchd->bhd", p[..., window:],
+                                 v_far.astype(f32), precision=hi))
+
+        keep = live[:, None, None]
+        return (jnp.where(keep, mix(wv, sv), 0.0),
+                jnp.where(keep, mix(jnp.abs(wv), jnp.abs(sv)), 0.0))
+
+    def timed(fn, *args):
+        fn(*args).block_until_ready()
+        best = float("inf")
+        for _ in range(3 if reps else 0):
+            t = time.perf_counter()
+            for _ in range(reps):
+                out = fn(*args)
+            out.block_until_ready()
+            best = min(best, (time.perf_counter() - t) / reps / layers * 1e3)
+        return round(best, 4) if reps else None
+
+    rng = np.random.default_rng(45)
+    edges = [0, window - 1, window, window + 1, 2 * window - 1, pmax - 1,
+             chunk - 1, window + chunk - 1, 3 * window - 1, 3 * window]
+    mixes = {
+        "cell": rng.integers(window // 2, pmax - window, slots),
+        "edges": np.resize(edges, slots),
+        "first_window": rng.integers(0, window, slots),
+        "deepest": np.full(slots, pmax - 1),
+        "a_third_free": rng.integers(window // 2, pmax - window, slots)}
+    twin_fn, own_fn = jax.jit(twin), jax.jit(own)
+    true_fn = jax.jit(true_read)
+    # the unit roundoff of the weights rounded to the tables' dtype
+    unit = float(jnp.finfo(dtype).eps) / 2
+    cases = {}
+    for name, pos in mixes.items():
+        live = np.ones(slots, bool)
+        if name == "a_third_free":
+            live[::3] = False
+        pos, live_d = jnp.asarray(pos, jnp.int32), jnp.asarray(live)
+        if not live.all():
+            # what a free slot's rows hold must not show (the tables are
+            # replaced, not copied: two sets would not fit the chip)
+            free = jnp.asarray(~live)[:, None, None, None]
+            for ls in stack:
+                for i, t in enumerate(ls):
+                    ls[i] = jnp.where(free, jnp.nan, t).astype(t.dtype)
+                del t
+        got = np.asarray(own_fn(qs, stack, pos, live_d))
+        want = np.asarray(twin_fn(qs, stack, pos, live_d))
+        # each read's widest distance from the true one, as a share of
+        # what the rounding of the weights allows (1 is the bound)
+        share = {"kernel": 0.0, "masked": 0.0}
+        for layer, (q, tabs) in enumerate(zip(qs, stack)):
+            truth, mass = map(np.asarray, true_fn(q, tabs, pos, live_d))
+            allowed = unit * mass + tol
+            for read, out in (("kernel", got), ("masked", want)):
+                share[read] = max(share[read], float(
+                    (np.abs(out[layer] - truth) / allowed).max()))
+        err = float(np.abs(got - want).max())
+        _require(np.isfinite(got).all() and share["kernel"] <= 1.0
+                 and not got[:, ~live].any(),
+                 f"eva_read {name}: {share['kernel']} of the rounding's "
+                 f"bound from the true read, or a free slot not zeros")
+        rows = (kernel.fetched_rows(np.asarray(pos) % window + 1)
+                + kernel.fetched_rows((np.asarray(pos) // window)
+                                      * (window // chunk)))[live]
+        fetched = int(rows.sum()) * 2 * heads * head_dim \
+            * np.dtype(dtype).itemsize
+        rec = {"error": err, "bound_share": share,
+               "rows_fetched_mean": float(rows.mean()),
+               "masked_ms": timed(twin_fn, qs, stack, pos, live_d),
+               "kernel_ms": timed(own_fn, qs, stack, pos, live_d)}
+        rec["kernel_gb_s"] = (round(fetched / rec["kernel_ms"] / 1e6, 1)
+                              if reps else None)
+        cases[name] = rec
+        print(f"chip_smoke: eva_read {name}: {json.dumps(rec)}", flush=True)
+    return {"ok": True, "setup_s": round(time.perf_counter() - t_start, 2),
+            "tolerance": tol, "selected": selected, "cases": cases}
+
+
 # held like LATENT_TOL: the root mean square of the logit differences over
 # every checked position and the widest single one. On the chip the leg
 # read 0.0074 and 0.187 on logits that spread by 1.28 (bfloat16 operands
@@ -1121,6 +1294,7 @@ def main():
         ("decode_attention", decode_attention_leg),
         ("sampling", sampling_leg),
         ("latent_read", latent_read_leg),
+        ("eva_read", eva_read_leg),
         ("ssm", ssm_leg),
         ("grouped_product", grouped_product_leg),
         ("train", train_resnet50),

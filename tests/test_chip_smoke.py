@@ -94,6 +94,26 @@ def test_grouped_product_leg():
     assert set(cases["toy.prefill"]["gmm_ms"]) == {"128x32x24", "256x32x24"}
 
 
+@pytest.mark.parametrize("dtype,error", [("float32", 2e-6),
+                                         ("bfloat16", 2e-2)])
+def test_eva_read_leg(dtype, error):
+    """The kernel against the masked read and the true read at toy
+    tables (two windows of 256, 128 summaries in chunks of 8, 4 heads of
+    16): every mix of positions within the rounding of the weights, a
+    free slot's NaN rows unseen."""
+    rec = chip_smoke.eva_read_leg(tables=(6, 256, 128, 4, 16), chunk=8,
+                                  layers=2, dtype=dtype, reps=0,
+                                  interpret=True)
+    assert rec["ok"] and set(rec["cases"]) == {
+        "cell", "edges", "first_window", "deepest", "a_third_free"}
+    for case in rec["cases"].values():
+        assert case["error"] <= error
+        assert max(case["bound_share"].values()) <= 1.0
+    assert rec["cases"]["first_window"]["rows_fetched_mean"] <= 256
+    # not on a TPU: the decode step would keep the masked read
+    assert rec["selected"] is False
+
+
 def test_latent_read_leg():
     """A full layer and a window layer at toy widths in float32: prefill
     past the selection of 8 and the window of 5, then steps, held to the

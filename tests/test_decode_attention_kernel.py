@@ -384,3 +384,33 @@ def test_state_update_compiled_at_the_cell_size_moves_no_state(one_chip):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == 4 * 128 * 128 * 64 * 128
     assert memory.temp_size_in_bytes < 2 ** 20
+
+
+def test_eva_read_compiled_at_the_cell_size(one_chip):
+    """``ops/eva_attention.py`` (EVA attention's two-table decode read)
+    at ``evabyte-longdoc-generate``'s tables: 20 slots, a window of 2048
+    rows and 1024 summaries of 32 heads of 128 in bfloat16, rows before
+    heads. The tables go in as they lie, with no copy of one around the
+    call and nothing of their size beside them. Kept here, beside the
+    other step kernels' compiles, so that one worker loads the TPU's
+    compiler."""
+    from bigdl_tpu.ops import eva_attention as eva
+    b, heads, head_dim = 20, 32, 128
+    near, far = (b, 2048, heads, head_dim), (b, 1024, heads, head_dim)
+    at = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    read = jax.jit(lambda q, *rest: eva.eva_attention(
+        q, *rest, window=2048, chunk=16, interpret=False))
+    compiled = read.lower(
+        at((b, heads, head_dim), jnp.float32), at(near, jnp.bfloat16),
+        at(near, jnp.bfloat16), at(far, jnp.bfloat16),
+        at(far, jnp.bfloat16), at((b,), jnp.int32),
+        at((b,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "eva_attention" in text
+    moved = [line for line in text.splitlines()
+             if re.search(r"= \w+\[20,(2048|1024),32,128\]\S* "
+                          r"(copy|transpose|fusion)\(", line)]
+    assert not moved, moved[:2]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    assert eva._vmem_bytes((heads, head_dim), jnp.bfloat16, 24) \
+        <= eva._VMEM_CEILING

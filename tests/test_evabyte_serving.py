@@ -28,6 +28,7 @@ from bigdl_tpu.obs import reqtrace
 from bigdl_tpu.models.evabyte import EvaByteForCausalLM
 from bigdl_tpu.models.gpt import GPTForCausalLM, prompt_bucket
 from bigdl_tpu.models.lfm2 import LFM2ForCausalLM
+from bigdl_tpu.ops import eva_attention
 from bigdl_tpu.serving import ServingEngine
 from bigdl_tpu.serving import slots as slots_mod
 from bigdl_tpu.serving.slots import SlotManager, select_tokens
@@ -293,6 +294,89 @@ def test_a_model_of_one_table_stamps_none_of_it():
     for s in obs.default_tracer().spans():
         if s.name in ("serve/step", "serve/prefill"):
             assert not [k for k in s.attrs if k.startswith("eva_")]
+
+
+# (h) the step's read through the model's own kernel ------------------------
+# whole blocks of 128 rows in both tables: a window of one block and 32
+# summaries a window, four windows (interpreted on the CPU)
+KW_BLOCKS = dict(KW, window_size=128, max_position=512)
+
+
+@pytest.fixture(scope="module")
+def eva_blocks():
+    model = EvaByteForCausalLM(**KW_BLOCKS)
+    shapes = jax.eval_shape(lambda k: model.setup(k, None)[0],
+                            jax.random.key(0))
+    params = weights.make_params(shapes, 5, SPEC)
+    reference, _ = reference_mod.make({
+        "constructor_kwargs": KW_BLOCKS, "controls": [], "faults": []})
+    return model, params, reference
+
+
+@pytest.fixture
+def kernel_read(monkeypatch):
+    """The step built as on the chip: ``ops/eva_attention.py`` takes
+    both tables (and runs interpreted)."""
+    monkeypatch.setattr(eva_attention, "applies", lambda *a: True)
+
+
+def test_the_step_built_with_the_kernel_agrees_with_reference(
+        eva_blocks, kernel_read):
+    """Prompts that end inside the first window, just short of its edge
+    and in the second and third, then steps that cross a window boundary
+    in every slot: each slot's logits after the prefill and after every
+    step are the reference's at that position, read through the kernel;
+    the slot table counts what the kernel fetches, whole blocks of each
+    live slot's window rows and closed summaries, and the spans still
+    stamp the rows the read NEEDS."""
+    model, params, reference = eva_blocks
+    w, c, pmax = 128, KW_BLOCKS["chunk_size"], KW_BLOCKS["max_position"]
+    lengths = (5, 120, 200, 300)
+    steps = 140
+    rng = np.random.default_rng(45)
+    seqs = [rng.integers(0, 40, pmax).astype(np.int32) for _ in lengths]
+    sm = SlotManager(model, params, max_slots=5, window=4)
+    assert (sm.kv_write, sm.attn_read) == ("scatter", "model")
+    slots = sm.admit([s[:n] for s, n in zip(seqs, lengths)])
+    want = []
+    for s in seqs:
+        ids = np.zeros(pmax, np.int32)
+        ids[:len(s)] = s
+        want.append(np.asarray(reference(params, ids,
+                                         np.arange(pmax, dtype=np.int32))))
+    for step in range(steps + 1):
+        got = np.asarray(sm._logits)
+        for slot, wnt, n in zip(slots, want, lengths):
+            assert np.abs(got[slot] - wnt[n - 1 + step]).max() < TOL, \
+                (step, n)
+        if step == steps:
+            break
+        pos = sm.lengths[sm.active].astype(int)
+        fetched = sum(-(-(p % w + 1) // 128) + -(-((p // w) * (w // c))
+                                                  // 128) for p in pos)
+        assert sm.attn_blocks() == (fetched, 5 * (1 + 1))
+        forced = np.full(got.shape, -np.inf, np.float32)
+        for slot, s, n in zip(slots, seqs, lengths):
+            forced[slot, s[n + step]] = 0.0
+        sm._logits = jnp.asarray(forced)
+        sm.step()
+        assert sm.step_attrs == {
+            "eva_window_rows": int(sum(p % w + 1 for p in pos)),
+            "eva_summary_rows": int(sum((w // c) * (p // w) for p in pos)),
+            "eva_chunks_closed": int(sum(p % c == c - 1 for p in pos))}
+    assert sm.stats["step_traces"] == 1
+    assert all(n + steps > w * (1 + n // w) for n in lengths)
+
+
+def test_the_kernel_is_not_taken_where_it_does_not_apply(eva_blocks):
+    """On the CPU the tables keep the masked read, and the model's word
+    says so: no table counts its own fetches."""
+    model, params, _ = eva_blocks
+    sm = SlotManager(model, params, max_slots=2, window=2)
+    assert sm.attn_read == "masked"
+    cache = model.init_cache(2, jnp.float32)
+    assert [t.own_read(cache[0][t.leaves[0]])
+            for t in model.cache_tables()] == [None, None]
 
 
 # (g) GPT-2's and LFM2's executables are the programs they were -------------
